@@ -3,7 +3,7 @@
 # CPU-simulated mesh — see tests/conftest.py and SURVEY.md §4).
 
 PYTEST      = python -m pytest
-MESH_ENV    = JAX_PLATFORMS='' XLA_FLAGS=--xla_force_host_platform_device_count=8
+MESH_ENV    = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 
 .PHONY: test test_fast test_ops test_win_ops test_optimizers test_parallel \
         test_launcher test_models bench chaos dryrun native scaling \

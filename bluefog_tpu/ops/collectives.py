@@ -18,14 +18,13 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..runtime import handles as _handles
 from ..runtime.state import _global_state
 from ..runtime.timeline import timeline_context
 from .neighbors import _auto_name, _check_rank_stacked
-from ..utils.compat import shard_map
 
 
 def _jit_smap(mesh, spec, body):

@@ -27,7 +27,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from .. import topology as topology_util
@@ -35,7 +35,6 @@ from ..runtime import handles as _handles
 from ..runtime.state import _global_state
 from ..runtime.timeline import timeline_context
 from .plan import CombinePlan, apply_plan
-from ..utils.compat import shard_map
 
 Weights = Union[float, Dict[int, float]]
 NestedWeights = Union[Dict[int, float], Dict[int, Dict[int, float]]]

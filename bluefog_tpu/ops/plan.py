@@ -38,11 +38,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import topology as topology_util
-from ..utils.compat import shard_map
 
 
 # Accumulate in f32 whenever inputs are lower precision (bf16 params on TPU):

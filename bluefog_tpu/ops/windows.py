@@ -54,7 +54,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import topology as topology_util
@@ -70,7 +70,6 @@ from ..runtime.state import _global_state
 from ..runtime.timeline import (timeline_context, timeline_counter,
                                 timeline_flow_finish, timeline_flow_start)
 from .neighbors import _check_rank_stacked, _per_rank
-from ..utils.compat import shard_map
 
 Weights = Union[float, Dict[int, float], Dict[int, Dict[int, float]]]
 

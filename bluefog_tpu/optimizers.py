@@ -56,6 +56,7 @@ or ``loss_fn(params, model_state, batch) -> (loss, (model_state, aux))`` with
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -67,7 +68,7 @@ import jax.flatten_util
 import jax.numpy as jnp
 import optax
 from flax import struct
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import topology as topology_util
@@ -86,7 +87,6 @@ from .runtime.logging import logger
 from .runtime.native import PeerLostError
 from .runtime.state import _global_state
 from .runtime.timeline import timeline_context
-from .utils.compat import shard_map
 
 
 # Consensus-gauge cadence (seconds): matches the time-series sampler's
@@ -119,19 +119,24 @@ def replicate(tree, mesh=None, axis: str = "rank"):
 
     The analog of ``bf.broadcast_parameters(..., root_rank=0)`` at t=0
     (reference: torch/utility.py:22-56): every rank starts from identical
-    values.
+    values. Each device receives one copy and adds its own leading axis
+    there — the ``(n, ...)`` stack never exists on a single device.
     """
     st = _global_state()
     st.check_initialized()
     mesh = mesh or st.mesh
-    n = mesh.devices.size
-    sh = NamedSharding(mesh, P(mesh.axis_names))
+    copies = jax.device_put(
+        jax.tree_util.tree_map(jnp.asarray, tree), NamedSharding(mesh, P()))
+    return _stack_fn(mesh)(copies)
 
-    def rep(x):
-        x = jnp.asarray(x)
-        return jax.device_put(jnp.broadcast_to(x[None], (n,) + x.shape), sh)
 
-    return jax.tree_util.tree_map(rep, tree)
+@functools.lru_cache(maxsize=8)
+def _stack_fn(mesh):
+    """Jitted per-device ``x -> x[None]`` over a replicated tree (cached per
+    mesh so equal trees reuse one trace). No donation: on a one-device mesh
+    ``device_put`` may hand back the caller's own buffers."""
+    return jax.jit(shard_map(
+        _restack, mesh=mesh, in_specs=P(), out_specs=P(mesh.axis_names)))
 
 
 def unreplicate(tree, rank: int = 0):

@@ -20,25 +20,16 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..utils.compat import shard_map
 
 # Python scalar, not jnp.float32(...): a concrete array here would initialize
 # the XLA backend at import time, breaking jax.distributed.initialize() in
 # multi-controller jobs (it must run before any backend touch).
 _NEG = -1e30
 
-if hasattr(lax, "pcast"):
-    def _pvary(x, axes):
-        return lax.pcast(x, axes, to="varying")
-elif hasattr(lax, "pvary"):  # jax < 0.9: pcast absent, pvary not deprecated
-    def _pvary(x, axes):
-        return lax.pvary(x, axes)
-else:  # jax <= 0.4.x: no varying-type system at all — shard_map does not
-    # track device-varying annotations, so the marker is a no-op
-    def _pvary(x, axes):
-        return x
+def _pvary(x, axes):
+    return lax.pcast(x, axes, to="varying")
 
 
 def reference_attention(q, k, v, causal: bool = False):
@@ -84,28 +75,11 @@ def ring_attention_shard(q, k, v, *, axis_name: str, causal: bool = False,
     return _ring_einsum_diff(q, k, v, axis_name, causal)
 
 
-def _axis_index(axis_name: str):
-    """``lax.axis_index`` that also lowers on the jax-0.4.x CPU backend.
-
-    There, the ring bodies' axis index emits a PartitionId HLO that the
-    SPMD partitioner rejects (``UNIMPLEMENTED: PartitionId``). An
-    all_to_all over an iota is equivalent — device i keeps element i of
-    ``arange(n)`` — and lowers on every backend; it costs one n-element
-    int32 exchange outside the scan, so keep the native lowering where it
-    works.
-    """
-    if jax.default_backend() != "cpu":
-        return lax.axis_index(axis_name)
-    n = lax.psum(1, axis_name)
-    return lax.all_to_all(jnp.arange(n, dtype=jnp.int32), axis_name,
-                          split_axis=0, concat_axis=0, tiled=True)[0]
-
-
 def _ring_einsum_partials(q, k, v, axis_name: str, causal: bool):
     """Einsum ring forward; returns (normalized out, row max m, row sum l),
     m/l in [B, Sq, H] layout — the backward's softmax reconstruction keys."""
     n = lax.psum(1, axis_name)
-    me = _axis_index(axis_name)
+    me = lax.axis_index(axis_name)
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     scale = 1.0 / math.sqrt(D)
@@ -167,7 +141,7 @@ def _ring_backward(axis_name: str, causal: bool, res, g,
     """
     q, k, v, out, m, l = res
     n = lax.psum(1, axis_name)
-    me = _axis_index(axis_name)
+    me = lax.axis_index(axis_name)
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     scale = 1.0 / math.sqrt(D)
@@ -257,7 +231,7 @@ def _ring_attention_flash(q, k, v, *, axis_name: str, causal: bool,
     from .flash import flash_block
 
     n = lax.psum(1, axis_name)
-    me = _axis_index(axis_name)
+    me = lax.axis_index(axis_name)
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     q_off = me * Sq

@@ -24,9 +24,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..utils.compat import shard_map
 
 
 def ep_mesh(n_experts: int, devices: Optional[Sequence] = None) -> Mesh:

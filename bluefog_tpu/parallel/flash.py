@@ -14,8 +14,9 @@ einsum fallback.
 
 Global-position offsets are scalar-prefetch operands so the SAME compiled
 kernel serves every ring step (block positions are runtime values, not
-trace constants). Off-TPU the kernel runs in interpret mode, keeping the
-CPU-mesh test suite meaningful.
+trace constants). ``interpret=True`` (the Pallas interpreter, for CPU-mesh
+tests) is only ever the caller's explicit choice: nothing here looks at the
+backend, so a run on the chip is a run of the compiled kernels.
 """
 
 from __future__ import annotations
@@ -27,14 +28,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from ..utils.compat import typeof as _typeof
-
-try:  # pallas TPU backend is absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAVE_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
 
@@ -172,7 +166,7 @@ def flash_block(q, k, v, q_off, k_off, *, causal: bool = True,
     # kernel compiles under interpret mode but fails to lower on real TPU.
     # Union over q/k/v: any varying operand makes the outputs varying (k/v
     # can be rank-varying while q is replicated, e.g. broadcast-query).
-    vmas = [getattr(_typeof(t), "vma", None) for t in (q, k, v)]
+    vmas = [getattr(jax.typeof(t), "vma", None) for t in (q, k, v)]
     kw = {} if all(m is None for m in vmas) else {
         "vma": frozenset().union(*(m for m in vmas if m is not None))}
     out_shape = (
@@ -180,32 +174,29 @@ def flash_block(q, k, v, q_off, k_off, *, causal: bool = True,
         jax.ShapeDtypeStruct((B * H, Sq, 8), jnp.float32, **kw),
         jax.ShapeDtypeStruct((B * H, Sq, 8), jnp.float32, **kw),
     )
-    if _HAVE_PLTPU:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, tq, D), lambda bh, qi, kj, offs: (bh, qi, 0)),
-                pl.BlockSpec((1, tk, D), lambda bh, qi, kj, offs: (bh, kj, 0)),
-                pl.BlockSpec((1, tk, D), lambda bh, qi, kj, offs: (bh, kj, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, tq, D), lambda bh, qi, kj, offs: (bh, qi, 0)),
-                pl.BlockSpec((1, tq, 8), lambda bh, qi, kj, offs: (bh, qi, 0)),
-                pl.BlockSpec((1, tq, 8), lambda bh, qi, kj, offs: (bh, qi, 0)),
-            ],
-        )
-        # bh/qi grid dims are independent (parallel); kj is the sequential
-        # online-softmax accumulation and must stay "arbitrary"
-        params = {} if interpret else {
-            "compiler_params": pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))}
-        o, m, l = pl.pallas_call(
-            kernel, grid_spec=grid_spec, out_shape=out_shape,
-            interpret=interpret, **params,
-        )(offs, bhsd(q), bhsd(k), bhsd(v))
-    else:  # pragma: no cover - pltpu always importable in this image
-        raise RuntimeError("pallas TPU backend unavailable")
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((1, tq, D), lambda bh, qi, kj, offs: (bh, qi, 0)),
+            pl.BlockSpec((1, tk, D), lambda bh, qi, kj, offs: (bh, kj, 0)),
+            pl.BlockSpec((1, tk, D), lambda bh, qi, kj, offs: (bh, kj, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, tq, D), lambda bh, qi, kj, offs: (bh, qi, 0)),
+            pl.BlockSpec((1, tq, 8), lambda bh, qi, kj, offs: (bh, qi, 0)),
+            pl.BlockSpec((1, tq, 8), lambda bh, qi, kj, offs: (bh, qi, 0)),
+        ],
+    )
+    # bh/qi grid dims are independent (parallel); kj is the sequential
+    # online-softmax accumulation and must stay "arbitrary"
+    params = {} if interpret else {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"))}
+    o, m, l = pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=out_shape,
+        interpret=interpret, **params,
+    )(offs, bhsd(q), bhsd(k), bhsd(v))
 
     def sbhd(x):  # [B*H, Sq, C] -> [B, Sq, H, C]
         return x.reshape((B, H) + x.shape[1:]).transpose(0, 2, 1, 3)
@@ -376,14 +367,11 @@ def flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off, *,
         return x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], D)
 
     offs = jnp.asarray([q_off, k_off], jnp.int32)
-    vmas = [getattr(_typeof(t), "vma", None) for t in (q, k, v, g)]
+    vmas = [getattr(jax.typeof(t), "vma", None) for t in (q, k, v, g)]
     kw = {} if all(mm is None for mm in vmas) else {
         "vma": frozenset().union(*(mm for mm in vmas if mm is not None))}
     operands = (offs, bhsd(q), bhsd(k), bhsd(v), bhsd(g),
                 _lane8(m), _lane8(l), _lane8(d_term))
-    if not _HAVE_PLTPU:  # pragma: no cover - pltpu always importable here
-        raise RuntimeError("pallas TPU backend unavailable")
-
     params = {} if interpret else {
         "compiler_params": pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))}

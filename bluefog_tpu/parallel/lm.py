@@ -16,11 +16,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .context import ring_attention_shard, ulysses_attention_shard
-from ..utils.compat import shard_map
 
 
 def _cp_model(model, kind: str, axis: str):

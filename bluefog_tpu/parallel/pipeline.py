@@ -38,11 +38,10 @@ import optax
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .context import _pvary, reference_attention
-from ..utils.compat import shard_map
 
 
 def pp_mesh(n_stages: int, devices: Optional[Sequence] = None) -> Mesh:
@@ -243,11 +242,7 @@ def _pp_fused_loss(model, mesh: Mesh, n_stages: int, n_micro: int):
 
         # rematted: without the checkpoint the scan backward would stash a
         # per-tick fp32 [mb, seq, vocab] logits residual on every stage —
-        # larger than the buffers this schedule exists to avoid. Shape [1],
-        # not scalar: jax-0.4.x's shard_map partial-eval promotes scalar
-        # remat/scan residuals incorrectly (the stage-varying names land on
-        # a rank-0 aval) and grad dies in _check_names with _SpecError, so
-        # no float scalar may cross a checkpoint/scan boundary here
+        # larger than the buffers this schedule exists to avoid
         @jax.checkpoint
         def microbatch_loss(y, idx):
             h = norm_mod.apply({"params": rest["final_norm"]}, y)
@@ -256,7 +251,7 @@ def _pp_fused_loss(model, mesh: Mesh, n_stages: int, n_micro: int):
             tgts = lax.dynamic_index_in_dim(targets_mb, idx, axis=0,
                                             keepdims=False)
             return optax.softmax_cross_entropy_with_integer_labels(
-                logits, tgts).mean(keepdims=True).reshape((1,))
+                logits, tgts).mean()
 
         def tick(carry, t):
             x_cur, loss_acc = carry
@@ -270,8 +265,7 @@ def _pp_fused_loss(model, mesh: Mesh, n_stages: int, n_micro: int):
             # -free branches notwithstanding), so uniformity wins here.
             contrib = microbatch_loss(y, jnp.clip(idx, 0, n_micro - 1))
             loss_acc = loss_acc + jnp.where(
-                jnp.logical_and(me == n_stages - 1, idx >= 0), contrib,
-                jnp.zeros_like(contrib))
+                jnp.logical_and(me == n_stages - 1, idx >= 0), contrib, 0.0)
             nxt = lax.ppermute(
                 y, "pipe", [(s, (s + 1) % n_stages) for s in range(n_stages)])
             ingest = embed(jnp.clip(t + 1, 0, n_micro - 1))
@@ -282,23 +276,16 @@ def _pp_fused_loss(model, mesh: Mesh, n_stages: int, n_micro: int):
             return (x_next, loss_acc), None
 
         x0 = jnp.where(me == 0, embed(0), jnp.zeros_like(embed(0)))
-        loss0 = _pvary(jnp.zeros((1,), jnp.float32), ("pipe",))
+        loss0 = _pvary(jnp.zeros((), jnp.float32), ("pipe",))
         (_, loss_acc), _ = lax.scan(
             tick, (x0, loss0), jnp.arange(n_micro + n_stages - 1))
-        # only the last stage accumulated a nonzero partial. Return the
-        # per-stage partial ([1], stage-varying) and reduce OUTSIDE the
-        # shard_map: an in-body lax.psum of the total trips jax-0.4.x's
-        # pre-vma replication checker under grad (the jvp/partial-eval
-        # rewrite loses track of the psum'd value's rep and rejects the
-        # P() out_spec with _SpecError), while a stage-varying out_spec
-        # has nothing to prove — and the transposed ingest/epilogue psums
-        # the checker inserts itself are handled fine either way.
-        return loss_acc
+        # only the last stage accumulated; psum replicates the total
+        return lax.psum(loss_acc, "pipe")
 
     mapped = shard_map(
         per_stage, mesh=mesh,
         in_specs=(P("pipe"), P(), P(), P()),
-        out_specs=P("pipe"),
+        out_specs=P(),
     )
 
     def loss(stacked_blocks, rest, batch):
@@ -308,11 +295,9 @@ def _pp_fused_loss(model, mesh: Mesh, n_stages: int, n_micro: int):
             raise ValueError(
                 f"batch {b} must divide into {n_micro} microbatches")
         mb = b // n_micro
-        # the explicit psum placement: cross-stage total as a sharded sum
-        # in the outer program (grads flow back uniformly to every stage)
-        return jnp.sum(mapped(stacked_blocks, rest,
-                              tokens.reshape(n_micro, mb, seq),
-                              targets.reshape(n_micro, mb, seq))) / n_micro
+        return mapped(stacked_blocks, rest,
+                      tokens.reshape(n_micro, mb, seq),
+                      targets.reshape(n_micro, mb, seq)) / n_micro
 
     return loss
 

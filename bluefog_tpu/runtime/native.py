@@ -629,6 +629,32 @@ def _arm_fault_from_env(lib) -> None:
                    "(BLUEFOG_CP_FAULT — never set this in production)", cfg)
 
 
+def _build() -> bool:
+    """Compile csrc/bf_runtime.cc into the default artifact; a failure is a
+    WARNING that carries the compiler's own message."""
+    try:
+        subprocess.run(["sh", os.path.join(_CSRC, "build.sh")], check=True,
+                       capture_output=True, timeout=120)
+        return True
+    except subprocess.CalledProcessError as exc:
+        detail = exc.stderr.decode(errors="replace").strip()[-2000:]
+    except (subprocess.SubprocessError, OSError) as exc:
+        detail = str(exc)
+    logger.warning("native runtime build failed; using pure-Python "
+                   "fallbacks. Compiler said: %s", detail)
+    return False
+
+
+def _stale(so: str) -> bool:
+    """The default artifact is missing or older than its source (csrc/build
+    is git-ignored, so a leftover .so can predate the checked-out source)."""
+    try:
+        return os.path.getmtime(so) < os.path.getmtime(
+            os.path.join(_CSRC, "bf_runtime.cc"))
+    except OSError:
+        return True
+
+
 def load() -> Optional[ctypes.CDLL]:
     """Load (building if needed) the native library; None when unavailable."""
     global _lib, _tried
@@ -637,42 +663,21 @@ def load() -> Optional[ctypes.CDLL]:
             return _lib
         _tried = True
         so = _so_path()
-        if not os.path.exists(so):
-            if so != _SO:
+        if so != _SO:
+            if not os.path.exists(so):
                 # an explicit BLUEFOG_NATIVE_SO that does not exist is a
                 # misconfiguration, not a build trigger (sanitizer builds
                 # are produced by `make tsan` / `make asan`, not lazily)
                 logger.warning("BLUEFOG_NATIVE_SO=%s does not exist; "
                                "native runtime unavailable", so)
                 return None
-            script = os.path.join(_CSRC, "build.sh")
-            if not os.path.exists(script):
-                return None
-            try:
-                subprocess.run(["sh", script], check=True,
-                               capture_output=True, timeout=120)
-            except (subprocess.SubprocessError, OSError) as exc:
-                logger.info("native runtime build failed (%s); "
-                            "using pure-Python fallbacks", exc)
-                return None
+        elif _stale(so) and not _build():
+            return None
         try:
             _lib = _configure(ctypes.CDLL(so))
-        except AttributeError:
-            # A stale cached build predates a symbol _configure now needs
-            # (the .so is gitignored; load() only builds when it's missing).
-            # Rebuild once from the current sources and retry.
-            logger.info("native runtime is stale (missing symbol); "
-                        "rebuilding from csrc")
-            try:
-                subprocess.run(["sh", os.path.join(_CSRC, "build.sh")],
-                               check=True, capture_output=True, timeout=120)
-                _lib = _configure(ctypes.CDLL(so))
-            except (subprocess.SubprocessError, OSError,
-                    AttributeError) as exc:
-                logger.info("native runtime rebuild failed (%s)", exc)
-                _lib = None
-        except OSError as exc:
-            logger.info("native runtime load failed (%s)", exc)
+        except (OSError, AttributeError) as exc:
+            logger.warning("native runtime load failed (%s); using "
+                           "pure-Python fallbacks", exc)
             _lib = None
         if _lib is not None:
             _arm_fault_from_env(_lib)

@@ -29,7 +29,7 @@ from jax.sharding import Mesh
 
 from .. import topology as topology_util
 from . import handles
-from .config import Config
+from .config import Config, compile_cache_dir
 from .logging import logger
 
 
@@ -150,6 +150,7 @@ def init(
         logger.info("env %s has no effect on TPU (transport is XLA-managed)", knob)
 
     _maybe_init_distributed()
+    compile_cache_dir()
     # Multi-controller scalar coordination (window mutexes/versions/p,
     # cross-controller barrier). No-op unless the job is multi-process or
     # BLUEFOG_CP_HOST is set (runtime/control_plane.py).
@@ -298,6 +299,12 @@ def init(
         "bluefog_tpu initialized: %d rank(s) on %s, local_size=%d",
         st.size, st.devices[0].platform, st.local_size,
     )
+    if st.devices[0].platform != jax.default_backend():
+        logger.warning(
+            "ranks are %d %s device(s) (%s), not the default JAX backend %s",
+            st.size, st.devices[0].platform, st.devices[0].device_kind,
+            jax.default_backend(),
+        )
 
 
 def shutdown(_announce: bool = True) -> None:

@@ -41,14 +41,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import optax
-from jax.sharding import NamedSharding, PartitionSpec as P
-
-from .utils.compat import ABSTRACT_MESH_ARG_SHARDINGS, abstract_mesh
+from jax import shard_map
+from jax.sharding import AbstractMesh, NamedSharding, PartitionSpec as P
 
 from . import topology as topology_util
 from .ops.plan import CombinePlan
 from .optimizers import build_fused_step, build_sharded_step
-from .utils.compat import shard_map
 
 _COLLECTIVES = (
     "collective_permute", "all_reduce", "all_gather", "reduce_scatter",
@@ -97,10 +95,6 @@ def dynamic_onepeer_plan(n: int, step: int = 0) -> CombinePlan:
 # ---------------------------------------------------------------------------
 
 def _struct(shape, sharding, dtype=jnp.float32):
-    # 0.4.x cannot lower AbstractMesh ARG shardings (compat); the shard_map
-    # in_specs still stamp the partitioning into the lowered module
-    if not ABSTRACT_MESH_ARG_SHARDINGS:
-        return jax.ShapeDtypeStruct(shape, dtype)
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
@@ -119,9 +113,9 @@ def lower_train_step(kind: str, n: int, *, local_size: int = 4,
         if n % local_size:
             raise ValueError("local_size must divide n for hierarchical")
         m = n // local_size
-        mesh = abstract_mesh((m, local_size), ("machine", "local"))
+        mesh = AbstractMesh((m, local_size), ("machine", "local"))
     else:
-        mesh = abstract_mesh((n,), ("rank",))
+        mesh = AbstractMesh((n,), ("rank",))
 
     def loss(p, ms, b):
         x, y = b
@@ -181,7 +175,7 @@ def lower_cp_forward(n: int, *, seq: int = 1024, heads: int = 8,
     """
     from .parallel.context import ring_attention_shard
 
-    mesh = abstract_mesh((n,), ("rank",))
+    mesh = AbstractMesh((n,), ("rank",))
     body = functools.partial(ring_attention_shard, axis_name="rank",
                              causal=causal)
     spec = P(None, "rank")
@@ -212,7 +206,7 @@ def lower_moe_lm_grad(n: int, *, n_layers: int = 2, moe_every: int = 2,
     from .models import MoETransformerLM
     from .parallel.expert import ep_lm_init, ep_lm_loss_fn, moe_param_specs
 
-    mesh = abstract_mesh((n,), ("expert",))
+    mesh = AbstractMesh((n,), ("expert",))
     model = MoETransformerLM(
         vocab_size=vocab, num_experts=n, num_layers=n_layers,
         num_heads=2, d_model=d_model, d_ff=d_ff, moe_every=moe_every,

@@ -1,0 +1,354 @@
+"""chip_smoke.py — the quickest proof that bluefog_tpu still starts on the chip.
+
+``python chip_smoke.py`` (no arguments, one process, every chip of the host)
+drives the gossip trainer once through the entry points a user calls —
+``bf.init()``, the model classes, ``bf.Distributed*Optimizer.init/.step``,
+``bf.neighbor_allreduce`` — at the full width of the two models the repo
+benchmarks, with random weights from a seed:
+
+* ``resnet50``: the step ``bench.py`` times (ResNet-50, bf16, 224x224, 128
+  images per chip, SGD-momentum, neighbor averaging), and the check that
+  rank ``r``'s slice of every state leaf lives on device ``r``.
+* ``gossip`` (more than one chip): ``bf.neighbor_allreduce`` against the
+  topology's weight matrix, one ResNet-50 step per shift set of the dynamic
+  one-peer Expo-2 schedule, the hierarchical optimizer on the host's machine
+  mesh, and two ``DistributedWinPutOptimizer`` steps on a small MLP.
+* ``lm_flash``: the three compiled flash-attention kernels against the dense
+  f32-softmax reference, then the 4-layer d_model-2048 LM at 8192 tokens per
+  chip through ``bf.DistributedNeighborAllreduceOptimizer.step``.
+
+It refuses to start unless every rank is a TPU device, and a failing phase
+raises (nothing is caught). A run that passed ends with two JSON lines on
+stdout: the report (versions, peak HBM, and per phase the compile seconds,
+steady seconds per step, first and last loss), then as the last line exactly
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`` with
+the device as JAX reports it. It makes no performance claim: the seconds it
+prints are observations with a device stamp. Compiled programs go to the
+persistent cache ``bf.init()`` configures (``JAX_COMPILATION_CACHE_DIR``, else
+``<checkout>/.jax_cache``), so a second run in the same checkout reports far
+smaller compile times.
+"""
+
+from __future__ import annotations
+
+import json
+import math
+import os
+import sys
+import time
+from functools import partial
+
+HERE = os.path.dirname(os.path.realpath(__file__))
+sys.path.insert(0, HERE)
+
+import numpy as np  # noqa: E402
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import optax  # noqa: E402
+
+import bluefog_tpu as bf  # noqa: E402
+from bluefog_tpu.models import MLP, ResNet50, TransformerLM  # noqa: E402
+from bluefog_tpu.parallel.context import reference_attention  # noqa: E402
+from bluefog_tpu.parallel.flash import flash_attention  # noqa: E402
+from bluefog_tpu.runtime import native  # noqa: E402
+from bluefog_tpu.runtime.config import compile_cache_dir  # noqa: E402
+
+if os.path.dirname(os.path.dirname(os.path.realpath(bf.__file__))) != HERE:
+    raise SystemExit(
+        f"chip_smoke: bluefog_tpu was imported from {bf.__file__}, not from "
+        f"the checkout beside this script ({HERE})")
+
+RESNET_BATCH, IMAGE, RESNET_STEPS = 128, 224, 5
+LM = dict(vocab_size=32768, num_layers=4, num_heads=16, d_model=2048,
+          d_ff=8192)
+LM_SEQ, LM_STEPS = 8192, 4
+KERNEL_SHAPE = (1, 2048, 16, 128)  # B, S, H, D of the kernel-vs-reference leg
+KERNEL_TOL = 3e-2
+FLASH = partial(flash_attention, causal=True)
+
+
+def _timed_steps(opt, state, batch, steps):
+    """One step that compiles, then ``steps`` more closed by one
+    ``block_until_ready``. Returns (state, compile s, steady s/step, the
+    per-step [n] losses as numpy)."""
+    t0 = time.perf_counter()
+    state, m = opt.step(state, batch)
+    jax.block_until_ready(m["loss"])
+    compile_s = time.perf_counter() - t0
+    losses = [m["loss"]]
+    t0 = time.perf_counter()
+    for _ in range(steps):
+        state, m = opt.step(state, batch)
+        losses.append(m["loss"])
+    jax.block_until_ready(m["loss"])
+    steady = (time.perf_counter() - t0) / steps
+    losses = np.stack([np.asarray(l, np.float32) for l in losses])
+    if not np.isfinite(losses).all():
+        raise RuntimeError(f"non-finite loss: {losses.tolist()}")
+    return state, compile_s, steady, losses
+
+
+def _report(compile_s, steady, losses):
+    return {"compile_s": round(compile_s, 2),
+            "steady_s_per_step": round(steady, 4),
+            "first_loss": round(float(losses[0].mean()), 4),
+            "last_loss": round(float(losses[-1].mean()), 4)}
+
+
+def _check_layout(tree, what):
+    """Every leaf: one addressable shard per device, of leading size 1, and
+    row r on the device of rank r."""
+    devices = list(bf.mesh().devices.flat)
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        name = what + jax.tree_util.keystr(path)
+        shards = leaf.addressable_shards
+        if len(shards) != len(devices) or leaf.shape[0] != len(devices):
+            raise RuntimeError(
+                f"{name}: {len(shards)} shard(s) of shape {leaf.shape} over "
+                f"{len(devices)} device(s)")
+        for sh in shards:
+            r = sh.index[0].start or 0
+            if sh.data.shape[0] != 1 or sh.device != devices[r]:
+                raise RuntimeError(
+                    f"{name}: row {r} has shard shape {sh.data.shape} on "
+                    f"{sh.device}, expected leading 1 on {devices[r]}")
+
+
+def _rank_batch(make):
+    """Build a rank-stacked batch with each rank's slice made on its chip."""
+    return jax.jit(make, out_shardings=bf.rank_sharding(bf.mesh()))(
+        jax.random.PRNGKey(1))
+
+
+def phase_resnet50():
+    n = bf.size()
+    model = ResNet50(num_classes=1000, dtype=jnp.bfloat16)
+    variables = jax.jit(lambda k: model.init(
+        k, jnp.zeros((RESNET_BATCH, IMAGE, IMAGE, 3), jnp.float32),
+        train=True))(jax.random.PRNGKey(0))
+    params = variables["params"]
+
+    def loss_fn(p, ms, batch):
+        images, labels = batch
+        logits, updates = model.apply(
+            {"params": p, "batch_stats": ms}, images, train=True,
+            mutable=["batch_stats"])
+        loss = optax.softmax_cross_entropy_with_integer_labels(
+            logits, labels).mean()
+        return loss, (updates["batch_stats"], {})
+
+    opt = bf.DistributedNeighborAllreduceOptimizer(
+        optax.sgd(0.1, momentum=0.9), loss_fn, with_model_state=True)
+    state = opt.init(params, model_state=variables["batch_stats"])
+    batch = _rank_batch(lambda k: (
+        jax.random.normal(k, (n, RESNET_BATCH, IMAGE, IMAGE, 3), jnp.float32),
+        jnp.zeros((n, RESNET_BATCH), jnp.int32)))
+
+    state, compile_s, steady, losses = _timed_steps(
+        opt, state, batch, RESNET_STEPS)
+    moved = np.asarray(jax.jit(lambda new, old: sum(
+        jnp.sum(jnp.abs(a - b[None]), axis=tuple(range(1, a.ndim)))
+        for a, b in zip(jax.tree_util.tree_leaves(new),
+                        jax.tree_util.tree_leaves(old))))(state.params, params))
+    if not (moved > 0).all():
+        raise RuntimeError(f"parameters did not move on every rank: {moved}")
+    for what in ("params", "opt_state", "model_state"):
+        _check_layout(getattr(state, what), what)
+    out = _report(compile_s, steady, losses)
+    out["layout"] = "row r of every state leaf on device r"
+    return out, (opt, state, batch)
+
+
+def phase_gossip(opt, state, batch):
+    """Runs on the ResNet-50 optimizer and state of the previous phase."""
+    n = bf.size()
+    # 1. neighbor_allreduce of rank-distinct rows against the weight matrix:
+    # M[r, s] is the weight rank r gives to what it receives from s
+    M = np.zeros((n, n))
+    for r in range(n):
+        srcs = bf.in_neighbor_ranks(r)
+        M[r, [r] + srcs] = 1.0 / (len(srcs) + 1)
+    x = ((np.arange(n)[:, None] + 1) / 8 + np.arange(8)[None] / 64).astype(
+        np.float32)
+    got = np.asarray(bf.neighbor_allreduce(
+        bf.shard_rank_stacked(bf.mesh(), x)))
+    np.testing.assert_allclose(got, M @ x, rtol=1e-6, atol=1e-6)
+
+    # 2. the paper's configuration: one ResNet-50 step per shift set of the
+    # dynamic one-peer Expo-2 schedule (ceil(log2 n) compiled programs)
+    rounds = max(1, math.ceil(math.log2(n)))
+    gens = [bf.topology_util.GetDynamicSendRecvRanks(bf.load_topology(), r)
+            for r in range(n)]
+    t0 = time.perf_counter()
+    seen = set()
+    for _ in range(rounds):
+        sends = {r: next(g)[0] for r, g in enumerate(gens)}
+        recv = {r: [s for s, dsts in sends.items() if r in dsts]
+                for r in range(n)}
+        seen.add(tuple((d - s) % n for s, dsts in sorted(sends.items())
+                       for d in dsts))
+        opt.send_neighbors = sends
+        opt.self_weight = {r: 1.0 / (len(recv[r]) + 1) for r in range(n)}
+        opt.neighbor_weights = {
+            r: {s: 1.0 / (len(recv[r]) + 1) for s in recv[r]}
+            for r in range(n)}
+        state, m = opt.step(state, batch)
+        if not np.isfinite(np.asarray(m["loss"])).all():
+            raise RuntimeError(f"non-finite dynamic-step loss: {m['loss']}")
+    if len(seen) != rounds:
+        raise RuntimeError(f"expected {rounds} distinct shift sets: {seen}")
+    dynamic_s = time.perf_counter() - t0
+    _check_layout(state.params, "params")
+
+    # 3. small MLP: the hierarchical optimizer on this host's machine mesh,
+    # and the window plane's mailbox programs
+    mlp = MLP(features=(32, 8))
+    xb = jax.random.normal(jax.random.PRNGKey(2), (n, 4, 16))
+    yb = jnp.zeros((n, 4), jnp.int32)
+    mlp_params = mlp.init(jax.random.PRNGKey(3), xb[0])["params"]
+
+    def mlp_loss(p, b):
+        return optax.softmax_cross_entropy_with_integer_labels(
+            mlp.apply({"params": p}, b[0]), b[1]).mean()
+
+    hopt = bf.DistributedHierarchicalNeighborAllreduceOptimizer(
+        optax.sgd(0.1), mlp_loss)
+    _, hm = hopt.step(hopt.init(mlp_params), (xb, yb))
+    wopt = bf.DistributedWinPutOptimizer(optax.sgd(0.05), mlp_loss)
+    wstate = wopt.init(mlp_params)
+    for _ in range(2):  # step 1 fills the mailboxes, step 2 mixes them
+        wstate, wm = wopt.step(wstate, (xb, yb))
+    wopt.free()
+    for what, m in (("hierarchical", hm), ("win_put", wm)):
+        if not np.isfinite(np.asarray(m["loss"])).all():
+            raise RuntimeError(f"non-finite {what} loss: {m['loss']}")
+    return {
+        "neighbor_allreduce": "equals the weight matrix product to 1e-6",
+        "dynamic_one_peer_programs": rounds,
+        "dynamic_s_total": round(dynamic_s, 2),
+        "machine_mesh": list(bf.machine_mesh().devices.shape),
+        "hierarchical_loss": round(float(np.mean(np.asarray(hm["loss"]))), 4),
+        "win_put_loss": round(float(np.mean(np.asarray(wm["loss"]))), 4),
+    }
+
+
+def _mosaic_calls(fn, *args):
+    """Mosaic kernels in the program jit lowers for ``fn`` (an interpreted
+    kernel lowers to none)."""
+    return fn.lower(*args).as_text().count("tpu_custom_call")
+
+
+def _check_flash_kernels():
+    """Compiled forward, dq and dk/dv kernels against the dense reference."""
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    q, k, v, w = (jax.random.normal(kk, KERNEL_SHAPE, jnp.bfloat16)
+                  for kk in keys)
+
+    def weighted(attn):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v).astype(jnp.float32) * w.astype(jnp.float32))
+
+    grad = jax.jit(jax.grad(weighted(FLASH), argnums=(0, 1, 2)))
+    calls = _mosaic_calls(grad, q, k, v)
+    if calls != 3:
+        raise RuntimeError(
+            f"expected 3 Mosaic kernels in the flash backward, found {calls}")
+    ref = partial(reference_attention, causal=True)
+    got = (jax.jit(FLASH)(q, k, v),) + grad(q, k, v)
+    want = (jax.jit(ref)(q, k, v),) + jax.jit(
+        jax.grad(weighted(ref), argnums=(0, 1, 2)))(q, k, v)
+    err = {}
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, atol=KERNEL_TOL, rtol=KERNEL_TOL,
+                                   err_msg=f"flash {name} vs reference")
+        err[name] = round(float(np.max(np.abs(a - b))), 4)
+    return err
+
+
+def phase_lm_flash():
+    n = bf.size()
+    kernel_err = _check_flash_kernels()
+    model = TransformerLM(dtype=jnp.bfloat16, attn_fn=FLASH, **LM)
+    params = jax.jit(lambda k: model.init(
+        k, jnp.zeros((1, LM_SEQ), jnp.int32))["params"])(jax.random.PRNGKey(0))
+
+    def loss_fn(p, batch):
+        tokens, targets = batch
+        return optax.softmax_cross_entropy_with_integer_labels(
+            model.apply({"params": p}, tokens), targets).mean()
+
+    opt = bf.DistributedNeighborAllreduceOptimizer(optax.adam(1e-3), loss_fn)
+    state = opt.init(params)
+    del params  # the rank-stacked copy is the only one the step needs
+
+    def make(k):
+        tokens = jax.random.randint(k, (n, 1, LM_SEQ), 0, LM["vocab_size"])
+        return tokens, jnp.roll(tokens, -1, axis=2)
+
+    state, compile_s, steady, losses = _timed_steps(
+        opt, state, _rank_batch(make), LM_STEPS)
+    if not (losses[-1] < losses[0]).all():
+        raise RuntimeError(
+            f"LM loss did not fall on every rank: {losses.tolist()}")
+    _check_layout(state.params, "params")
+    out = _report(compile_s, steady, losses)
+    out["kernel_max_abs_err"] = kernel_err
+    return out
+
+
+def _device_stamp():
+    """The device as JAX reports it; exits unless every rank is a TPU chip and
+    every chip is a rank."""
+    devices = list(bf.mesh().devices.flat)
+    found = sorted({d.platform for d in devices})
+    if found != ["tpu"] or len(devices) != len(jax.devices()):
+        raise SystemExit(
+            f"chip_smoke: needs every rank on a TPU chip, but bf.init() "
+            f"ranked over {len(devices)} of {len(jax.devices())} device(s), "
+            f"platform {found} ({devices[0].device_kind}); nothing was run")
+    return {"platform": jax.devices()[0].platform,
+            "kind": jax.devices()[0].device_kind, "count": len(jax.devices())}
+
+
+def result_line(stamp):
+    """The last line of stdout: these keys and no others."""
+    return json.dumps({"ok": True, "device": stamp})
+
+
+def main():
+    import jaxlib
+    from importlib.metadata import version
+
+    bf.init()
+    stamp = _device_stamp()
+    versions = {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+                "libtpu": version("libtpu")}
+    native_loaded = native.load() is not None
+    print(f"device: {stamp}\nversions: {versions}\n"
+          f"native runtime loaded: {native_loaded}\n"
+          f"compile cache: {compile_cache_dir()}", flush=True)
+
+    phases = {}
+    phases["resnet50"], resnet = phase_resnet50()
+    print("resnet50:", phases["resnet50"], flush=True)
+    if stamp["count"] > 1:
+        phases["gossip"] = phase_gossip(*resnet)
+    else:
+        phases["gossip"] = "not applicable: one chip has no peer to gossip with"
+    print("gossip:", phases["gossip"], flush=True)
+    del resnet  # ResNet-50 and the LM do not fit beside each other
+    phases["lm_flash"] = phase_lm_flash()
+    print("lm_flash:", phases["lm_flash"], flush=True)
+    peak_gib = [round(d.memory_stats()["peak_bytes_in_use"] / 2**30, 2)
+                for d in bf.mesh().devices.flat]
+    bf.shutdown()
+    print(json.dumps({"device": stamp, "versions": versions,
+                      "native_runtime": native_loaded,
+                      "peak_hbm_gib_per_chip": peak_gib, "phases": phases}))
+    print(result_line(stamp), flush=True)
+
+
+if __name__ == "__main__":
+    main()

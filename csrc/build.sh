@@ -21,8 +21,11 @@ case "${SANITIZE:-}" in
         -o build/libbf_runtime.asan.so bf_runtime.cc
     ;;
   "")
-    exec g++ -O2 -shared -fPIC -std=c++17 -pthread \
-        -o build/libbf_runtime.so bf_runtime.cc
+    # built beside the target and renamed: a process that loads the library
+    # while another rebuilds it never maps a half-written file
+    g++ -O2 -shared -fPIC -std=c++17 -pthread \
+        -o "build/libbf_runtime.so.$$" bf_runtime.cc
+    exec mv -f "build/libbf_runtime.so.$$" build/libbf_runtime.so
     ;;
   *)
     echo "build.sh: unknown SANITIZE='$SANITIZE' (thread|address)" >&2

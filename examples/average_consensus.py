@@ -5,7 +5,7 @@ every rank starts with a random vector and repeatedly averages with its graph
 neighbors until all ranks agree on the global mean.
 
 Run (CPU-simulated 8-device mesh):
-    JAX_PLATFORMS='' XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/average_consensus.py
 On a real TPU slice just run it plainly: ranks are the local chips.
 """
@@ -25,9 +25,7 @@ from bluefog_tpu import topology_util
 
 
 def main() -> int:
-    from bluefog_tpu.runtime.config import example_devices
-
-    bf.init(topology_util.ExponentialTwoGraph, devices=example_devices())
+    bf.init(topology_util.ExponentialTwoGraph)
     n = bf.size()
     print(f"ranks: {n} on {bf.mesh().devices.flat[0].platform}")
 

@@ -46,6 +46,10 @@ def main():
     p.add_argument("--steps", type=int, default=20)
     p.add_argument("--attention", default="ring",
                    choices=["ring", "ulysses", "flash"])
+    p.add_argument("--interpret", action="store_true",
+                   help="run the flash kernels in the Pallas interpreter "
+                        "(a CPU mesh has no Mosaic lowering); never the "
+                        "default, so a chip run is a compiled-kernel run")
     args = p.parse_args()
 
     bf.init()
@@ -57,10 +61,8 @@ def main():
     if args.attention == "flash":
         from functools import partial
         from bluefog_tpu.parallel.flash import flash_attention
-        # real pallas kernel on TPU, interpret mode on CPU dev boxes /
-        # --simulate runs (no Mosaic lowering off-TPU)
         attn_fn = partial(flash_attention, causal=True,
-                          interpret=jax.default_backend() != "tpu")
+                          interpret=args.interpret)
     model = TransformerLM(
         vocab_size=args.vocab, num_layers=args.num_layers,
         num_heads=args.num_heads, d_model=args.d_model,

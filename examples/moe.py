@@ -10,8 +10,8 @@ No reference analog (the reference is data-parallel only); this is the
 expert-parallelism end-to-end demo, same spirit as examples/long_context_lm.py
 for sequence parallelism.
 
-Run:
-    JAX_PLATFORMS='' XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+Run (CPU-simulated 8-device mesh):
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/moe.py
 """
 
@@ -50,14 +50,12 @@ def main() -> None:
 
     E, d, d_ff, classes, per = args.experts, 16, 64, 8, 64
     devices = jax.devices()
-    if len(devices) < E:  # forced-CPU simulation: the default backend may
-        devices = jax.devices("cpu")  # be a single real chip
     tokens = classes * per
     if tokens % E or len(devices) < E:
         usable = [e for e in (2, 4, 8, 16, 32)
                   if tokens % e == 0 and e <= len(devices)]
         hint = f"try --experts {usable}" if usable else (
-            "run under JAX_PLATFORMS='' "
+            "run under JAX_PLATFORMS=cpu "
             "XLA_FLAGS=--xla_force_host_platform_device_count=8 for a "
             "simulated 8-device mesh")
         raise SystemExit(

@@ -302,8 +302,7 @@ def main() -> None:
                         help="optional path for a semilogy convergence plot")
     args = parser.parse_args()
 
-    from bluefog_tpu.runtime.config import example_devices
-    bf.init(devices=example_devices())
+    bf.init()
     print(f"ranks: {bf.size()} on {bf.mesh().devices.flat[0].platform}")
     _, _, mse = run(method=args.method, task=args.task,
                     topology=args.topology, maxite=args.max_iter,

@@ -223,6 +223,4 @@ def train(args, devices=None):
 
 
 if __name__ == "__main__":
-    from bluefog_tpu.runtime.config import example_devices
-
-    train(parse_args(), devices=example_devices())
+    train(parse_args())

@@ -16,7 +16,7 @@ sides share one process for a self-contained example; point
 them on different machines.
 
 Run (CPU-simulated 8-device mesh):
-    JAX_PLATFORMS='' XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         BLUEFOG_SERVE_PUBLISH_EVERY=1 python examples/serving.py
 On a real TPU slice just run it plainly: ranks are the local chips.
 """
@@ -49,9 +49,7 @@ import bluefog_tpu as bf
 
 
 def main() -> int:
-    from bluefog_tpu.runtime.config import example_devices
-
-    bf.init(devices=example_devices())
+    bf.init()
     print(f"ranks: {bf.size()}")
 
     # a tiny ridge-regression "model": one weight vector, least squares

@@ -1,11 +1,12 @@
 """Batch-size sweep for the ResNet-50 benchmark step (real-chip probe).
 
 Imports bench.setup() so the probe measures EXACTLY the benchmarked step
-(same model, optimizer, data placement, and host-transfer sync idiom),
+(same model, optimizer, data placement and completion barrier),
 printing img/s per batch size. Used to pick bench.py's BATCH_PER_CHIP
 (PERF.md: B=128 adopted in round 2).
 
-Run from the repo root: ``python scripts/batch_sweep.py [batch ...]``.
+Run from the repo root: ``python scripts/batch_sweep.py [batch ...]``. Every
+point runs in this one process (the chip belongs to one process at a time).
 """
 
 from __future__ import annotations
@@ -24,6 +25,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 os.environ.setdefault("BLUEFOG_FLIGHT_DIR",
                       tempfile.mkdtemp(prefix="bf_flight_"))
 
+import jax  # noqa: E402
+
 import bluefog_tpu as bf  # noqa: E402
 import bench  # noqa: E402
 
@@ -36,14 +39,14 @@ def measure(batch: int) -> float:
     # coordinated shutdown between points would latch every peer's
     # shutdown_requested() in a multi-controller job (see state.py re-init
     # note).
-    opt, state, data, sync = bench.setup(batch)
+    opt, state, data = bench.setup(batch)
     for _ in range(WARMUP):
         state, metrics = opt.step(state, data)
-    sync(metrics)
+    jax.block_until_ready(metrics["loss"])
     t0 = time.perf_counter()
     for _ in range(STEPS):
         state, metrics = opt.step(state, data)
-    sync(metrics)
+    jax.block_until_ready(metrics["loss"])
     return batch * STEPS / (time.perf_counter() - t0)
 
 

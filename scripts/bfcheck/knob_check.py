@@ -7,7 +7,6 @@ source and finds each ``BLUEFOG_*`` environment READ:
   receiver whose attribute chain mentions ``environ``),
 * ``os.environ[name]`` subscripts in Load context,
 * ``name in os.environ`` membership probes,
-* ``timeout_from_env(name, default)`` (the shared entry-script helper),
 * ``EnvInt("NAME", default)`` / ``EnvSeconds("NAME", default)`` in
   ``csrc/bf_runtime.cc``.
 
@@ -131,11 +130,6 @@ class _ReadCollector(ast.NodeVisitor):
                 and (_mentions_environ(fn.value)
                      or (isinstance(fn.value, ast.Name)
                          and fn.value.id in ("os", "env"))):
-            if node.args:
-                name = self._knob_arg(node.args[0])
-                if len(node.args) > 1:
-                    default = node.args[1]
-        elif isinstance(fn, ast.Name) and fn.id == "timeout_from_env":
             if node.args:
                 name = self._knob_arg(node.args[0])
                 if len(node.args) > 1:

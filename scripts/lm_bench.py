@@ -5,7 +5,8 @@ synthetic data, warmup, timed window, throughput printout) applied to the
 long-context LM path this framework adds on top of reference parity:
 flash-attention forward + flash-attention-2 backward kernels, bf16 compute,
 one jitted train step. Reports ms/step, tokens/s, and model FLOPs
-utilization against the v5e bf16 peak.
+utilization against the bf16 peak of the device kind it ran on (bench.PEAKS).
+Runs on a TPU only.
 
 FLOPs accounting (PaLM-style model FLOPs, causal):
   matmul params: 6 * N_matmul * tokens   (fwd + bwd)
@@ -40,10 +41,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 os.environ.setdefault("BLUEFOG_FLIGHT_DIR",
                       tempfile.mkdtemp(prefix="bf_flight_"))
 
+from bench import peaks, require_tpu  # noqa: E402
 from bluefog_tpu.models import TransformerLM  # noqa: E402
 from bluefog_tpu.parallel.flash import flash_attention  # noqa: E402
-
-V5E_BF16_PEAK = 197e12  # TPU v5e per-chip bf16 peak FLOP/s
 
 
 def matmul_param_count(params) -> int:
@@ -60,6 +60,8 @@ def matmul_param_count(params) -> int:
 def run(seq_len: int, d_model: int, num_layers: int, num_heads: int,
         batch: int, vocab: int, steps: int, warmup: int, remat: bool,
         chunked_ce: bool = False, ce_chunk: int = 1024):
+    device = jax.devices()[0]  # the bare jitted step runs on the default one
+    stamp = require_tpu([device])
     model = TransformerLM(
         vocab_size=vocab, num_layers=num_layers, num_heads=num_heads,
         d_model=d_model, d_ff=4 * d_model, dtype=jnp.bfloat16,
@@ -98,13 +100,12 @@ def run(seq_len: int, d_model: int, num_layers: int, num_heads: int,
     batch_ = (tokens, targets)
     for _ in range(warmup):
         params, opt_state, l = step(params, opt_state, batch_)
-    if warmup:
-        float(np.asarray(l))  # close the warmup window
+    jax.block_until_ready(l)
 
     t0 = time.perf_counter()
     for _ in range(steps):
         params, opt_state, l = step(params, opt_state, batch_)
-    float(np.asarray(l))  # ONE closing host sync (reference methodology)
+    jax.block_until_ready(l)  # dispatch is asynchronous: wait for the last step
     dt = (time.perf_counter() - t0) / steps
 
     n_mat = matmul_param_count(params)
@@ -118,8 +119,9 @@ def run(seq_len: int, d_model: int, num_layers: int, num_heads: int,
         "ms_per_step": round(dt * 1e3, 2),
         "value": round(tokens_per_step / dt),
         "unit": "tokens/s",
-        "mfu": round(flops / dt / V5E_BF16_PEAK, 3),
+        "mfu": round(flops / dt / peaks(device)["bf16_flops"], 3),
         "final_loss": round(float(np.asarray(l)), 3),
+        "device": stamp,
     }
     print(json.dumps(result), flush=True)
     return result

@@ -45,8 +45,7 @@ def main() -> None:
     p.add_argument("--iters", type=int, default=50)
     args = p.parse_args()
 
-    from bluefog_tpu.runtime.config import example_devices
-    bf.init(devices=example_devices())
+    bf.init()
     n = bf.size()
     print(f"mesh: {n} rank(s) on {bf.mesh().devices.flat[0].platform}, "
           f"{args.size} f32/rank, {args.iters} iters")

@@ -109,18 +109,17 @@ def main() -> None:
 
     if not args.parse_only:
         import jax
-        import numpy as np
 
         import bench
 
-        opt, state, batch, sync = bench.setup()
+        opt, state, batch = bench.setup()
         for _ in range(3):  # compile + warm
             state, m = opt.step(state, batch)
-        sync(m)
+        jax.block_until_ready(m["loss"])
         with jax.profiler.trace(trace_dir):
             for _ in range(args.steps):
                 state, m = opt.step(state, batch)
-            sync(m)
+            jax.block_until_ready(m["loss"])
         import bluefog_tpu as bf
         bf.shutdown()
         print("trace written to", trace_dir)

@@ -44,7 +44,7 @@ def test_cp_example_trains(attention):
 
 @pytest.mark.slow  # interpret-mode flash is the slow path on CPU
 def test_flash_example_trains():
-    stdout = _run("flash")
+    stdout = _run("flash", extra=("--interpret",))
     assert "full-sequence on one chip" in stdout
     losses = [float(line.rsplit("loss ", 1)[1])
               for line in stdout.splitlines() if "loss " in line]
