@@ -149,6 +149,7 @@ from .optimizers import (
     DistributedWinPutOptimizer,
     DistributedPullGetOptimizer,
     DistributedPushSumOptimizer,
+    step_programs,
 )
 
 # parameter/optimizer-state sync utilities (reference: torch/utility.py)

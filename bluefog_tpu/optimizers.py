@@ -55,6 +55,7 @@ or ``loss_fn(params, model_state, batch) -> (loss, (model_state, aux))`` with
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import os
@@ -162,6 +163,15 @@ def _canon_loss(loss_fn, has_aux: bool, with_model_state: bool):
 _unstack = lambda t: jax.tree_util.tree_map(lambda x: x[0], t)
 _restack = lambda t: jax.tree_util.tree_map(lambda x: jnp.asarray(x)[None], t)
 
+# The step's device phases, as ``jax.named_scope``s in every fused step: each
+# op of the compiled program carries the scope it was traced under in its
+# ``op_name`` metadata, which is what xprof shows and what a trace reducer
+# joins on (docs/timeline.md, "Names in a device trace"). The unstacking and
+# restacking around them stay outside any scope.
+SCOPE_GRAD = "bf.grad"        # loss forward and backward, the gradient reduce
+SCOPE_UPDATE = "bf.update"    # the optax update and its application
+SCOPE_COMBINE = "bf.combine"  # whatever mixes parameters between ranks
+
 
 def build_fused_step(mesh, kind: str, loss, opt, plan: Optional[CombinePlan]):
     """Construct the fused per-step SPMD program for one comm strategy.
@@ -187,23 +197,26 @@ def build_fused_step(mesh, kind: str, loss, opt, plan: Optional[CombinePlan]):
         ms = _unstack(model_state)
         b = _unstack(batch)
 
-        (l, (new_ms, aux)), grads = jax.value_and_grad(
-            lambda p_: loss(p_, ms, b), has_aux=True)(p)
-        if kind == "gradient_allreduce":
-            grads = jax.tree_util.tree_map(
-                lambda g: lax.pmean(g, mesh.axis_names), grads)
-        updates, new_os = opt.update(grads, os_, p)
-        p = optax.apply_updates(p, updates)
-        if kind == "allreduce":
-            p = jax.tree_util.tree_map(
-                lambda x: lax.pmean(x, mesh.axis_names), p)
-        elif kind == "neighbor_allreduce":
-            p = spmd_combine(w, p, axis=axis, n=pn, shifts=shifts,
-                             use_gather=use_gather, stacked=False)
-        elif kind == "hierarchical":
-            p = jax.tree_util.tree_map(lambda x: lax.pmean(x, "local"), p)
-            p = spmd_combine(w, p, axis="machine", n=pn, shifts=shifts,
-                             use_gather=use_gather, stacked=False)
+        with jax.named_scope(SCOPE_GRAD):
+            (l, (new_ms, aux)), grads = jax.value_and_grad(
+                lambda p_: loss(p_, ms, b), has_aux=True)(p)
+            if kind == "gradient_allreduce":
+                grads = jax.tree_util.tree_map(
+                    lambda g: lax.pmean(g, mesh.axis_names), grads)
+        with jax.named_scope(SCOPE_UPDATE):
+            updates, new_os = opt.update(grads, os_, p)
+            p = optax.apply_updates(p, updates)
+        with jax.named_scope(SCOPE_COMBINE):
+            if kind == "allreduce":
+                p = jax.tree_util.tree_map(
+                    lambda x: lax.pmean(x, mesh.axis_names), p)
+            elif kind == "neighbor_allreduce":
+                p = spmd_combine(w, p, axis=axis, n=pn, shifts=shifts,
+                                 use_gather=use_gather, stacked=False)
+            elif kind == "hierarchical":
+                p = jax.tree_util.tree_map(lambda x: lax.pmean(x, "local"), p)
+                p = spmd_combine(w, p, axis="machine", n=pn, shifts=shifts,
+                                 use_gather=use_gather, stacked=False)
         metrics = {"loss": l, "aux": aux}
         return (_restack(p), _restack(new_os), _restack(new_ms),
                 _restack(metrics))
@@ -243,21 +256,23 @@ def build_sharded_step(mesh, loss, opt):
         ms = _unstack(model_state)
         b = _unstack(batch)
 
-        (l, (new_ms, aux)), grads = jax.value_and_grad(
-            lambda p_: loss(p_, ms, b), has_aux=True)(p)
-        flat_g, _ = jax.flatten_util.ravel_pytree(grads)
-        flat_p, unravel = jax.flatten_util.ravel_pytree(p)
-        total = flat_p.size
-        size = -(-total // n)
         me = lax.axis_index(axis)
-        g_shard = lax.psum_scatter(
-            jnp.pad(flat_g, (0, size * n - total)), axis,
-            scatter_dimension=0, tiled=True) / n
-        p_shard, _ = _flat_shard(flat_p, n, me)
-        updates, new_os = opt.update(g_shard, os_, p_shard)
-        new_flat = lax.all_gather(
-            optax.apply_updates(p_shard, updates), axis, tiled=True)
-        p_new = unravel(new_flat[:total])
+        with jax.named_scope(SCOPE_GRAD):
+            (l, (new_ms, aux)), grads = jax.value_and_grad(
+                lambda p_: loss(p_, ms, b), has_aux=True)(p)
+            flat_g, _ = jax.flatten_util.ravel_pytree(grads)
+            total = flat_g.size
+            size = -(-total // n)
+            g_shard = lax.psum_scatter(
+                jnp.pad(flat_g, (0, size * n - total)), axis,
+                scatter_dimension=0, tiled=True) / n
+        with jax.named_scope(SCOPE_UPDATE):
+            flat_p, unravel = jax.flatten_util.ravel_pytree(p)
+            p_shard, _ = _flat_shard(flat_p, n, me)
+            updates, new_os = opt.update(g_shard, os_, p_shard)
+            p_shard = optax.apply_updates(p_shard, updates)
+        with jax.named_scope(SCOPE_COMBINE):
+            p_new = unravel(lax.all_gather(p_shard, axis, tiled=True)[:total])
         metrics = {"loss": l, "aux": aux}
         return (_restack(p_new), _restack(new_os), _restack(new_ms),
                 _restack(metrics))
@@ -270,6 +285,50 @@ def build_sharded_step(mesh, loss, opt):
         out_specs=(spec, spec, spec, spec),
     )
     return jax.jit(mapped, donate_argnums=(1, 2, 3))
+
+
+def _shape_of(x) -> jax.ShapeDtypeStruct:
+    """What the jit cache keys on for one argument, without its buffer: an
+    array that was never placed (a host batch, an uncommitted one) leaves its
+    sharding to the program, as it does when the step is called."""
+    aval = jax.typeof(x)
+    placed = getattr(x, "committed", False)
+    return jax.ShapeDtypeStruct(aval.shape, aval.dtype, weak_type=aval.weak_type,
+                                sharding=x.sharding if placed else None)
+
+
+class StepProgram:
+    """One step program a fused optimizer built, as :func:`step_programs`
+    lists it: ``name`` of the optimizer, its cache ``key`` (whether the step
+    communicates, and the plan's edge shifts) and, on demand, the compiled
+    HLO. Holds the jitted function and the arguments' shapes and shardings
+    (the small host weight matrix as it is), no device array."""
+
+    def __init__(self, name: str, key, fn, args) -> None:
+        self.name, self.key, self._fn = name, key, fn
+        self._avals = (args[0],) + jax.tree_util.tree_map(_shape_of, tuple(args[1:]))
+
+    def hlo_text(self) -> str:
+        """The optimized HLO of the program as the device runs it: every
+        instruction under the name a profiler trace gives its op, with
+        ``metadata={op_name="...bf.update/..."}``. Lowers from the cached
+        trace (the loss is not traced again) and finds the executable this
+        process already has (0.04-0.3 s on a v5e, no compile); never called on
+        the step path."""
+        return self._fn.lower(*self._avals).compile().as_text()
+
+    def __repr__(self) -> str:
+        return f"StepProgram({self.name!r}, key={self.key!r})"
+
+
+# the last programs any optimizer of this process built, oldest first; it
+# outlives the optimizers so that a reader can run after they are dropped
+_STEP_PROGRAMS: "collections.deque[StepProgram]" = collections.deque(maxlen=16)
+
+
+def step_programs() -> list:
+    """The step programs built in this process (the last 16), oldest first."""
+    return list(_STEP_PROGRAMS)
 
 
 class _FusedOptimizer:
@@ -332,30 +391,40 @@ class _FusedOptimizer:
             return None, np.zeros((1, 1), np.float32), ("none",)
         return plan, plan.weight_array(), (plan.shifts, plan.use_gather)
 
+    def _compile(self, key, plan, do_comm: bool, args):
+        """A cache miss: build the step for ``key``, keep it, and register it
+        with the shapes of the ``args`` it is about to be called with."""
+        with timeline_context(self.name, "BUILD"):
+            fn = self._step_cache[key] = self._build(key, plan, do_comm)
+        _STEP_PROGRAMS.append(StepProgram(self.name, key, fn, args))
+        return fn
+
     def step(self, state: TrainState, batch) -> Tuple[TrainState, Dict]:
         """One training iteration over the whole mesh."""
         k = self.num_steps_per_communication
         self._counter += 1
         do_comm = (self._counter % k) == 0
-        plan, w, wkey = self._weights_and_key() if do_comm else (None, np.zeros((1, 1), np.float32), ("skip",))
-        key = (do_comm,) + wkey
-        fn = self._step_cache.get(key)
-        if fn is None:
-            fn = self._build(key, plan, do_comm)
-            self._step_cache[key] = fn
-        _perf_gate_delay()
-        try:
-            with timeline_context(self.name, "STEP"), \
-                    _metrics.timed("opt.step_sec"), \
-                    _flight.recorder().span("opt.step", b=self._counter):
-                params, opt_state, model_state, metrics = fn(
-                    w, state.params, state.opt_state, state.model_state,
-                    batch)
-        except Exception as exc:
-            # black-box dump before the stack unwinds: the ring's tail IS
-            # the postmortem evidence (rate-limited; never raises)
-            _flight.fatal("opt.step", exc)
-            raise
+        # STEP is the host side of the whole step on the profiler's clock;
+        # dispatch is STEP - PLAN - BUILD (BUILD opens on a cache miss only)
+        with timeline_context(self.name, "STEP"):
+            with timeline_context(self.name, "PLAN"):
+                plan, w, wkey = self._weights_and_key() if do_comm else (
+                    None, np.zeros((1, 1), np.float32), ("skip",))
+            key = (do_comm,) + wkey
+            args = (w, state.params, state.opt_state, state.model_state, batch)
+            fn = self._step_cache.get(key)
+            if fn is None:
+                fn = self._compile(key, plan, do_comm, args)
+            _perf_gate_delay()
+            try:
+                with _metrics.timed("opt.step_sec"), \
+                        _flight.recorder().span("opt.step", b=self._counter):
+                    params, opt_state, model_state, metrics = fn(*args)
+            except Exception as exc:
+                # black-box dump before the stack unwinds: the ring's tail IS
+                # the postmortem evidence (rate-limited; never raises)
+                _flight.fatal("opt.step", exc)
+                raise
         _metrics.gauge("opt.step").set(self._counter)
         return TrainState(params, opt_state, model_state), metrics
 
@@ -926,13 +995,12 @@ class _WindowOptimizer(_FusedOptimizer):
 
     def _local_step(self, state, batch):
         key = (False, "none")
+        args = (np.zeros((1, 1), np.float32),
+                state.params, state.opt_state, state.model_state, batch)
         fn = self._step_cache.get(key)
         if fn is None:
-            fn = self._build(key, None, False)
-            self._step_cache[key] = fn
-        params, opt_state, model_state, metrics = fn(
-            np.zeros((1, 1), np.float32),
-            state.params, state.opt_state, state.model_state, batch)
+            fn = self._compile(key, None, False, args)
+        params, opt_state, model_state, metrics = fn(*args)
         return TrainState(params, opt_state, model_state), metrics
 
     def _gossip(self, buffers):  # packed [n, total] buffers -> mixed buffers

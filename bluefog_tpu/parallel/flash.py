@@ -32,6 +32,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
 
+# The three kernels as ``jax.named_scope``s, each around exactly one
+# ``pallas_call``: the scope, not a function's name, is what a trace reducer
+# finds the kernel by (docs/timeline.md, "Names in a device trace").
+SCOPE_FWD = "bf.flash.fwd"
+SCOPE_DQ = "bf.flash.dq"
+SCOPE_DKV = "bf.flash.dkv"
+
 
 def _tile(s: int, candidates) -> int:
     """Largest candidate tile evenly dividing s (1 is always a candidate)."""
@@ -193,10 +200,12 @@ def flash_block(q, k, v, q_off, k_off, *, causal: bool = True,
     params = {} if interpret else {
         "compiler_params": pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))}
-    o, m, l = pl.pallas_call(
-        kernel, grid_spec=grid_spec, out_shape=out_shape,
-        interpret=interpret, **params,
-    )(offs, bhsd(q), bhsd(k), bhsd(v))
+    qkv = (bhsd(q), bhsd(k), bhsd(v))
+    with jax.named_scope(SCOPE_FWD):
+        o, m, l = pl.pallas_call(
+            kernel, grid_spec=grid_spec, out_shape=out_shape,
+            interpret=interpret, **params,
+        )(offs, *qkv)
 
     def sbhd(x):  # [B*H, Sq, C] -> [B, Sq, H, C]
         return x.reshape((B, H) + x.shape[1:]).transpose(0, 2, 1, 3)
@@ -393,12 +402,13 @@ def flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off, *,
             pl.BlockSpec((1, tq, D), lambda bh, qi, kj, o: (bh, qi, 0)),
         ],
     )
-    (dq,) = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, scale=scale),
-        grid_spec=dq_spec,
-        out_shape=(jax.ShapeDtypeStruct((B * H, Sq, D), jnp.float32, **kw),),
-        interpret=interpret, **params,
-    )(*operands)
+    with jax.named_scope(SCOPE_DQ):
+        (dq,) = pl.pallas_call(
+            functools.partial(_dq_kernel, causal=causal, scale=scale),
+            grid_spec=dq_spec,
+            out_shape=(jax.ShapeDtypeStruct((B * H, Sq, D), jnp.float32, **kw),),
+            interpret=interpret, **params,
+        )(*operands)
 
     # pass 2: dk/dv (Q innermost, accumulates into the k tile's outputs)
     dkv_spec = pltpu.PrefetchScalarGridSpec(
@@ -418,15 +428,16 @@ def flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off, *,
             pl.BlockSpec((1, tk, D), lambda bh, kj, qi, o: (bh, kj, 0)),
         ],
     )
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, scale=scale),
-        grid_spec=dkv_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((B * H, Sk, D), jnp.float32, **kw),
-            jax.ShapeDtypeStruct((B * H, Sk, D), jnp.float32, **kw),
-        ),
-        interpret=interpret, **params,
-    )(*operands)
+    with jax.named_scope(SCOPE_DKV):
+        dk, dv = pl.pallas_call(
+            functools.partial(_dkv_kernel, causal=causal, scale=scale),
+            grid_spec=dkv_spec,
+            out_shape=(
+                jax.ShapeDtypeStruct((B * H, Sk, D), jnp.float32, **kw),
+                jax.ShapeDtypeStruct((B * H, Sk, D), jnp.float32, **kw),
+            ),
+            interpret=interpret, **params,
+        )(*operands)
 
     def sbhd(x, s):
         return x.reshape((B, H, s, D)).transpose(0, 2, 1, 3)

@@ -441,7 +441,9 @@ _HELP_EXACT: Dict[str, str] = {
     "serve.publish_sec": "wall seconds of the last serving snapshot "
                          "publish (encode + stripe writes + fence)",
     "opt.step": "optimizer step counter of this rank",
-    "opt.step_sec": "wall seconds per optimizer step",
+    "opt.step_sec": "host seconds dispatching one optimizer step's program "
+                    "(asynchronous: the device runs on after it; not the "
+                    "step's duration)",
     "opt.pack_sec": "seconds packing the fusion buffer per gossip step",
     "opt.gossip_sec": "seconds in window gossip ops per step",
     "opt.unpack_sec": "seconds unpacking the fusion buffer per step",
