@@ -292,6 +292,57 @@ def count_step_collectives(kind: str, n: int, **kw) -> Dict[str, int]:
     return collective_counts(lower_train_step(kind, n, **kw))
 
 
+_HLO_ENTRY = re.compile(r"^ENTRY\b.*$", re.M)
+_HLO_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s+(?:\([^=]*?\)|\S+)\s+([a-z][\w\-]*)\((.*)$")
+_HLO_FIRST_OPERAND = re.compile(r"\s*(?:[^%(),]*\s)?%?([\w.\-]+)")
+# ops that hand a buffer on without computing it: a permute's operand is
+# followed through them to the instruction that wrote the value
+_HLO_PASS_THROUGH = ("get-tuple-element", "bitcast", "copy", "copy-start",
+                     "copy-done")
+
+
+def permute_start_slack(hlo_text: str) -> Dict[str, object]:
+    """Where the scheduler put each ``collective-permute-start`` of a compiled
+    (scheduled) HLO module's entry computation.
+
+    ``slack``: for every start, in schedule order, the instructions between
+    the one that produced its operand (followed back through
+    ``get-tuple-element`` / ``bitcast`` / copies) and the start itself -- 1 is
+    "right behind its producer", hundreds mean the start was held back.
+    ``max_in_flight``: the most permutes started and not yet done at any point
+    of the schedule. A step whose permutes may start where their leaf's update
+    ends has ``max_in_flight == starts`` and small slacks; one that is held to
+    a cap of k in flight has ``max_in_flight == k`` and its starts sunk behind
+    the backward (docs/timeline.md, "Reading a step's schedule")."""
+    position: Dict[str, int] = {}
+    opcode: Dict[str, str] = {}
+    operand: Dict[str, str] = {}
+    slack, in_flight, most = [], 0, 0
+    entry = _HLO_ENTRY.search(hlo_text)
+    body = hlo_text[entry.end():] if entry else ""
+    for line in body.splitlines():
+        if line.startswith("}"):
+            break
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None:
+            continue
+        name, op, rest = m.groups()
+        first = _HLO_FIRST_OPERAND.match(rest)
+        position[name], opcode[name] = len(position), op
+        operand[name] = first.group(1) if first else ""
+        if op == "collective-permute-start":
+            src = operand[name]
+            while opcode.get(src) in _HLO_PASS_THROUGH:
+                src = operand[src]
+            slack.append(position[name] - position.get(src, 0))
+            in_flight += 1
+            most = max(most, in_flight)
+        elif op == "collective-permute-done":
+            in_flight -= 1
+    return {"starts": len(slack), "max_in_flight": most, "slack": slack}
+
+
 # ---------------------------------------------------------------------------
 # Wire-bytes model
 # ---------------------------------------------------------------------------

@@ -11,8 +11,9 @@ benchmarks, with random weights from a seed:
   rank ``r``'s slice of every state leaf lives on device ``r``.
 * ``gossip`` (more than one chip): ``bf.neighbor_allreduce`` against the
   topology's weight matrix, one ResNet-50 step per shift set of the dynamic
-  one-peer Expo-2 schedule, the hierarchical optimizer on the host's machine
-  mesh, and two ``DistributedWinPutOptimizer`` steps on a small MLP.
+  one-peer Expo-2 schedule and where the compiler put each program's permutes
+  (``scaling.permute_start_slack``), the hierarchical optimizer on the host's
+  machine mesh, and two ``DistributedWinPutOptimizer`` steps on a small MLP.
 * ``lm_flash``: the three compiled flash-attention kernels against the dense
   f32-softmax reference, then the 4-layer d_model-2048 LM at 8192 tokens per
   chip through ``bf.DistributedNeighborAllreduceOptimizer.step``.
@@ -49,10 +50,12 @@ import optax  # noqa: E402
 
 import bluefog_tpu as bf  # noqa: E402
 from bluefog_tpu.models import MLP, ResNet50, TransformerLM  # noqa: E402
+from bluefog_tpu.optimizers import PERMUTES_IN_FLIGHT_MAX  # noqa: E402
 from bluefog_tpu.parallel.context import reference_attention  # noqa: E402
 from bluefog_tpu.parallel.flash import flash_attention  # noqa: E402
 from bluefog_tpu.runtime import native  # noqa: E402
 from bluefog_tpu.runtime.config import compile_cache_dir  # noqa: E402
+from bluefog_tpu.scaling import permute_start_slack  # noqa: E402
 
 if os.path.dirname(os.path.dirname(os.path.realpath(bf.__file__))) != HERE:
     raise SystemExit(
@@ -200,6 +203,20 @@ def phase_gossip(opt, state, batch):
         raise RuntimeError(f"expected {rounds} distinct shift sets: {seen}")
     dynamic_s = time.perf_counter() - t0
     _check_layout(state.params, "params")
+    # where the compiler put those programs' permutes: all that the step's
+    # compile option allows may be in flight at once, and the starts sit
+    # beside the updates that feed them (docs/timeline.md)
+    schedules = {}
+    for program in bf.step_programs()[-rounds:]:
+        found = permute_start_slack(program.hlo_text())
+        if found["max_in_flight"] < min(found["starts"], PERMUTES_IN_FLIGHT_MAX):
+            raise RuntimeError(
+                f"{program!r}: {found['starts']} permutes, but at most "
+                f"{found['max_in_flight']} in flight")
+        schedules[str(program.key[1])] = {
+            "permutes": found["starts"],
+            "max_in_flight": found["max_in_flight"],
+            "median_start_slack": int(np.median(found["slack"]))}
 
     # 3. small MLP: the hierarchical optimizer on this host's machine mesh,
     # and the window plane's mailbox programs
@@ -227,6 +244,7 @@ def phase_gossip(opt, state, batch):
         "neighbor_allreduce": "equals the weight matrix product to 1e-6",
         "dynamic_one_peer_programs": rounds,
         "dynamic_s_total": round(dynamic_s, 2),
+        "dynamic_one_peer_schedules": schedules,
         "machine_mesh": list(bf.machine_mesh().devices.shape),
         "hierarchical_loss": round(float(np.mean(np.asarray(hm["loss"]))), 4),
         "win_put_loss": round(float(np.mean(np.asarray(wm["loss"]))), 4),
