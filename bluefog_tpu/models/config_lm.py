@@ -1,0 +1,330 @@
+"""A decoder-only LM whose block is read from a configuration.
+
+Where :class:`~bluefog_tpu.models.TransformerLM` fixes its block in code, this
+one takes an :class:`LMConfig` -- the keys of a DeepSeek-V3 style
+``config.json`` -- and builds, layer by layer:
+
+  attention  ``"latent"``: multi-head latent attention (two low-rank paths with
+             an RMSNorm on each latent, a no-rope part per head and one rope
+             part shared by all heads, a q.k width of ``qk_nope + qk_rope`` and
+             a narrower v), or ``"equal"``: equal-width heads from one fused
+             projection. Both rotate pairs ``(2i, 2i+1)`` (``rope_interleave``)
+             or halves.
+  FFN        a SwiGLU of ``intermediate_size`` in the ``first_k_dense_replace``
+             leading layers and wherever there are no experts; otherwise
+             :class:`~bluefog_tpu.parallel.expert.RoutedExperts`: top-k of
+             ``n_routed_experts`` by sigmoid score plus a choice-only bias
+             (kept in the ``"routing"`` collection and moved by the
+             auxiliary-loss-free balancing rule, not by the optimizer),
+             the experts ``experts_held`` computed here, a shared expert.
+  MTP        ``num_nextn_predict_layers`` multi-token-prediction modules after
+             the last layer, sharing the embedding and the head.
+
+Every norm is an RMSNorm with a learned scale, there are no biases, and the
+residuals are sequential. Parameters are float32; ``dtype`` is the compute
+type; router scores and the top-k are float32 whatever it is.
+
+The parts run under ``jax.named_scope``s a trace reducer can find them by:
+``bf.mla.proj`` (projections, latent norms and rope), the three ``bf.flash.*``
+of the attention function, ``bf.moe.route`` / ``bf.moe.experts`` /
+``bf.moe.shared``, ``bf.ffn.dense``, ``bf.lm.head`` and ``bf.mtp`` around a
+whole MTP module.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from functools import partial
+from typing import Any, Callable, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+import optax
+from flax import linen as nn
+
+from ..parallel.context import reference_attention
+from ..parallel.expert import ROUTING, RoutedExperts, SwiGLU
+
+SCOPE_MLA_PROJ = "bf.mla.proj"
+SCOPE_DENSE_FFN = "bf.ffn.dense"
+SCOPE_HEAD = "bf.lm.head"
+SCOPE_MTP = "bf.mtp"
+
+
+@dataclasses.dataclass(frozen=True)
+class LMConfig:
+    """The block, under the names a DeepSeek-V3 style ``config.json`` gives it."""
+
+    vocab_size: int
+    hidden_size: int
+    num_hidden_layers: int
+    num_attention_heads: int
+    intermediate_size: int
+    attention: str = "latent"            # "latent" | "equal"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_theta: float = 10000.0
+    rope_interleave: bool = True
+    rms_norm_eps: float = 1e-6
+    first_k_dense_replace: int = 0
+    n_routed_experts: int = 0            # the router's width; 0: every layer is dense
+    num_experts_per_tok: int = 0
+    moe_intermediate_size: int = 0
+    n_shared_experts: int = 1
+    scoring_func: str = "sigmoid"
+    routed_scaling_factor: float = 1.0
+    experts_held: Optional[Tuple[int, int]] = None  # ids computed here; None: all
+    bias_update_speed: float = 0.0       # the balancing rule's step; 0: the bias stays
+    num_nextn_predict_layers: int = 0
+
+    @classmethod
+    def from_dict(cls, doc: dict, **overrides) -> "LMConfig":
+        """From the keys of a ``config.json`` (others are ignored)."""
+        names = {f.name for f in dataclasses.fields(cls)}
+        return cls(**{**{k: v for k, v in doc.items() if k in names}, **overrides})
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return self.experts_held or (0, self.n_routed_experts)
+
+    def is_expert_layer(self, layer: int) -> bool:
+        return self.n_routed_experts > 0 and layer >= self.first_k_dense_replace
+
+
+def rope(x, positions, theta: float, interleave: bool):
+    """Rotary embedding of ``x [B, S, H, D]`` at ``positions [S]`` or ``[B, S]``:
+    pairs ``(2i, 2i+1)`` if ``interleave``, else ``(i, i + D/2)``."""
+    d2 = x.shape[-1] // 2
+    freqs = theta ** (-jnp.arange(d2, dtype=jnp.float32) / d2)
+    if positions.ndim == 1:
+        positions = positions[None]
+    angle = positions[..., None].astype(jnp.float32) * freqs       # [B, S, d2]
+    sin, cos = jnp.sin(angle)[:, :, None, :], jnp.cos(angle)[:, :, None, :]
+    xf = x.astype(jnp.float32)
+    if interleave:
+        pairs = xf.reshape(xf.shape[:-1] + (d2, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+        return out.reshape(x.shape).astype(x.dtype)
+    x1, x2 = xf[..., :d2], xf[..., d2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                           axis=-1).astype(x.dtype)
+
+
+class Attention(nn.Module):
+    """The attention residual's inner part: normed input in, ``[B, S, d]`` out."""
+
+    cfg: LMConfig
+    dtype: Any
+    attn_fn: Callable
+
+    @nn.compact
+    def __call__(self, h, positions):
+        cfg = self.cfg
+        heads, d = cfg.num_attention_heads, h.shape[-1]
+        dense = partial(nn.Dense, dtype=self.dtype, param_dtype=jnp.float32, use_bias=False)
+        norm = partial(nn.RMSNorm, epsilon=cfg.rms_norm_eps, dtype=self.dtype,
+                       param_dtype=jnp.float32)
+        turn = partial(rope, positions=positions, theta=cfg.rope_theta,
+                       interleave=cfg.rope_interleave)
+        lead = h.shape[:2]
+        with jax.named_scope(SCOPE_MLA_PROJ):
+            if cfg.attention == "equal":
+                q, k, v = jnp.split(dense(3 * d, name="qkv")(h), 3, axis=-1)
+                q, k, v = (t.reshape(lead + (heads, d // heads)) for t in (q, k, v))
+                q, k = turn(q), turn(k)
+            else:
+                nope, rot, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+                c_q = norm(name="q_a_norm")(dense(cfg.q_lora_rank, name="q_a")(h))
+                q = dense(heads * (nope + rot), name="q_b")(c_q).reshape(
+                    lead + (heads, nope + rot))
+                kv_a = dense(cfg.kv_lora_rank + rot, name="kv_a")(h)
+                c_kv = norm(name="kv_a_norm")(kv_a[..., :cfg.kv_lora_rank])
+                k_rot = turn(kv_a[..., None, cfg.kv_lora_rank:])       # one head, shared
+                kv = dense(heads * (nope + dv), name="kv_b")(c_kv).reshape(
+                    lead + (heads, nope + dv))
+                q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], axis=-1)
+                k = jnp.concatenate(
+                    [kv[..., :nope], jnp.broadcast_to(k_rot, lead + (heads, rot))], axis=-1)
+                v = kv[..., nope:]
+        a = self.attn_fn(q, k, v)
+        with jax.named_scope(SCOPE_MLA_PROJ):
+            return dense(d, name="o")(a.reshape(lead + (-1,)))
+
+
+class Layer(nn.Module):
+    """One pre-norm block: attention, then a dense SwiGLU or the expert layer."""
+
+    cfg: LMConfig
+    experts: bool
+    dtype: Any
+    attn_fn: Callable
+    interpret: bool = False
+
+    @nn.compact
+    def __call__(self, x, positions, choice=None):
+        cfg = self.cfg
+        norm = partial(nn.RMSNorm, epsilon=cfg.rms_norm_eps, dtype=self.dtype,
+                       param_dtype=jnp.float32)
+        with jax.named_scope(SCOPE_MLA_PROJ):
+            h = norm(name="attn_norm")(x)
+        x = x + Attention(cfg, self.dtype, self.attn_fn, name="attn")(h, positions)
+        if self.experts:
+            h = norm(name="ffn_norm")(x)
+            return x + RoutedExperts(
+                num_experts=cfg.n_routed_experts, experts_per_token=cfg.num_experts_per_tok,
+                d_ff=cfg.moe_intermediate_size, held=cfg.held, n_shared=cfg.n_shared_experts,
+                scoring=cfg.scoring_func, scaling=cfg.routed_scaling_factor,
+                bias_update_speed=cfg.bias_update_speed, dtype=self.dtype, interpret=self.interpret, name="ffn")(h, choice)
+        with jax.named_scope(SCOPE_DENSE_FFN):
+            h = norm(name="ffn_norm")(x)
+            return x + SwiGLU(cfg.intermediate_size, self.dtype, name="ffn")(h)
+
+
+class ConfigLM(nn.Module):
+    """Causal LM built from an :class:`LMConfig`.
+
+    ``model.apply({"params": p}, tokens)`` gives the logits ``[B, S, V]`` in
+    float32; with MTP modules it gives ``(logits, mtp_logits)``, where
+    ``mtp_logits[k][:, i]`` predicts token ``i + k + 2`` from the trunk's
+    output at ``i`` and the embeddings of tokens ``i + 1 .. i + k + 1``
+    (``next_tokens [B, S]`` is token ``i + 1`` at position ``i``; by default
+    the sequence rolled by one, whose last position wraps).
+
+    ``attn_fn(q, k, v) -> out`` defaults to dense causal attention;
+    ``partial(flash_attention, causal=True)`` is the kernel path. ``choices``
+    (one ``[B, S, k]`` array of expert ids per expert layer, forward order)
+    forces the experts each token takes. Every expert layer sows its counters
+    and its choice (``mutable=["intermediates"]``; :func:`moe_counters`) and
+    keeps its routing bias in the ``"routing"`` collection, which ``init``
+    returns beside ``"params"`` and ``apply`` takes beside them.
+    """
+
+    cfg: LMConfig
+    dtype: Any = jnp.float32
+    attn_fn: Optional[Callable] = None
+    interpret: bool = False  # Pallas interpreter for the grouped products (CPU tests)
+
+    def setup(self):
+        # setattr gives every submodule its name (flax takes none in setup)
+        cfg = self.cfg
+        attn = self.attn_fn or partial(reference_attention, causal=True)
+        norm = partial(nn.RMSNorm, epsilon=cfg.rms_norm_eps, dtype=self.dtype,
+                       param_dtype=jnp.float32)
+        layer = partial(Layer, cfg, dtype=self.dtype, attn_fn=attn, interpret=self.interpret)
+        self.embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=self.dtype,
+                              param_dtype=jnp.float32)
+        for i in range(cfg.num_hidden_layers):
+            setattr(self, f"layer_{i}", layer(experts=cfg.is_expert_layer(i)))
+        self.final_norm = norm()
+        self.lm_head = nn.Dense(cfg.vocab_size, dtype=self.dtype, param_dtype=jnp.float32,
+                                use_bias=False)
+        # an MTP module: norms of the trunk's output and of the next token's
+        # embedding, a 2d -> d projection, one block, its own final norm
+        for k in range(cfg.num_nextn_predict_layers):
+            setattr(self, f"mtp_{k}_h_norm", norm())
+            setattr(self, f"mtp_{k}_e_norm", norm())
+            setattr(self, f"mtp_{k}_proj", nn.Dense(
+                cfg.hidden_size, dtype=self.dtype, param_dtype=jnp.float32, use_bias=False))
+            setattr(self, f"mtp_{k}_block", layer(experts=cfg.n_routed_experts > 0))
+            setattr(self, f"mtp_{k}_final_norm", norm())
+
+    def _head(self, x, final_norm):
+        with jax.named_scope(SCOPE_HEAD):
+            return self.lm_head(final_norm(x)).astype(jnp.float32)
+
+    def __call__(self, tokens, positions=None, next_tokens=None,
+                 choices: Optional[Sequence] = None):
+        cfg = self.cfg
+        if positions is None:
+            positions = jnp.arange(tokens.shape[1])
+        choices = iter(choices) if choices is not None else None
+        take = lambda block: next(choices) if block.experts and choices is not None else None
+        x = self.embed(tokens)
+        for i in range(cfg.num_hidden_layers):
+            block = getattr(self, f"layer_{i}")
+            x = block(x, positions, take(block))
+        logits = self._head(x, self.final_norm)
+        if not cfg.num_nextn_predict_layers:
+            return logits
+        if next_tokens is None:
+            next_tokens = jnp.roll(tokens, -1, axis=1)
+        mtp_logits = []
+        for k in range(cfg.num_nextn_predict_layers):
+            part = lambda name: getattr(self, f"mtp_{k}_{name}")
+            with jax.named_scope(SCOPE_MTP):
+                ahead = self.embed(jnp.roll(next_tokens, -k, axis=1))
+                x = part("proj")(jnp.concatenate(
+                    [part("h_norm")(x), part("e_norm")(ahead)], axis=-1))
+                x = part("block")(x, positions, take(part("block")))
+                mtp_logits.append(self._head(x, part("final_norm")))
+        return logits, tuple(mtp_logits)
+
+
+def _sowed(intermediates, name: str) -> list:
+    """What every expert layer sowed under ``name``, in forward order (the
+    trunk's layers by index, then the MTP modules')."""
+    found = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(
+            intermediates, is_leaf=lambda x: isinstance(x, tuple))[0]:
+        keys = [getattr(k, "key", None) for k in path]
+        if keys[-1] == name:
+            found[keys[0]] = leaf[0]
+    order = lambda module: (module.startswith("mtp_"), int(module.split("_")[1]))
+    return [found[module] for module in sorted(found, key=order)]
+
+
+def moe_counters(intermediates) -> dict:
+    """The expert layers' counters of one forward pass, from the collection a
+    ``mutable=["intermediates"]`` apply returns: ``rows_routed`` and
+    ``rows_overflowed`` summed over the layers, ``load_max_over_mean`` the
+    largest of them. Empty for a model without expert layers."""
+    layers = _sowed(intermediates, "moe_counters")
+    if not layers:
+        return {}
+    return {"rows_routed": sum(c["rows_routed"] for c in layers),
+            "rows_overflowed": sum(c["rows_overflowed"] for c in layers),
+            "load_max_over_mean": jnp.max(jnp.stack(
+                [c["load_max_over_mean"] for c in layers]))}
+
+
+def moe_choices(intermediates) -> list:
+    """Every expert layer's chosen ids ``[B, S, k]``, in forward order: what
+    ``ConfigLM``'s ``choices`` takes."""
+    return _sowed(intermediates, "moe_choice")
+
+
+def next_token_loss(model: ConfigLM, mtp_weight: float = 0.3):
+    """``loss_fn(params, routing, batch) -> (loss, (routing, counters))`` for
+    the ``bf.Distributed*Optimizer``s with ``with_model_state=True``: the mean
+    cross-entropy of the next token, plus ``mtp_weight`` times that of each
+    MTP module's. ``routing`` is the model's ``"routing"`` collection (the
+    expert layers' biases; ``{}`` without expert layers) and goes to
+    ``opt.init(params, model_state=routing)``; it comes back moved by the
+    balancing rule. ``batch`` is ``(tokens, targets)`` or, with MTP,
+    ``(tokens, targets, mtp_targets)``: ``targets`` are the tokens one on
+    (they are also what the first MTP module embeds), ``mtp_targets`` two on.
+    ``opt.step``'s ``metrics["aux"]`` carries :func:`moe_counters`."""
+    ce = optax.softmax_cross_entropy_with_integer_labels
+
+    def loss_fn(params, routing, batch):
+        tokens, targets = batch[0], batch[1]
+        out, state = model.apply({"params": params, ROUTING: routing}, tokens,
+                                 next_tokens=targets, mutable=["intermediates", ROUTING])
+        if not model.cfg.num_nextn_predict_layers:
+            loss = ce(out, targets).mean()
+        else:
+            logits, mtp_logits = out
+            with jax.named_scope(SCOPE_HEAD):
+                loss = ce(logits, targets).mean()
+            with jax.named_scope(SCOPE_MTP):
+                for k, extra in enumerate(mtp_logits):
+                    loss = loss + mtp_weight * ce(
+                        extra, jnp.roll(batch[2], -k, axis=1)).mean()
+        return loss, (state.get(ROUTING, routing),
+                      moe_counters(state.get("intermediates", {})))
+
+    return loss_fn

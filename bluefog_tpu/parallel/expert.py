@@ -12,6 +12,14 @@ scaled by the gate probability; tokens beyond an expert's capacity are
 dropped (output zero) — choose ``capacity_factor >= num_experts`` to make
 dropping impossible, which is how the exactness tests pin the SPMD path to
 the dense oracle (``SwitchFFN``'s plain ``__call__``).
+
+:class:`RoutedExperts` is the layer of a fine-grained mixture as it is
+deployed (top-k of hundreds of experts, sigmoid scores with a choice-only
+bias, a shared expert, no token dropped): it is told which experts it holds,
+scores all of them, and computes its own experts' part of the result with
+grouped matrix products over ragged per-expert row counts. On one chip it
+runs without an exchange; the all-to-all that would bring other chips'
+tokens is not here.
 """
 
 from __future__ import annotations
@@ -25,7 +33,11 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax, shard_map
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from .flash import _dot_prec, _tile, _vma
 
 
 def ep_mesh(n_experts: int, devices: Optional[Sequence] = None) -> Mesh:
@@ -330,3 +342,377 @@ def ep_apply(params, x, mesh: Mesh, capacity_factor: float = 2.0,
     x = jax.device_put(x, NamedSharding(mesh, P("expert")))
     return _ep_fn(mesh, n, capacity, jnp.dtype(dtype or x.dtype).name)(
         placed["gate"], placed["up"], placed["down"], x)
+
+
+# ---------------------------------------------------------------------------
+# Top-k routing over many experts, the chip's share of them held here
+# ---------------------------------------------------------------------------
+
+# ``jax.named_scope``s a trace reducer finds the layer's parts by
+SCOPE_ROUTE = "bf.moe.route"      # scores, top-k, sort, gather, weighted scatter back
+SCOPE_EXPERTS = "bf.moe.experts"  # the grouped products
+SCOPE_SHARED = "bf.moe.shared"    # the shared expert, computed on every chip in full
+# the flax collection of what routing keeps beside the parameters: the bias
+ROUTING = "routing"
+
+
+# Rows of one tile of the held experts' buffer. Every expert's rows start on a
+# tile, so a tile multiplies one expert's weights; an expert's last tile is
+# part padding. 128 is the v5e matrix unit's own height: at one chip's own
+# tokens a held expert has a few hundred rows a layer, and a taller tile
+# would be mostly padding.
+ROW_TILE = 128
+
+
+def _last(i, used):
+    """The tile the blocks of grid step ``i`` are: ``i``, or the last one in use."""
+    return jnp.minimum(i, used[0] - 1)
+
+
+def _interpreter_cannot(*arrays) -> bool:
+    """Inside ``shard_map`` the Pallas interpreter cannot run these kernels:
+    it evaluates an index map's read of a prefetched scalar with a varying
+    operand and an unvarying index, which the vma check refuses ("please open
+    an issue"). The compiled kernels are not concerned. There -- a CPU mesh
+    stepping a toy model through ``opt.step`` -- the same products are written
+    with XLA ops below; outside ``shard_map`` the interpreter runs the kernels."""
+    return bool(_vma(*arrays))
+
+
+def _tiles_in_use(tile_expert, tiles_used):
+    return jnp.arange(tile_expert.shape[0]) < tiles_used[0]
+
+
+def _rows_kernel(used_ref, expert_ref, x_ref, w_ref, o_ref, *, transpose: bool):
+    """One row tile times its expert's matrix (``w`` or its transpose)."""
+    del expert_ref  # the index maps read it
+
+    @pl.when(pl.program_id(0) < used_ref[0])
+    def _():
+        contract = (((1,), (1,)), ((), ())) if transpose else (((1,), (0,)), ((), ()))
+        o_ref[...] = lax.dot_general(
+            x_ref[...], w_ref[0], contract, preferred_element_type=jnp.float32,
+            precision=_dot_prec(x_ref.dtype)).astype(o_ref.dtype)
+
+
+def _rows_matmul(rows, weights, tile_expert, tiles_used, transpose: bool, interpret: bool):
+    """``rows[tile i] @ weights[tile_expert[i]]`` (or ``@ weights[...].T``) for
+    the first ``tiles_used`` tiles; the tiles after them are skipped -- no
+    product, and no block moves, because their index maps stay on the last
+    tile in use -- and their rows of the result are never written."""
+    m, k = rows.shape
+    n = weights.shape[1] if transpose else weights.shape[2]
+    if interpret and _interpreter_cannot(rows, weights, tile_expert):
+        out = jnp.einsum("tik,tnk->tin" if transpose else "tik,tkn->tin",
+                         rows.reshape(-1, ROW_TILE, k), weights[tile_expert],
+                         precision=_dot_prec(rows.dtype))
+        return jnp.where(_tiles_in_use(tile_expert, tiles_used)[:, None, None], out,
+                         jnp.nan).reshape(m, n).astype(rows.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(m // ROW_TILE,),
+        in_specs=[
+            pl.BlockSpec((ROW_TILE, k), lambda i, used, expert: (_last(i, used), 0)),
+            pl.BlockSpec((1,) + weights.shape[1:],
+                         lambda i, used, expert: (expert[_last(i, used)], 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((ROW_TILE, n), lambda i, used, expert: (_last(i, used), 0)))
+    params = {} if interpret else {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",), vmem_limit_bytes=32 << 20)}
+    return pl.pallas_call(
+        functools.partial(_rows_kernel, transpose=transpose), grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), rows.dtype, **_vma(rows, weights)),
+        interpret=interpret, **params)(tiles_used, tile_expert, rows, weights)
+
+
+def _weights_kernel(used_ref, expert_ref, x_ref, g_ref, o_ref, acc_ref):
+    """``x[tile]^T @ g[tile]`` summed over an expert's tiles, which are
+    consecutive: the accumulator starts at an expert's first tile and is
+    written out at its last."""
+    i = pl.program_id(1)
+    used = used_ref[0]
+    here = expert_ref[jnp.minimum(i, used - 1)]
+    first = (i == 0) | (expert_ref[jnp.maximum(i - 1, 0)] != here)
+    final = (i == used - 1) | (expert_ref[jnp.minimum(i + 1, used - 1)] != here)
+
+    @pl.when(i < used)
+    def _():
+        @pl.when(first)
+        def _start():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        acc_ref[...] += lax.dot_general(
+            x_ref[...], g_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=_dot_prec(x_ref.dtype))
+
+        @pl.when(final)
+        def _store():
+            o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _weights_grad(rows, g, tile_expert, tiles_used, num_groups: int, interpret: bool):
+    """``[groups, k, n]``: each expert's ``rows^T @ g`` over its own tiles.
+    Every expert has a tile (``dispatch_held`` gives an empty one a tile of
+    padding), so every block of the result is written."""
+    m, k = rows.shape
+    n = g.shape[1]
+    if interpret and _interpreter_cannot(rows, g, tile_expert):
+        by_tile = jnp.einsum("tik,tin->tkn", rows.reshape(-1, ROW_TILE, k),
+                             g.reshape(-1, ROW_TILE, n), precision=_dot_prec(rows.dtype))
+        by_tile = jnp.where(_tiles_in_use(tile_expert, tiles_used)[:, None, None], by_tile, 0)
+        return jnp.zeros((num_groups, k, n), rows.dtype).at[tile_expert].add(by_tile)
+    # an f32 accumulator [k, tn] of at most 4 MiB beside the double-buffered blocks
+    tn = _tile(n, [c for c in (1024, 768, 512, 384, 256, 128, 64, 32, 16, 8, 4, 2, 1)
+                   if k * c * 4 <= 4 << 20])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(n // tn, m // ROW_TILE),
+        in_specs=[
+            pl.BlockSpec((ROW_TILE, k), lambda j, i, used, expert: (_last(i, used), 0)),
+            pl.BlockSpec((ROW_TILE, tn), lambda j, i, used, expert: (_last(i, used), j)),
+        ],
+        out_specs=pl.BlockSpec((1, k, tn),
+                               lambda j, i, used, expert: (expert[_last(i, used)], 0, j)),
+        scratch_shapes=[pltpu.VMEM((k, tn), jnp.float32)])
+    params = {} if interpret else {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=32 << 20)}
+    return pl.pallas_call(
+        _weights_kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((num_groups, k, n), rows.dtype, **_vma(rows, g)),
+        interpret=interpret, **params)(tiles_used, tile_expert, rows, g)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def grouped_matmul(rows, weights, tile_expert, tiles_used, interpret: bool = False):
+    """``rows[i] @ weights[e(i)]`` for rows laid out as :func:`dispatch_held`
+    lays them: ``rows`` ``[m, k]`` in tiles of ``ROW_TILE`` rows, tile ``t``
+    all of expert ``tile_expert[t]`` (``[m / ROW_TILE]`` int32, experts in
+    ascending order, every expert at least once), of which the first
+    ``tiles_used`` (``[1]`` int32) are in use; ``weights`` ``[g, k, n]``.
+    Three Pallas kernels (this product, its transpose for the rows' gradient,
+    and the per-expert ``rows^T @ g`` for the weights') whose grids skip the
+    tiles not in use: those cost no product and no traffic. The result's rows
+    past the tiles in use are zero, here and in the gradient."""
+    out = _rows_matmul(rows, weights, tile_expert, tiles_used, False, interpret)
+    return _zero_past(out, tiles_used)
+
+
+def _zero_past(out, tiles_used):
+    """The kernels never write the rows of the tiles not in use: zero them."""
+    in_use = jnp.arange(out.shape[0]) < tiles_used[0] * ROW_TILE
+    return jnp.where(in_use[:, None], out, jnp.zeros((), out.dtype))
+
+
+def _grouped_matmul_fwd(rows, weights, tile_expert, tiles_used, interpret):
+    return (grouped_matmul(rows, weights, tile_expert, tiles_used, interpret),
+            (rows, weights, tile_expert, tiles_used))
+
+
+def _grouped_matmul_bwd(interpret, res, g):
+    rows, weights, tile_expert, tiles_used = res
+    d_rows = _zero_past(
+        _rows_matmul(g, weights, tile_expert, tiles_used, True, interpret), tiles_used)
+    d_weights = _weights_grad(rows, g, tile_expert, tiles_used, weights.shape[0], interpret)
+    return d_rows, d_weights, None, None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def routed_rows_bound(tokens: int, experts_per_token: int, held: int,
+                      num_experts: int) -> int:
+    """How many of a step's (token, chosen expert) slots the held experts'
+    buffer takes: four times what uniform routing sends here
+    (``tokens * k * held / E``), never more than every slot of every token.
+    How they divide between the held experts is free; only their total is
+    bounded, and what exceeds it is counted as overflow. Four, not two: with
+    seeded initial weights the tokens' hidden states share a component, the
+    router's choice is skewed by it, and the fullest of 32 shares of 8 experts
+    was measured at up to 2.84 times the uniform share (PERF.md section 6, PR 27)."""
+    slots = tokens * experts_per_token
+    return min(slots, -(-4 * slots * held // num_experts))
+
+
+def buffer_rows(bound: int, held: int) -> int:
+    """Rows of the buffer: the bound, a tile's padding for every held expert,
+    rounded up to whole tiles."""
+    rows = bound + held * ROW_TILE
+    return -(-rows // ROW_TILE) * ROW_TILE
+
+
+def route_top_k(scores, bias, experts_per_token: int, scaling: float,
+                choice=None):
+    """``(ids [T, k] int32, weights [T, k] f32)``: the ``k`` experts with the
+    largest ``scores + bias`` of each token, and ``scaling * s / (sum of the
+    chosen s + 1e-20)`` from the scores themselves. The bias enters the choice
+    only, and the ids carry no gradient. A given ``choice`` of ids replaces the
+    top-k and leaves the weights to the scores."""
+    if choice is None:
+        _, choice = lax.top_k(scores + bias, experts_per_token)
+    chosen = jnp.take_along_axis(scores, choice, axis=-1)
+    weights = scaling * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return choice.astype(jnp.int32), weights
+
+
+def balance_bias(bias, ids, speed: float):
+    """The auxiliary-loss-free balancing step (DeepSeek-V3's ``noaux_tc``): the
+    bias of every expert that ``ids`` ``[T, k]`` chose more often than the mean
+    goes down by ``speed``, of every one chosen less often up by it. The load
+    is counted over the tokens at hand -- one chip's; a data-parallel group
+    would sum the counts first."""
+    load = jnp.sum(ids.reshape(-1, 1) == jnp.arange(bias.shape[0]), axis=0,
+                   dtype=jnp.float32)
+    return bias + speed * jnp.sign(jnp.mean(load) - load)
+
+
+def dispatch_held(ids, held: Tuple[int, int], bound: int):
+    """Where each row of the held experts' buffer comes from. ``ids`` ``[T, k]``
+    are every token's chosen experts, ``held = (lo, hi)`` the ids computed
+    here, ``bound`` the slots taken at most (:func:`routed_rows_bound`).
+
+    The buffer has :func:`buffer_rows` rows in tiles of ``ROW_TILE``. The
+    slots that chose a held expert are laid out in expert order, every
+    expert's rows starting on a tile and an expert without rows still given
+    one tile, so that a tile is all one expert's. Returns ``(slot [rows],
+    valid [rows], tile_expert [tiles], tiles_used [1], counters)``: row ``i``
+    is slot ``slot[i]`` of the flattened ``[T * k]`` choices (token
+    ``slot // k``) where ``valid``, and padding elsewhere; ``counters`` are
+    scalars -- ``rows_routed`` (slots that chose a held expert),
+    ``load_max_over_mean`` (the fullest held expert's rows over the mean),
+    ``rows_overflowed`` (slots past ``bound``, cut from the end of the expert
+    order: their contribution is lost, and this is where it shows)."""
+    lo, hi = held
+    n_held = hi - lo
+    rows = buffer_rows(bound, n_held)
+    local = ids.reshape(-1) - lo
+    mine = (local >= 0) & (local < n_held)
+    key = jnp.where(mine, local, n_held)            # the others sort last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(n_held), axis=0, dtype=jnp.int32)
+    routed = jnp.sum(sizes)
+    starts = jnp.cumsum(sizes) - sizes              # of each expert in ``order``
+    kept = jnp.minimum(jnp.cumsum(sizes), bound) - jnp.minimum(starts, bound)
+    tiles = jnp.maximum(-(-kept // ROW_TILE), 1)
+    tile_ends = jnp.cumsum(tiles)
+    # the expert whose tiles tile t is among: how many experts end at or before
+    # it (the tiles past the last one in use stay with the last expert)
+    tile_expert = jnp.minimum(
+        jnp.sum(tile_ends[None, :] <= jnp.arange(rows // ROW_TILE)[:, None], axis=1),
+        n_held - 1).astype(jnp.int32)
+    row = jnp.arange(rows)
+    expert = tile_expert[row // ROW_TILE]
+    within = row - (tile_ends - tiles)[expert] * ROW_TILE
+    valid = (within < kept[expert]) & (row < tile_ends[-1] * ROW_TILE)
+    slot = jnp.where(valid, order[jnp.minimum(starts[expert] + within, order.shape[0] - 1)], 0)
+    counters = {
+        "rows_routed": routed,
+        "load_max_over_mean": jnp.max(sizes) * n_held / jnp.maximum(routed, 1).astype(jnp.float32),
+        "rows_overflowed": routed - jnp.sum(kept),
+    }
+    return slot, valid, tile_expert, tile_ends[-1:].astype(jnp.int32), counters
+
+
+class RoutedExperts(nn.Module):
+    """The chip's share of a top-k expert layer with a shared expert.
+
+    ``num_experts`` experts are scored (``scoring``: ``"sigmoid"`` or
+    ``"softmax"``, in float32) and ``experts_per_token`` chosen by score plus
+    the routing bias; ``held = (lo, hi)`` are the ids whose weights live
+    here. The result is ``shared(x) + sum over the chosen experts held here
+    of w_e * E_e(x)`` with ``w_e`` normalised over all the chosen ones and
+    multiplied by ``scaling``: what the experts held elsewhere would add is
+    left out (with ``held = (0, num_experts)`` nothing is). Every expert is a
+    SwiGLU of width ``d_ff``. Rows routed here are gathered in expert order
+    into a buffer of a static number of rows (:func:`routed_rows_bound`,
+    :func:`buffer_rows`); per-expert counts are ragged inside it, so imbalance
+    between experts costs nothing, and the grouped products do work only for
+    the tiles in use (:func:`grouped_matmul`).
+
+    The bias is no parameter: it gets no gradient and lives in the
+    ``"routing"`` collection (``model_state`` of the ``bf`` optimizers, as
+    batch-norm statistics do), seeded small and not zero. Where that
+    collection is mutable, a forward pass ends with the auxiliary-loss-free
+    balancing update (``noaux_tc``): ``bias += bias_update_speed *
+    sign(mean load - load)`` from the tokens' choices of this pass over all
+    ``num_experts`` (:func:`balance_bias`); the pass itself chose with the
+    bias as it came in.
+
+    ``choice`` (``[..., k]`` ids) forces the chosen experts, for a comparison
+    with a reference that must not depend on which side of a near tie the
+    8th score fell. The counters of :func:`dispatch_held` are sowed under
+    ``intermediates/moe_counters``, the chosen ids under
+    ``intermediates/moe_choice``.
+    """
+
+    num_experts: int
+    experts_per_token: int
+    d_ff: int
+    held: Tuple[int, int]
+    n_shared: int = 1
+    scoring: str = "sigmoid"
+    scaling: float = 1.0
+    bias_update_speed: float = 0.0
+    dtype: Any = jnp.float32
+    interpret: bool = False
+
+    @nn.compact
+    def __call__(self, x, choice=None):
+        d = x.shape[-1]
+        leading = x.shape[:-1]
+        t = int(np.prod(leading))
+        n_held = self.held[1] - self.held[0]
+        k = self.experts_per_token
+        init = nn.initializers.lecun_normal()
+        router = self.param("router", init, (d, self.num_experts), jnp.float32)
+        # small and seeded, not zero: a forgotten bias must change the choice
+        bias = self.variable(
+            ROUTING, "bias", lambda: nn.initializers.normal(0.02)(
+                self.make_rng("params"), (self.num_experts,), jnp.float32))
+        expert_init = nn.initializers.variance_scaling(
+            1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=(0,))
+        gate = self.param("gate", expert_init, (n_held, d, self.d_ff), jnp.float32)
+        up = self.param("up", expert_init, (n_held, d, self.d_ff), jnp.float32)
+        down = self.param("down", expert_init, (n_held, self.d_ff, d), jnp.float32)
+
+        xt = x.reshape(t, d).astype(self.dtype)
+        with jax.named_scope(SCOPE_ROUTE):
+            logits = jnp.dot(xt.astype(jnp.float32), router,
+                             precision=lax.Precision.HIGHEST)
+            scores = (jax.nn.sigmoid(logits) if self.scoring == "sigmoid"
+                      else jax.nn.softmax(logits, axis=-1))
+            ids, weights = route_top_k(
+                scores, bias.value, k, self.scaling,
+                None if choice is None else choice.reshape(t, k))
+            if self.is_mutable_collection(ROUTING) and not self.is_initializing():
+                bias.value = balance_bias(bias.value, ids, self.bias_update_speed)
+            bound = routed_rows_bound(t, k, n_held, self.num_experts)
+            slot, valid, tile_expert, tiles_used, counters = dispatch_held(
+                ids, self.held, bound)
+            token = lax.div(slot, k)                                # of each row
+            gathered = jnp.where(valid[:, None], xt[token], 0)      # [rows, d]
+            row_weight = jnp.where(valid, weights.reshape(-1)[slot], 0.0)
+        with jax.named_scope(SCOPE_EXPERTS):
+            mm = lambda rows, w: grouped_matmul(  # noqa: E731
+                rows, w.astype(self.dtype), tile_expert, tiles_used, self.interpret)
+            y = mm(nn.silu(mm(gathered, gate)) * mm(gathered, up), down)  # [rows, d]
+        with jax.named_scope(SCOPE_ROUTE):
+            routed = jnp.zeros((t, d), jnp.float32).at[token].add(
+                y.astype(jnp.float32) * row_weight[:, None])
+        with jax.named_scope(SCOPE_SHARED):
+            shared = SwiGLU(self.n_shared * self.d_ff, self.dtype, name="shared")(xt)
+        self.sow("intermediates", "moe_counters", counters)
+        self.sow("intermediates", "moe_choice", ids.reshape(leading + (k,)))
+        return (routed.astype(self.dtype) + shared).reshape(leading + (d,)).astype(x.dtype)
+
+
+class SwiGLU(nn.Module):
+    """``down(silu(gate(x)) * up(x))``, no biases: the gated FFN of the dense
+    layers and of the shared expert."""
+
+    d_ff: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        dense = functools.partial(nn.Dense, dtype=self.dtype, param_dtype=jnp.float32,
+                                  use_bias=False)
+        h = nn.silu(dense(self.d_ff, name="gate")(x)) * dense(self.d_ff, name="up")(x)
+        return dense(x.shape[-1], name="down")(h)

@@ -68,6 +68,31 @@ def _k_tile(sk: int) -> int:
 
 
 
+def _vma(*arrays) -> dict:
+    """``vma=`` for a ``pallas_call``'s ``out_shape``. Inside shard_map the
+    inputs carry varying-mesh-axes (vma) metadata and pallas_call requires
+    out_shape to declare the same — without it the kernel compiles under
+    interpret mode but fails to lower on real TPU. Union over the operands:
+    any varying operand makes the outputs varying (k/v can be rank-varying
+    while q is replicated, e.g. broadcast-query)."""
+    vmas = [getattr(jax.typeof(t), "vma", None) for t in arrays]
+    if all(m is None for m in vmas):
+        return {}
+    return {"vma": frozenset().union(*(m for m in vmas if m is not None))}
+
+
+def _bwd_vmem(d: int, dv: int) -> dict:
+    """Scoped-VMEM limit of the two backward kernels, from the head widths.
+    Their 512 x 2048 tiles were sized (r5 sweep) for two 128-lane operands a
+    side inside Mosaic's default 16 MiB; a width is held in whole 128-lane
+    tiles, so a 192-wide q.k takes 256 lanes and the dk/dv kernel's stack
+    comes to 16.8 MiB. The limit grows with the lanes held instead of the
+    tiles shrinking; at 128 + 128 lanes nothing is passed and the kernels
+    lower as they always did."""
+    lanes = sum(-(-w // 128) * 128 for w in (d, dv))
+    return {} if lanes <= 256 else {"vmem_limit_bytes": (16 << 20) * lanes // 256}
+
+
 def _dot_prec(dtype):
     """Kernel matmul precision: DEFAULT for sub-f32 operands (bf16 x bf16
     runs the MXU at 4x its f32 rate and the products are exact for bf16
@@ -151,33 +176,28 @@ def flash_block(q, k, v, q_off, k_off, *, causal: bool = True,
                 interpret: bool = False):
     """Attention partials of q against one K/V block.
 
-    q: [B, Sq, H, D]; k, v: [B, Sk, H, D]; q_off/k_off: scalar global
-    positions of element 0 (for causal masking across ring steps).
-    Returns (o, m, l): [B, Sq, H, D] f32 unnormalized output and [B, Sq, H]
+    q: [B, Sq, H, D]; k: [B, Sk, H, D]; v: [B, Sk, H, Dv] (Dv may differ
+    from D: latent attention has a 192-wide q.k and a 128-wide v; the score
+    scale is 1/sqrt(D)); q_off/k_off: scalar global positions of element 0
+    (for causal masking across ring steps).
+    Returns (o, m, l): [B, Sq, H, Dv] f32 unnormalized output and [B, Sq, H]
     f32 row max / row sum. Final output = o / l after merging blocks.
     """
     B, Sq, H, D = q.shape
-    Sk = k.shape[1]
+    Sk, Dv = k.shape[1], v.shape[-1]
     scale = 1.0 / math.sqrt(D)
     tq = _q_tile(Sq)
 
-    def bhsd(x):  # [B, S, H, D] -> [B*H, S, D]
-        return x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], D)
+    def bhsd(x):  # [B, S, H, C] -> [B*H, S, C]
+        return x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], x.shape[3])
 
     tk = _k_tile(Sk)
     offs = jnp.asarray([q_off, k_off], jnp.int32)
     grid = (B * H, Sq // tq, Sk // tk)
     kernel = functools.partial(_kernel, causal=causal, scale=scale)
-    # Inside shard_map the inputs carry varying-mesh-axes (vma) metadata and
-    # pallas_call requires out_shape to declare the same — without it the
-    # kernel compiles under interpret mode but fails to lower on real TPU.
-    # Union over q/k/v: any varying operand makes the outputs varying (k/v
-    # can be rank-varying while q is replicated, e.g. broadcast-query).
-    vmas = [getattr(jax.typeof(t), "vma", None) for t in (q, k, v)]
-    kw = {} if all(m is None for m in vmas) else {
-        "vma": frozenset().union(*(m for m in vmas if m is not None))}
+    kw = _vma(q, k, v)
     out_shape = (
-        jax.ShapeDtypeStruct((B * H, Sq, D), jnp.float32, **kw),
+        jax.ShapeDtypeStruct((B * H, Sq, Dv), jnp.float32, **kw),
         jax.ShapeDtypeStruct((B * H, Sq, 8), jnp.float32, **kw),
         jax.ShapeDtypeStruct((B * H, Sq, 8), jnp.float32, **kw),
     )
@@ -187,10 +207,10 @@ def flash_block(q, k, v, q_off, k_off, *, causal: bool = True,
         in_specs=[
             pl.BlockSpec((1, tq, D), lambda bh, qi, kj, offs: (bh, qi, 0)),
             pl.BlockSpec((1, tk, D), lambda bh, qi, kj, offs: (bh, kj, 0)),
-            pl.BlockSpec((1, tk, D), lambda bh, qi, kj, offs: (bh, kj, 0)),
+            pl.BlockSpec((1, tk, Dv), lambda bh, qi, kj, offs: (bh, kj, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, tq, D), lambda bh, qi, kj, offs: (bh, qi, 0)),
+            pl.BlockSpec((1, tq, Dv), lambda bh, qi, kj, offs: (bh, qi, 0)),
             pl.BlockSpec((1, tq, 8), lambda bh, qi, kj, offs: (bh, qi, 0)),
             pl.BlockSpec((1, tq, 8), lambda bh, qi, kj, offs: (bh, qi, 0)),
         ],
@@ -359,31 +379,32 @@ def flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off, *,
                     causal: bool = True, interpret: bool = False):
     """Gradients of q's attention against one K/V block (pallas kernels).
 
-    Inputs: q [B, Sq, H, D]; k, v [B, Sk, H, D]; g = dOut [B, Sq, H, D];
+    Inputs: q [B, Sq, H, D]; k [B, Sk, H, D]; v [B, Sk, H, Dv];
+    g = dOut [B, Sq, H, Dv];
     ``d_term = sum(dOut * Out, -1)`` and the saved GLOBAL softmax row stats
     ``m`` (row max) and ``l`` (row sum), all [B, Sq, H] f32 — the same
     quantities the XLA ring backward reconstructs per block
-    (context._ring_backward). Returns (dq_partial, dk, dv) in f32: the
-    caller sums dq partials over blocks and ships dk/dv home with the ring.
+    (context._ring_backward). Returns (dq_partial, dk, dv) in f32, shaped
+    as q, k and v: the caller sums dq partials over blocks and ships dk/dv
+    home with the ring.
     """
     B, Sq, H, D = q.shape
-    Sk = k.shape[1]
+    Sk, Dv = k.shape[1], v.shape[-1]
     scale = 1.0 / math.sqrt(D)
     tq = _q_tile(Sq)
     tk = _k_tile(Sk)
 
     def bhsd(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], D)
+        return x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], x.shape[3])
 
     offs = jnp.asarray([q_off, k_off], jnp.int32)
-    vmas = [getattr(jax.typeof(t), "vma", None) for t in (q, k, v, g)]
-    kw = {} if all(mm is None for mm in vmas) else {
-        "vma": frozenset().union(*(mm for mm in vmas if mm is not None))}
+    kw = _vma(q, k, v, g)
     operands = (offs, bhsd(q), bhsd(k), bhsd(v), bhsd(g),
                 _lane8(m), _lane8(l), _lane8(d_term))
     params = {} if interpret else {
         "compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))}
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            **_bwd_vmem(D, Dv))}
 
     # pass 1: dq (K innermost, accumulates into the q tile's output)
     dq_spec = pltpu.PrefetchScalarGridSpec(
@@ -392,8 +413,8 @@ def flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off, *,
         in_specs=[
             pl.BlockSpec((1, tq, D), lambda bh, qi, kj, o: (bh, qi, 0)),
             pl.BlockSpec((1, tk, D), lambda bh, qi, kj, o: (bh, kj, 0)),
-            pl.BlockSpec((1, tk, D), lambda bh, qi, kj, o: (bh, kj, 0)),
-            pl.BlockSpec((1, tq, D), lambda bh, qi, kj, o: (bh, qi, 0)),
+            pl.BlockSpec((1, tk, Dv), lambda bh, qi, kj, o: (bh, kj, 0)),
+            pl.BlockSpec((1, tq, Dv), lambda bh, qi, kj, o: (bh, qi, 0)),
             pl.BlockSpec((1, tq, 8), lambda bh, qi, kj, o: (bh, qi, 0)),
             pl.BlockSpec((1, tq, 8), lambda bh, qi, kj, o: (bh, qi, 0)),
             pl.BlockSpec((1, tq, 8), lambda bh, qi, kj, o: (bh, qi, 0)),
@@ -417,15 +438,15 @@ def flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off, *,
         in_specs=[
             pl.BlockSpec((1, tq, D), lambda bh, kj, qi, o: (bh, qi, 0)),
             pl.BlockSpec((1, tk, D), lambda bh, kj, qi, o: (bh, kj, 0)),
-            pl.BlockSpec((1, tk, D), lambda bh, kj, qi, o: (bh, kj, 0)),
-            pl.BlockSpec((1, tq, D), lambda bh, kj, qi, o: (bh, qi, 0)),
+            pl.BlockSpec((1, tk, Dv), lambda bh, kj, qi, o: (bh, kj, 0)),
+            pl.BlockSpec((1, tq, Dv), lambda bh, kj, qi, o: (bh, qi, 0)),
             pl.BlockSpec((1, tq, 8), lambda bh, kj, qi, o: (bh, qi, 0)),
             pl.BlockSpec((1, tq, 8), lambda bh, kj, qi, o: (bh, qi, 0)),
             pl.BlockSpec((1, tq, 8), lambda bh, kj, qi, o: (bh, qi, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, tk, D), lambda bh, kj, qi, o: (bh, kj, 0)),
-            pl.BlockSpec((1, tk, D), lambda bh, kj, qi, o: (bh, kj, 0)),
+            pl.BlockSpec((1, tk, Dv), lambda bh, kj, qi, o: (bh, kj, 0)),
         ],
     )
     with jax.named_scope(SCOPE_DKV):
@@ -434,13 +455,13 @@ def flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off, *,
             grid_spec=dkv_spec,
             out_shape=(
                 jax.ShapeDtypeStruct((B * H, Sk, D), jnp.float32, **kw),
-                jax.ShapeDtypeStruct((B * H, Sk, D), jnp.float32, **kw),
+                jax.ShapeDtypeStruct((B * H, Sk, Dv), jnp.float32, **kw),
             ),
             interpret=interpret, **params,
         )(*operands)
 
     def sbhd(x, s):
-        return x.reshape((B, H, s, D)).transpose(0, 2, 1, 3)
+        return x.reshape((B, H, s, x.shape[-1])).transpose(0, 2, 1, 3)
 
     return sbhd(dq, Sq), sbhd(dk, Sk), sbhd(dv, Sk)
 
@@ -459,7 +480,7 @@ def _blockwise_attention(q, k, v, causal: bool, tk: int):
     # keep K/V in their input dtype; each block upcasts inside the
     # checkpointed step, so only one block's f32 copy is ever live
     kb = k.reshape(B, nk, tk, H, D).transpose(1, 0, 2, 3, 4)
-    vb = v.reshape(B, nk, tk, H, D).transpose(1, 0, 2, 3, 4)
+    vb = v.reshape(B, nk, tk, H, v.shape[-1]).transpose(1, 0, 2, 3, 4)
     q_pos = jnp.arange(S)
 
     @jax.checkpoint
@@ -484,7 +505,7 @@ def _blockwise_attention(q, k, v, causal: bool, tk: int):
             "bqhk,bkhd->bqhd", p, vblk, preferred_element_type=jnp.float32)
         return (o_new, m_new, l_new), None
 
-    init = (jnp.zeros((B, S, H, D), jnp.float32),
+    init = (jnp.zeros((B, S, H, v.shape[-1]), jnp.float32),
             jnp.full((B, S, H), _NEG, jnp.float32),
             jnp.zeros((B, S, H), jnp.float32))
     (o, m, l), _ = jax.lax.scan(step, init, (jnp.arange(nk), kb, vb))
@@ -520,7 +541,9 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     interpret: bool = False):
-    """Single-device flash attention over [B, S, H, D] (normalized output).
+    """Single-device flash attention: q, k [B, S, H, D] and v [B, S, H, Dv]
+    give the normalized output [B, S, H, Dv] (Dv = D for equal-width heads;
+    latent attention has D = 192 and Dv = 128; the scale is 1/sqrt(D)).
 
     Differentiable: the forward runs the pallas VMEM kernel and the
     backward runs the pallas flash-attention-2 kernel pair

@@ -17,6 +17,14 @@ benchmarks, with random weights from a seed:
 * ``lm_flash``: the three compiled flash-attention kernels against the dense
   f32-softmax reference, then the 4-layer d_model-2048 LM at 8192 tokens per
   chip through ``bf.DistributedNeighborAllreduceOptimizer.step``.
+* ``mla_moe``: the kernels again at latent attention's widths (q.k 192, v
+  128); the three compiled kernels of ``grouped_matmul`` (the product and both
+  gradients) against XLA matmuls on ragged loads with an empty expert; then
+  ``bf.models.ConfigLM`` at JoyAI-LLM-Flash's widths (one dense and one expert
+  layer and the MTP module, experts [0, 8) of 256 held, top-8) at 8192 tokens
+  per chip through the same optimizer with the routing biases as its model
+  state: the expert layers' counters of the last step are printed, and an
+  overflowed row raises.
 
 It refuses to start unless every rank is a TPU device, and a failing phase
 raises (nothing is caught). A run that passed ends with two JSON lines on
@@ -49,8 +57,10 @@ import jax.numpy as jnp  # noqa: E402
 import optax  # noqa: E402
 
 import bluefog_tpu as bf  # noqa: E402
-from bluefog_tpu.models import MLP, ResNet50, TransformerLM  # noqa: E402
+from bluefog_tpu.models import (ConfigLM, LMConfig, MLP, ResNet50,  # noqa: E402
+                                TransformerLM, next_token_loss)
 from bluefog_tpu.optimizers import PERMUTES_IN_FLIGHT_MAX  # noqa: E402
+from bluefog_tpu.parallel import expert  # noqa: E402
 from bluefog_tpu.parallel.context import reference_attention  # noqa: E402
 from bluefog_tpu.parallel.flash import flash_attention  # noqa: E402
 from bluefog_tpu.runtime import native  # noqa: E402
@@ -67,6 +77,17 @@ LM = dict(vocab_size=32768, num_layers=4, num_heads=16, d_model=2048,
           d_ff=8192)
 LM_SEQ, LM_STEPS = 8192, 4
 KERNEL_SHAPE = (1, 2048, 16, 128)  # B, S, H, D of the kernel-vs-reference leg
+# JoyAI-LLM-Flash's config.json, two layers and an eighth of the vocabulary
+MLA_MOE = LMConfig(
+    vocab_size=16160, hidden_size=2048, num_hidden_layers=2, num_attention_heads=32,
+    intermediate_size=7168, q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, rope_theta=3.2e7, first_k_dense_replace=1,
+    n_routed_experts=256, num_experts_per_tok=8, moe_intermediate_size=768,
+    routed_scaling_factor=2.5, experts_held=(0, 8), bias_update_speed=0.001,
+    num_nextn_predict_layers=1)
+# rows of each held expert in the grouped products' check: ragged, one empty,
+# one of a single row, one of exactly a tile
+GROUPED_LOADS = (700, 0, 130, 1, 300, 128, 5, 900)
 KERNEL_TOL = 3e-2
 FLASH = partial(flash_attention, causal=True)
 
@@ -257,11 +278,11 @@ def _mosaic_calls(fn, *args):
     return fn.lower(*args).as_text().count("tpu_custom_call")
 
 
-def _check_flash_kernels():
+def _check_flash_kernels(d_v=KERNEL_SHAPE[3], d_qk=KERNEL_SHAPE[3]):
     """Compiled forward, dq and dk/dv kernels against the dense reference."""
     keys = jax.random.split(jax.random.PRNGKey(3), 4)
-    q, k, v, w = (jax.random.normal(kk, KERNEL_SHAPE, jnp.bfloat16)
-                  for kk in keys)
+    q, k, v, w = (jax.random.normal(kk, KERNEL_SHAPE[:3] + (d,), jnp.bfloat16)
+                  for kk, d in zip(keys, (d_qk, d_qk, d_v, d_v)))
 
     def weighted(attn):
         return lambda q, k, v: jnp.sum(
@@ -316,6 +337,98 @@ def phase_lm_flash():
     return out
 
 
+def _check_grouped_matmul():
+    """The compiled grouped product, its rows' gradient and its weights'
+    gradient at the expert layer's widths against one XLA matmul per expert
+    under its rows' mask, on ``GROUPED_LOADS`` in a buffer with tiles to spare."""
+    d, f, held = MLA_MOE.hidden_size, MLA_MOE.moe_intermediate_size, len(GROUPED_LOADS)
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    ids = np.concatenate([np.full(count, e) for e, count in enumerate(GROUPED_LOADS)]
+                         + [np.full(1000, held + 3)])           # and slots held elsewhere
+    ids = jax.random.permutation(keys[0], jnp.asarray(ids, jnp.int32))[:, None]
+    slot, valid, tile_expert, used, counters = expert.dispatch_held(ids, (0, held), 4096)
+    if int(counters["rows_routed"]) != sum(GROUPED_LOADS) or int(counters["rows_overflowed"]):
+        raise RuntimeError(f"dispatch_held miscounted {GROUPED_LOADS}: {counters}")
+    x = jax.random.normal(keys[1], (ids.shape[0], d), jnp.bfloat16)
+    rows = jnp.where(valid[:, None], x[slot], 0)
+    weights = (jax.random.normal(keys[2], (held, d, f), jnp.float32) / np.sqrt(d)).astype(
+        jnp.bfloat16)
+    # the cotangent, exact in bfloat16 so that both sides are handed the same
+    w = jax.random.normal(keys[3], (rows.shape[0], f), jnp.bfloat16).astype(jnp.float32)
+    row_expert = tile_expert[jnp.arange(rows.shape[0]) // expert.ROW_TILE]
+    in_use = jnp.arange(rows.shape[0]) < used[0] * expert.ROW_TILE
+
+    def grouped(rows, weights):
+        return expert.grouped_matmul(rows, weights, tile_expert, used).astype(jnp.float32)
+
+    def masked(rows, weights):
+        out = jnp.zeros((rows.shape[0], f), jnp.float32)
+        for e in range(held):
+            y = jnp.dot(rows.astype(jnp.float32), weights[e].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+            out = jnp.where(((row_expert == e) & in_use)[:, None], y, out)
+        return out
+
+    weighted = lambda fn: lambda rows, weights: jnp.sum(fn(rows, weights) * w)  # noqa: E731
+    # the product is linear in both, so its gradient alone holds two kernels
+    grad = jax.jit(jax.grad(weighted(grouped), argnums=(0, 1)))
+    calls = _mosaic_calls(grad, rows, weights) + _mosaic_calls(jax.jit(grouped), rows, weights)
+    if calls != 3:
+        raise RuntimeError(f"expected 3 Mosaic kernels in the grouped product and "
+                           f"its gradient, found {calls}")
+    got = (jax.jit(grouped)(rows, weights),) + grad(rows, weights)
+    want = (jax.jit(masked)(rows, weights),) + jax.jit(
+        jax.grad(weighted(masked), argnums=(0, 1)))(rows, weights)
+    err = {}
+    for name, a, b in zip(("out", "d_rows", "d_weights"), got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        # sums of hundreds of terms that cancel: an element's error goes with
+        # the leaf's size, not its own (a misplaced tile is wrong by the size)
+        np.testing.assert_allclose(a, b, atol=KERNEL_TOL * np.max(np.abs(b)), rtol=KERNEL_TOL,
+                                   err_msg=f"grouped_matmul {name} vs XLA")
+        err[name] = round(float(np.max(np.abs(a - b)) / np.max(np.abs(b))), 5)
+    empty = GROUPED_LOADS.index(0)
+    if np.any(np.asarray(got[2][empty], np.float32)):
+        raise RuntimeError("the empty expert's weight gradient is not zero")
+    return err
+
+
+def phase_mla_moe():
+    n = bf.size()
+    kernel_err = _check_flash_kernels(
+        d_v=MLA_MOE.v_head_dim,
+        d_qk=MLA_MOE.qk_nope_head_dim + MLA_MOE.qk_rope_head_dim)
+    grouped_err = _check_grouped_matmul()
+    model = ConfigLM(MLA_MOE, dtype=jnp.bfloat16, attn_fn=FLASH)
+    variables = jax.jit(lambda k: model.init(
+        k, jnp.zeros((1, LM_SEQ), jnp.int32)))(jax.random.PRNGKey(0))
+    opt = bf.DistributedNeighborAllreduceOptimizer(
+        optax.adam(1e-3), next_token_loss(model), with_model_state=True)
+    state = opt.init(variables["params"], model_state=variables["routing"])
+    del variables
+
+    def make(k):
+        tokens = jax.random.randint(k, (n, 1, LM_SEQ), 0, MLA_MOE.vocab_size)
+        return tokens, jnp.roll(tokens, -1, axis=2), jnp.roll(tokens, -2, axis=2)
+
+    batch = _rank_batch(make)
+    state, compile_s, steady, losses = _timed_steps(opt, state, batch, LM_STEPS)
+    if not (losses[-1] < losses[0]).all():
+        raise RuntimeError(
+            f"MLA/MoE LM loss did not fall on every rank: {losses.tolist()}")
+    _check_layout(state.params, "params")
+    _, metrics = opt.step(state, batch)
+    counters = {name: np.asarray(value).tolist()
+                for name, value in metrics["aux"].items()}
+    if any(counters["rows_overflowed"]):
+        raise RuntimeError(f"rows overflowed the held experts' buffer: {counters}")
+    out = _report(compile_s, steady, losses)
+    out["kernel_max_abs_err"] = kernel_err
+    out["grouped_matmul_max_rel_err"] = grouped_err
+    out["moe_counters_per_rank"] = counters
+    return out
+
+
 def _device_stamp():
     """The device as JAX reports it; exits unless every rank is a TPU chip and
     every chip is a rank."""
@@ -359,6 +472,8 @@ def main():
     del resnet  # ResNet-50 and the LM do not fit beside each other
     phases["lm_flash"] = phase_lm_flash()
     print("lm_flash:", phases["lm_flash"], flush=True)
+    phases["mla_moe"] = phase_mla_moe()
+    print("mla_moe:", phases["mla_moe"], flush=True)
     peak_gib = [round(d.memory_stats()["peak_bytes_in_use"] / 2**30, 2)
                 for d in bf.mesh().devices.flat]
     bf.shutdown()

@@ -1,0 +1,378 @@
+"""``bf.models.ConfigLM`` -- latent attention through the flash kernels, the
+chip's share of a top-k expert layer, the MTP module -- against the plain
+float32 reference the benchmark keeps (``benchmark/families/mla_moe_lm.py``,
+which shares no code with ``bluefog_tpu``), at toy widths on the CPU with the
+Pallas kernels interpreted.
+"""
+
+import dataclasses
+import importlib.util
+import os
+from functools import partial
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+import optax
+
+import bluefog_tpu as bf
+from bluefog_tpu.models import (ConfigLM, LMConfig, moe_choices, moe_counters,
+                                next_token_loss)
+from bluefog_tpu.parallel import expert
+from bluefog_tpu.parallel.context import reference_attention
+from bluefog_tpu.parallel.flash import flash_attention
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _family():
+    path = os.path.join(ROOT, "benchmark", "families", "mla_moe_lm.py")
+    spec = importlib.util.spec_from_file_location("mla_moe_lm_family", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+FAMILY = _family()
+
+# the published block at toy widths: q.k 24 = 16 + 8 and v 16 (192 = 128 + 64
+# and 128), one dense and two expert layers and the MTP module, experts
+# [8, 16) of 32 held (share 1 of 4), top-4
+TOY = {
+    "hidden_size": 32, "intermediate_size": 48, "num_attention_heads": 4,
+    "q_lora_rank": 24, "kv_lora_rank": 16, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+    "v_head_dim": 16, "rope_theta": 32000000, "rope_interleave": True, "rms_norm_eps": 1e-6,
+    "num_hidden_layers": 3, "first_k_dense_replace": 1, "n_routed_experts": 8,
+    "num_experts_per_tok": 4, "moe_intermediate_size": 16, "n_shared_experts": 1,
+    "scoring_func": "sigmoid", "routed_scaling_factor": 2.5, "num_nextn_predict_layers": 1,
+    "vocab_size": 64, "mtp_loss_weight": 0.3, "compute_dtype": "float32",
+    "interpret_kernels": True,
+    "published": {"n_routed_experts": 32}, "deployment": {"share": 1},
+}
+BATCH = {"sequences": 2, "seq_len": 64}
+
+
+@pytest.fixture(scope="module")
+def toy():
+    """(cfg, params, routing biases, batch of one rank) from fixed seeds."""
+    params, routing = FAMILY.init(TOY, BATCH, jax.random.PRNGKey(0))
+    batch = jax.tree_util.tree_map(
+        lambda x: x[0], FAMILY.make_batch(TOY, BATCH, jax.random.PRNGKey(1), 1))
+    return TOY, params, routing, batch
+
+
+def _rel(got, want):
+    """max |got - want| over max |want|, over a whole tree."""
+    got, want = jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)
+    return max(float(jnp.max(jnp.abs(g - w)) / (jnp.max(jnp.abs(w)) + 1e-30))
+               for g, w in zip(got, want))
+
+
+def _system_loss(cfg, params, routing, batch):
+    return FAMILY.loss(cfg)[0](params, routing, batch)[0]
+
+
+def _system(cfg, params, routing, batch):
+    return jax.value_and_grad(partial(_system_loss, cfg))(params, routing, batch)
+
+
+def _plain(cfg, params, routing, batch):
+    return jax.value_and_grad(partial(FAMILY.plain_loss, cfg))(params, routing, batch)
+
+
+# Both sides are float32 at the highest matmul precision and differ in the
+# order of their sums only (online softmax in tiles, rows gathered by expert):
+# the loss agrees to 1e-6 and every gradient leaf to 1e-4 of its largest
+# element. Each fault below moves the loss by ten tolerances or more (the
+# least, bfloat16 parameters, by 3.6e-5 of it).
+LOSS_RTOL, GRAD_RTOL = 2e-6, 2e-4
+
+
+def test_loss_and_gradients_match_the_plain_reference(toy):
+    cfg, params, routing, batch = toy
+    loss, grads = _system(cfg, params, routing, batch)
+    want_loss, want_grads = _plain(cfg, params, routing, batch)
+    assert abs(float(loss) - float(want_loss)) <= LOSS_RTOL * float(want_loss)
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, got), want in zip(flat, jax.tree_util.tree_leaves(want_grads)):
+        name = jax.tree_util.keystr(path)
+        assert not name.endswith("['bias']")   # the routing bias is no parameter
+        assert _rel(got, want) <= GRAD_RTOL, (name, _rel(got, want))
+    # the shared embedding and head get a contribution from the MTP module
+    only_main = jax.grad(partial(_system_loss, {**cfg, "mtp_loss_weight": 0.0}))(
+        params, routing, batch)
+    for leaf in ("embed", "lm_head"):
+        assert _rel(grads[leaf], only_main[leaf]) > 1e-2
+
+
+def _bf16_parameters(cfg, params, routing):
+    return cfg, jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.bfloat16).astype(jnp.float32), params), routing
+
+
+def _no_bias(cfg, params, routing):
+    return cfg, params, jax.tree_util.tree_map(jnp.zeros_like, routing)
+
+
+def _rope_by_halves(cfg, params, routing):
+    return {**cfg, "rope_interleave": False}, params, routing
+
+
+def _dropped_tokens(cfg, params, routing):
+    """A buffer that takes 32 of the ~128 slots routed here: the rest is dropped."""
+    return {**cfg, "_bound": 32}, params, routing
+
+
+@pytest.mark.parametrize("fault", [_bf16_parameters, _no_bias, _rope_by_halves,
+                                   _dropped_tokens])
+def test_the_comparison_is_tight_enough_to_see(fault, toy, monkeypatch):
+    """What the tolerances must catch: the system computes with the fault, the
+    reference without."""
+    cfg, params, routing, batch = toy
+    want = float(FAMILY.plain_loss(cfg, params, routing, batch))
+    faulty = fault(cfg, params, routing)
+    if "_bound" in faulty[0]:
+        monkeypatch.setattr(expert, "routed_rows_bound", lambda *a: faulty[0]["_bound"])
+    got = float(_system_loss(*faulty, batch))
+    assert abs(got - want) > 10 * LOSS_RTOL * want, (got, want)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("what", ["forward", "gradients"])
+def test_flash_with_a_wider_qk_than_v_matches_dense_attention(what, causal):
+    """d_qk 24 and d_v 16: the toy of 192 and 128. The scale is 1/sqrt(d_qk)."""
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    q, k = (jax.random.normal(key, (2, 64, 2, 24), jnp.float32) for key in keys[:2])
+    v = jax.random.normal(keys[2], (2, 64, 2, 16), jnp.float32)
+    flash = partial(flash_attention, causal=causal, interpret=True)
+    dense = partial(reference_attention, causal=causal)
+    if what == "forward":
+        got, want = flash(q, k, v), dense(q, k, v)
+        assert got.shape == want.shape == (2, 64, 2, 16)
+    else:
+        got, want = (jax.grad(lambda *a: jnp.sum(f(*a) * jnp.cos(f(*a))), argnums=(0, 1, 2))(
+            q, k, v) for f in (flash, dense))
+        assert [g.shape for g in got] == [q.shape, k.shape, v.shape]
+    assert _rel(got, want) <= 2e-5
+
+
+def _layer(held, experts=32, top=4, d=32, d_ff=16, **kw):
+    return expert.RoutedExperts(num_experts=experts, experts_per_token=top, d_ff=d_ff,
+                                held=held, scaling=2.5, interpret=True, **kw)
+
+
+def _layer_variables(key, experts=32, d=32):
+    """Parameters and routing bias of the uncut layer (all experts held)."""
+    return _layer((0, experts)).init(key, jnp.zeros((1, 8, d)))
+
+
+def _share_of(variables, lo, hi):
+    params = variables["params"]
+    return {**variables, "params": {
+        **params, **{name: params[name][lo:hi] for name in ("gate", "up", "down")}}}
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """The guide's share test: the routed parts of all 16 shares, with the
+    shared expert counted once, are the uncut reference's layer output."""
+    variables = _layer_variables(jax.random.PRNGKey(2))
+    params = variables["params"]
+    h = jax.random.normal(jax.random.PRNGKey(3), (1, 48, 32), jnp.float32)
+    uncut_cfg = {**TOY, "n_routed_experts": 32, "deployment": {"share": 0}}
+    want, _ = FAMILY._expert_layer(uncut_cfg, params, variables["routing"]["bias"], h[0])
+    shared = expert.SwiGLU(16).apply({"params": params["shared"]}, h)
+    total = shared
+    for r in range(16):
+        out = _layer((2 * r, 2 * r + 2)).apply(_share_of(variables, 2 * r, 2 * r + 2), h)
+        total = total + (out - shared)
+    assert _rel(total[0], want) <= 1e-5
+    # and one share alone is not it
+    assert _rel(out[0], want) > 1e-2
+
+
+def test_the_bias_enters_the_choice_and_not_the_weights():
+    scores = jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(4), (16, 32)))
+    none = jnp.zeros((32,))
+    ids, weights = expert.route_top_k(scores, none, 4, 2.5)
+    lifted = none.at[5].set(10.0)
+    ids_b, weights_b = expert.route_top_k(scores, lifted, 4, 2.5)
+    assert np.all(np.any(np.asarray(ids_b) == 5, axis=1))       # every token now takes 5
+    assert not np.all(np.any(np.asarray(ids) == 5, axis=1))
+    chosen = np.take_along_axis(np.asarray(scores), np.asarray(ids_b), axis=1)
+    np.testing.assert_allclose(weights_b, 2.5 * chosen / chosen.sum(1, keepdims=True),
+                               rtol=1e-6)                        # from s, not from s + b
+    np.testing.assert_allclose(np.asarray(weights_b).sum(1), 2.5, rtol=1e-6)
+    _, forced = expert.route_top_k(scores, lifted, 4, 2.5, choice=ids)
+    np.testing.assert_array_equal(forced, weights)               # a given choice ignores it
+
+
+def _masked_layer(params, h, ids, weights, lo, hi):
+    """The routed part, each held expert on every token under its mask."""
+    out = jnp.zeros_like(h)
+    for e in range(lo, hi):
+        mask = jnp.sum(jnp.where(ids == e, weights, 0.0), axis=-1)
+        y = (jax.nn.silu(h @ params["gate"][e - lo]) * (h @ params["up"][e - lo])) \
+            @ params["down"][e - lo]
+        out = out + mask[:, None] * y
+    return out
+
+
+@pytest.mark.parametrize("load", ["one expert takes most rows", "more rows than the buffer"])
+def test_ragged_loads_drop_nothing_and_an_overflow_is_counted(load):
+    """128 tokens, top-4 of 32: with experts [8, 16) held the buffer takes
+    every slot (512); with [8, 12) held, four times the uniform share, 256."""
+    h = jax.random.normal(jax.random.PRNGKey(6), (1, 128, 32), jnp.float32)
+    if load == "one expert takes most rows":
+        # every token takes expert 9, every fourth also 12; the rest elsewhere
+        lo, hi, bound = 8, 16, 512
+        choice = jnp.tile(jnp.array([9, 0, 1, 2]), (128, 1)).at[::4, 1].set(12)
+        routed = 128 + 32
+    else:
+        lo, hi, bound = 8, 12, 256
+        choice = jnp.tile(jnp.array([8, 9, 10, 11]), (128, 1))   # 512 slots, all held
+        routed = 512
+    assert expert.routed_rows_bound(128, 4, hi - lo, 32) == bound
+    variables = _share_of(_layer_variables(jax.random.PRNGKey(5)), lo, hi)
+    params = variables["params"]
+    out, state = _layer((lo, hi)).apply(variables, h, choice[None], mutable=["intermediates"])
+    counters = state["intermediates"]["moe_counters"][0]
+    assert int(counters["rows_routed"]) == routed
+    assert int(counters["rows_overflowed"]) == max(routed - bound, 0)
+    scores = jax.nn.sigmoid(h[0] @ params["router"])
+    _, weights = expert.route_top_k(scores, variables["routing"]["bias"], 4, 2.5, choice=choice)
+    if routed <= bound:
+        assert float(counters["load_max_over_mean"]) == pytest.approx(128 * 8 / 160)
+    else:
+        # expert order: 8 and 9 fit whole, 10 and 11 are cut; nothing else is touched
+        weights = jnp.where(choice < 10, weights, 0.0)
+        assert np.all(np.isfinite(np.asarray(out)))
+    want = _masked_layer(params, h[0], choice, weights, lo, hi) \
+        + expert.SwiGLU(16).apply({"params": params["shared"]}, h[0])
+    assert _rel(out[0], want) <= 1e-5
+
+
+def test_grouped_matmul_gradients_with_an_empty_expert():
+    """Rows of experts 0 and 2 of three; expert 1 has none and a zero gradient."""
+    ids = jnp.array([[0], [2], [2], [0], [2]])
+    slot, valid, tile_expert, used, _ = expert.dispatch_held(ids, (0, 3), bound=5)
+    assert tile_expert.tolist()[:3] == [0, 1, 2] and int(used[0]) == 3
+    x = jax.random.normal(jax.random.PRNGKey(8), (5, 16), jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(9), (3, 16, 8), jnp.float32)
+    rows = jnp.where(valid[:, None], x[slot], 0)
+
+    def grouped(rows, w):
+        return jnp.sum(jnp.sin(expert.grouped_matmul(rows, w, tile_expert, used, True)))
+
+    def dense(rows, w):
+        every = jnp.einsum("rk,ekn->ern", rows, w)
+        mine = every[tile_expert[jnp.arange(rows.shape[0]) // expert.ROW_TILE],
+                     jnp.arange(rows.shape[0])]
+        in_use = jnp.arange(rows.shape[0]) < used[0] * expert.ROW_TILE  # padding rows too
+        return jnp.sum(jnp.sin(jnp.where(in_use[:, None], mine, 0.0)))
+
+    got, want = (jax.grad(f, argnums=(0, 1))(rows, w) for f in (grouped, dense))
+    assert _rel(got, want) <= 1e-5
+    assert not np.any(np.asarray(got[1][1]))
+
+
+def test_free_running_choices_agree_with_the_reference_and_forcing_changes_nothing(toy):
+    """In float32 both sides pick the same experts, so forcing the reference's
+    choice on the system leaves its logits as they were; the check on the chip
+    (bfloat16 against float32) forces it, and prints this share."""
+    cfg, params, routing, batch = toy
+    tokens = batch[0][:1]
+    net = FAMILY.model(cfg)
+    variables = {"params": params, "routing": routing}
+    (logits, mtp), state = net.apply(variables, tokens, mutable=["intermediates"])
+    want, choices = FAMILY.plain_forward(cfg, params, routing, tokens, positions=64)
+    ours = moe_choices(state["intermediates"])
+    assert len(ours) == len(choices) == 3
+    for a, b in zip(ours, choices):
+        assert float(jnp.mean((a[..., :, None] == b[..., None, :]).any(-1))) >= 0.99
+    forced, forced_mtp = net.apply(variables, tokens, choices=choices)
+    assert _rel(jnp.stack([forced[0], forced_mtp[0][0]]), want) <= 1e-5
+    assert _rel(forced, logits) <= 1e-5
+    assert int(moe_counters(state["intermediates"])["rows_overflowed"]) == 0
+
+
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+def test_a_forward_pass_ends_with_the_balancing_step_where_routing_is_mutable(scoring):
+    """``bias += speed * sign(mean load - load)`` over all 32 experts from this
+    pass's choices, which were made with the bias as it came in; without
+    ``mutable`` the pass leaves no new bias."""
+    layer = _layer((8, 16), scoring=scoring, bias_update_speed=0.25)
+    variables = _share_of(_layer_variables(jax.random.PRNGKey(14)), 8, 16)
+    h = jax.random.normal(jax.random.PRNGKey(15), (1, 48, 32), jnp.float32)
+    out, state = layer.apply(variables, h, mutable=["intermediates", "routing"])
+    before = np.asarray(variables["routing"]["bias"])
+    score = jax.nn.sigmoid if scoring == "sigmoid" else partial(jax.nn.softmax, axis=-1)
+    want_ids, _ = expert.route_top_k(score(h[0] @ variables["params"]["router"]), before, 4, 2.5)
+    ids = np.asarray(state["intermediates"]["moe_choice"][0])[0]
+    np.testing.assert_array_equal(np.sort(ids, axis=1), np.sort(np.asarray(want_ids), axis=1))
+    load = np.bincount(ids.reshape(-1), minlength=32)
+    assert load.sum() == 48 * 4 and load.max() > 6 > load.min()      # mean 6: both signs
+    np.testing.assert_allclose(state["routing"]["bias"],
+                               before + 0.25 * np.sign(6.0 - load), rtol=0, atol=1e-7)
+    frozen, state = layer.apply(variables, h, mutable=["intermediates"])
+    assert "routing" not in state
+    np.testing.assert_array_equal(frozen, out)
+
+
+@pytest.mark.parametrize("speed", [0.0, 0.001])
+def test_the_bias_moves_by_the_balancing_rule_alone_through_opt_step(speed, bf8):
+    """Three steps of ``DistributedNeighborAllreduceOptimizer(adam)``: the
+    routing bias is model state, so Adam never sees it; every step moves each
+    of its elements by ``speed`` up or down (not at all at speed 0), every
+    parameter moves, and ``metrics["aux"]`` carries the expert layers' counters."""
+    cfg = dataclasses.replace(FAMILY.lm_config(TOY), num_hidden_layers=2,
+                              bias_update_speed=speed)
+    net = ConfigLM(cfg, interpret=True)
+    tokens = jax.random.randint(jax.random.PRNGKey(10), (8, 1, 32), 0, cfg.vocab_size)
+    batch = (tokens, jnp.roll(tokens, -1, axis=2), jnp.roll(tokens, -2, axis=2))
+    variables = net.init(jax.random.PRNGKey(11), tokens[0])
+    params, routing = variables["params"], variables["routing"]
+    assert not any("bias" in jax.tree_util.keystr(path)
+                   for path, _ in jax.tree_util.tree_flatten_with_path(params)[0])
+    opt = bf.DistributedNeighborAllreduceOptimizer(
+        optax.adam(1e-2), next_token_loss(net), with_model_state=True)
+    state = opt.init(params, model_state=routing)
+    for _ in range(3):
+        state, metrics = opt.step(state, batch)
+    after = bf.optimizers.unreplicate(jax.device_get(state.params))
+    for (path, leaf), before in zip(jax.tree_util.tree_flatten_with_path(after)[0],
+                                    jax.tree_util.tree_leaves(params)):
+        assert np.any(np.asarray(leaf) != np.asarray(before)), jax.tree_util.keystr(path)
+    biases = jax.tree_util.tree_leaves(jax.device_get(state.model_state))
+    assert len(biases) == 2                       # one expert layer and the MTP block
+    for leaf, before in zip(biases, jax.tree_util.tree_leaves(routing)):
+        assert leaf.shape == (8, 32) and np.any(np.asarray(before))
+        moved = (leaf - np.asarray(before)[None]) / (speed or 1.0)
+        if speed:
+            # 32 tokens x 4 of 32: the mean load is 4, so a step can be 0 too
+            np.testing.assert_allclose(moved, np.round(moved), atol=2e-3)
+            assert np.abs(moved).max() <= 3 + 2e-3 and np.any(np.abs(moved) > 0.5)
+            assert np.any(moved[0] != moved[1])   # every rank by its own tokens
+        else:
+            assert not np.any(moved)
+    aux = jax.device_get(metrics["aux"])
+    assert aux["rows_routed"].shape == (8,) and np.all(aux["rows_routed"] > 0)
+    assert np.all(aux["rows_overflowed"] == 0) and np.all(aux["load_max_over_mean"] >= 1.0)
+    assert np.all(np.isfinite(np.asarray(metrics["loss"])))
+
+
+def test_a_dense_equal_width_configuration_runs_without_experts_or_mtp():
+    """The other choices of the block: equal-width heads, every layer dense."""
+    cfg = LMConfig(vocab_size=64, hidden_size=32, num_hidden_layers=2, num_attention_heads=4,
+                   intermediate_size=48, attention="equal", rope_interleave=False)
+    net = ConfigLM(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(12), (2, 16), 0, 64)
+    variables = net.init(jax.random.PRNGKey(13), tokens)
+    params = variables["params"]
+    assert set(variables) == {"params"} and set(params["layer_0"]["attn"]) == {"qkv", "o"}
+    logits, state = net.apply({"params": params}, tokens, mutable=["intermediates"])
+    assert logits.shape == (2, 16, 64) and moe_counters(state.get("intermediates", {})) == {}
+    loss, (routing, counters) = next_token_loss(net)(
+        params, {}, (tokens, jnp.roll(tokens, -1, axis=1)))
+    assert np.isfinite(float(loss)) and routing == {} and counters == {}
