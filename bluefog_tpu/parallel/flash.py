@@ -27,8 +27,11 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..runtime import metrics
 
 _NEG = -1e30
 
@@ -102,6 +105,131 @@ def _dot_prec(dtype):
     return (jax.lax.Precision.HIGHEST if jnp.dtype(dtype) == jnp.float32
             else jax.lax.Precision.DEFAULT)
 
+
+# -- the causal schedule -----------------------------------------------------
+# One predicate decides what a grid step fetches (the index maps), whether it
+# computes (the kernels' ``pl.when``) and what ``causal_schedule`` counts.
+# ``offs`` is (q_off, k_off), the global positions of element 0 of either
+# side: the scalar-prefetch ref in a kernel or an index map, a pair of ints
+# in ``causal_schedule``, which passes ``xp=np`` and whole grids of indices.
+
+def _causal_tile(offs, qi, kj, tq, tk):
+    """(live, interior) of the tile pair (qi, kj). Live: some column of the
+    K tile is at or before the q tile's last row; a dead step computes
+    nothing. Interior: the whole K tile is at or before its first row, so
+    the mask is a no-op. Live and not interior is a tile on the diagonal."""
+    q_lo = offs[0] + qi * tq
+    k_lo = offs[1] + kj * tk
+    return k_lo <= q_lo + tq - 1, k_lo + tk - 1 <= q_lo
+
+
+def _chunk(tq: int, tk: int) -> int:
+    """Width of the column chunks a diagonal tile is computed in: ``tq``
+    where the K tile is several whole ones, else the tile (one chunk)."""
+    return tq if tk > tq and tk % tq == 0 else tk
+
+
+def _live_chunks(offs, qi, kj, tq, tk, xp=jnp):
+    """Leading column chunks of a LIVE K tile that hold a column allowed to
+    some row of q tile qi (1 .. tk / chunk). The columns behind them are
+    masked for every row: p = 0 there and no row's maximum comes from them,
+    so leaving them out changes no number."""
+    cw = _chunk(tq, tk)
+    q_hi = offs[0] + qi * tq + tq - 1
+    return xp.minimum((q_hi - offs[1] - kj * tk) // cw + 1, tk // cw)
+
+
+def _kv_block(offs, qi, kj, tq, tk, xp=jnp):
+    """K/V block of a forward / dq grid step (kj innermost, so a row's dead
+    steps are its last): block 0 on a dead step. Pallas copies a block only
+    when its index differs from the previous step's: block 0 arrives behind
+    the last live step's compute, stays through the dead steps and is what
+    the next row starts with, so no copy is issued that no step reads."""
+    live, _ = _causal_tile(offs, qi, kj, tq, tk)
+    return xp.where(live, kj, 0)
+
+
+def _q_block(offs, qi, kj, tq, tk, nq, xp=jnp):
+    """q-side block (q, g, m, l, d) of a dk/dv grid step (qi innermost, so
+    a row's dead steps are its first): the row's first live q tile on a dead
+    step, the last q tile where the whole row is dead (what the row before
+    ended on)."""
+    live, _ = _causal_tile(offs, qi, kj, tq, tk)
+    first = xp.maximum(offs[1] + kj * tk - offs[0], 0) // tq
+    return xp.where(live, qi, xp.minimum(first, nq - 1))
+
+
+def _idle_fetches(block, live) -> int:
+    """Copies no step reads: runs of one block index, in grid order, that
+    hold no live step."""
+    starts = np.flatnonzero(np.r_[True, block[1:] != block[:-1]])
+    return int((np.add.reduceat(live.astype(np.int64), starts) == 0).sum())
+
+
+def causal_schedule(sq: int, sk: int, q_off: int = 0, k_off: int = 0) -> dict:
+    """What the three causal kernels do for one (batch, head) of q [sq]
+    against a K/V block [sk] at these offsets, counted from the helpers the
+    kernels and their index maps are made of:
+
+    ``steps`` / ``live`` / ``dead``: grid steps of one kernel and their
+    causal classes; ``dead_fetching``: copies issued for dead steps only
+    (the larger of the two grid orders: K/V blocks with kj innermost, q-side
+    blocks with qi innermost); ``chunks_computed``: [tq, chunk] score chunks
+    the live steps work on; ``chunks_needed``: those that hold any allowed
+    (row, column) pair, counted over the whole block without the tiles."""
+    tq, tk = _q_tile(sq), _k_tile(sk)
+    nq, nk, cw = sq // tq, sk // tk, _chunk(tq, tk)
+    offs = (q_off, k_off)
+    qi, kj = np.meshgrid(np.arange(nq), np.arange(nk), indexing="ij")
+    live, interior = _causal_tile(offs, qi, kj, tq, tk)
+    computed = np.where(interior, tk // cw, np.where(
+        live, _live_chunks(offs, qi, kj, tq, tk, xp=np), 0))
+    q_hi = q_off + np.arange(nq) * tq + tq - 1
+    needed = k_off + np.arange(sk // cw) * cw <= q_hi[:, None]
+    kv = _kv_block(offs, qi, kj, tq, tk, xp=np)
+    qb = _q_block(offs, qi, kj, tq, tk, nq, xp=np)
+    n_live = int(live.sum())
+    return {
+        "steps": nq * nk, "live": n_live, "dead": nq * nk - n_live,
+        "dead_fetching": max(_idle_fetches(kv.ravel(), live.ravel()),
+                             _idle_fetches(qb.T.ravel(), live.T.ravel())),
+        "chunks_computed": int(computed.sum()),
+        "chunks_needed": int(needed.sum()),
+    }
+
+
+def _kv_index_map(causal: bool, tq: int, tk: int):
+    """Index map of the K and V specs of the forward / dq grid (bh, qi, kj)."""
+    if not causal:
+        return lambda bh, qi, kj, offs: (bh, kj, 0)
+    return lambda bh, qi, kj, offs: (bh, _kv_block(offs, qi, kj, tq, tk), 0)
+
+
+def _q_index_map(causal: bool, tq: int, tk: int, nq: int):
+    """Index map of the q-side specs of the dk/dv grid (bh, kj, qi)."""
+    if not causal:
+        return lambda bh, kj, qi, offs: (bh, qi, 0)
+    return lambda bh, kj, qi, offs: (
+        bh, _q_block(offs, qi, kj, tq, tk, nq), 0)
+
+
+def _when_causal(offs_ref, qi, kj, tq, tk, body):
+    """Run ``body(masked, width)`` for this step's causal class: not at all
+    on a dead step, unmasked over the whole K tile in the interior, and on
+    the diagonal masked over the leading ``width`` columns that hold an
+    allowed one — a static width a variant, picked by ``pl.when``."""
+    live, interior = _causal_tile(offs_ref, qi, kj, tq, tk)
+    pl.when(interior)(lambda: body(False, tk))
+    diagonal = live & ~interior
+    cw = _chunk(tq, tk)
+    if cw == tk:
+        pl.when(diagonal)(lambda: body(True, tk))
+        return
+    n = _live_chunks(offs_ref, qi, kj, tq, tk)
+    for c in range(1, tk // cw + 1):
+        pl.when(diagonal & (n == c))(functools.partial(body, True, c * cw))
+
+
 def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
             causal: bool, scale: float):
     qi = pl.program_id(1)
@@ -117,24 +245,24 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
         m_ref[0] = jnp.full_like(m_ref[0], _NEG)
         l_ref[0] = jnp.zeros_like(l_ref[0])
 
-    def body(masked: bool):
+    def body(masked: bool, w: int):
         # Dots keep the inputs' NATIVE dtype (bf16) with f32 accumulation:
         # the MXU runs bf16x bf16 at 4x its f32 rate, and the operands are
         # already bf16 so the products are bit-identical; only the scale
         # (applied post-dot, in f32) and the p cast below round differently
         # — the standard flash-attention-2 precision recipe.
         q = q_ref[0]                                  # [TQ, D] native dtype
-        k = k_ref[0]                                  # [TK, D]
-        v = v_ref[0]
+        k = k_ref[0, :w, :]                           # [W, D], W <= TK
+        v = v_ref[0, :w, :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=_dot_prec(q_ref.dtype)) * scale
         if masked:
             q_pos = offs_ref[0] + qi * tq + jax.lax.broadcasted_iota(
-                jnp.int32, (tq, tk), 0)
+                jnp.int32, (tq, w), 0)
             k_pos = offs_ref[1] + kj * tk + jax.lax.broadcasted_iota(
-                jnp.int32, (tq, tk), 1)
+                jnp.int32, (tq, w), 1)
             allowed = q_pos >= k_pos
             s = jnp.where(allowed, s, _NEG)
         m_prev = m_ref[0][:, 0]                       # [TQ]
@@ -155,20 +283,15 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
         l_ref[0] = jnp.broadcast_to(l_new[:, None], (tq, 8))
 
     if causal:
-        # Three tile classes (VPU saver — masking builds two [TQ, TK]
-        # iotas + compares + selects per tile, and only DIAGONAL tiles
-        # need it): dead tiles (K entirely in the future) are skipped;
-        # interior tiles (K entirely in the past) run unmasked; diagonal
-        # tiles pay the mask. At S >> TQ the diagonal is a vanishing
-        # fraction of live tiles.
-        live = (offs_ref[1] + kj * tk
-                <= offs_ref[0] + qi * tq + tq - 1)
-        interior = (offs_ref[1] + kj * tk + tk - 1
-                    <= offs_ref[0] + qi * tq)
-        pl.when(interior)(lambda: body(False))
-        pl.when(live & ~interior)(lambda: body(True))
+        # Three tile classes: dead tiles (K entirely in the future) are
+        # skipped and fetch nothing (_kv_block); interior tiles (K entirely
+        # in the past) run unmasked; diagonal tiles pay the mask — two
+        # [TQ, W] iotas, compares and selects — over their live column
+        # chunks only. With TK = 4 TQ the diagonal is no small part: 16 of
+        # the 40 live tiles of an 8192-token sequence, every tile at 2048.
+        _when_causal(offs_ref, qi, kj, tq, tk, body)
     else:
-        body(False)
+        body(False, tk)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "interpret"))
@@ -195,6 +318,7 @@ def flash_block(q, k, v, q_off, k_off, *, causal: bool = True,
     offs = jnp.asarray([q_off, k_off], jnp.int32)
     grid = (B * H, Sq // tq, Sk // tk)
     kernel = functools.partial(_kernel, causal=causal, scale=scale)
+    kv_map = _kv_index_map(causal, tq, tk)
     kw = _vma(q, k, v)
     out_shape = (
         jax.ShapeDtypeStruct((B * H, Sq, Dv), jnp.float32, **kw),
@@ -206,8 +330,8 @@ def flash_block(q, k, v, q_off, k_off, *, causal: bool = True,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, tq, D), lambda bh, qi, kj, offs: (bh, qi, 0)),
-            pl.BlockSpec((1, tk, D), lambda bh, qi, kj, offs: (bh, kj, 0)),
-            pl.BlockSpec((1, tk, Dv), lambda bh, qi, kj, offs: (bh, kj, 0)),
+            pl.BlockSpec((1, tk, D), kv_map),
+            pl.BlockSpec((1, tk, Dv), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, tq, Dv), lambda bh, qi, kj, offs: (bh, qi, 0)),
@@ -234,8 +358,10 @@ def flash_block(q, k, v, q_off, k_off, *, causal: bool = True,
 
 
 def _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref,
-               d_ref, masked: bool, scale: float):
-    """Shared backward-tile recompute -> (q, k, g*inv_l, P_unnorm, dS).
+               d_ref, masked: bool, w: int, scale: float):
+    """Shared backward-tile recompute -> (q, k, g*inv_l, P_unnorm, dS) over
+    the leading ``w`` columns of the K/V tile (all of it but on a diagonal
+    tile: _when_causal).
 
     The probability tile is rebuilt in VMEM from the saved GLOBAL (m, l)
     row statistics with the same offset-based causal mask as the forward
@@ -251,8 +377,8 @@ def _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref,
     # scale moves AFTER the qk dot (q stays unscaled, so the dk pass
     # applies it explicitly)
     q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
+    k = k_ref[0, :w, :]
+    v = v_ref[0, :w, :]
     g = g_ref[0]
     m = m_ref[0][:, 0]
     inv_l = 1.0 / l_ref[0][:, 0]
@@ -262,9 +388,9 @@ def _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref,
                             precision=_dot_prec(q_ref.dtype)) * scale
     if masked:
         q_pos = offs_ref[0] + qi * tq + jax.lax.broadcasted_iota(
-            jnp.int32, (tq, tk), 0)
+            jnp.int32, (tq, w), 0)
         k_pos = offs_ref[1] + kj * tk + jax.lax.broadcasted_iota(
-            jnp.int32, (tq, tk), 1)
+            jnp.int32, (tq, w), 1)
         allowed = q_pos >= k_pos
         s = jnp.where(allowed, s, _NEG)
     # VPU saver: the softmax row normalizer inv_l is folded into the
@@ -287,19 +413,6 @@ def _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref,
     return q, k, g_scaled, p, ds
 
 
-def _bwd_live(offs_ref, qi, kj, tq, tk):
-    """Causal block-skip shared by both backward passes (same predicate as
-    the forward): the tile pair is dead when the whole K tile lies in the
-    future of the last query row."""
-    return offs_ref[1] + kj * tk <= offs_ref[0] + qi * tq + tq - 1
-
-
-def _bwd_interior(offs_ref, qi, kj, tq, tk):
-    """K tile entirely in the past of the whole q tile: masking is a no-op
-    (see the forward's three tile classes)."""
-    return offs_ref[1] + kj * tk + tk - 1 <= offs_ref[0] + qi * tq
-
-
 def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref,
                dq_ref, *, causal: bool, scale: float):
     """dQ pass (flash-attention-2 backward): for each query tile, iterate
@@ -312,23 +425,20 @@ def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref,
     def _init():
         dq_ref[0] = jnp.zeros_like(dq_ref[0])
 
-    def body(masked: bool):
+    def body(masked: bool, w: int):
         _, k, _, _, ds = _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref,
-                                    g_ref, m_ref, l_ref, d_ref, masked,
+                                    g_ref, m_ref, l_ref, d_ref, masked, w,
                                     scale)
         dq_ref[0] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=_dot_prec(q_ref.dtype)) * scale
 
+    tq, tk = q_ref.shape[1], k_ref.shape[1]
     if causal:
-        tq, tk = q_ref.shape[1], k_ref.shape[1]
-        live = _bwd_live(offs_ref, qi, kj, tq, tk)
-        interior = _bwd_interior(offs_ref, qi, kj, tq, tk)
-        pl.when(interior)(lambda: body(False))
-        pl.when(live & ~interior)(lambda: body(True))
+        _when_causal(offs_ref, qi, kj, tq, tk, body)
     else:
-        body(False)
+        body(False, tk)
 
 
 def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref,
@@ -343,29 +453,28 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref,
         dk_ref[0] = jnp.zeros_like(dk_ref[0])
         dv_ref[0] = jnp.zeros_like(dv_ref[0])
 
-    def body(masked: bool):
+    def body(masked: bool, w: int):
         q, _, g, p, ds = _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref,
-                                    g_ref, m_ref, l_ref, d_ref, masked,
+                                    g_ref, m_ref, l_ref, d_ref, masked, w,
                                     scale)
-        dv_ref[0] += jax.lax.dot_general(
+        # rows of dk/dv behind the leading w belong to columns that are
+        # masked for this whole q tile: nothing is added to them
+        dv_ref[0, :w, :] += jax.lax.dot_general(
             p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=_dot_prec(q_ref.dtype))
         # q is unscaled in the shared tile recompute: apply the score scale
         # here (dK = dS^T @ (scale * Q))
-        dk_ref[0] += jax.lax.dot_general(
+        dk_ref[0, :w, :] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=_dot_prec(q_ref.dtype)) * scale
 
+    tq, tk = q_ref.shape[1], k_ref.shape[1]
     if causal:
-        tq, tk = q_ref.shape[1], k_ref.shape[1]
-        live = _bwd_live(offs_ref, qi, kj, tq, tk)
-        interior = _bwd_interior(offs_ref, qi, kj, tq, tk)
-        pl.when(interior)(lambda: body(False))
-        pl.when(live & ~interior)(lambda: body(True))
+        _when_causal(offs_ref, qi, kj, tq, tk, body)
     else:
-        body(False)
+        body(False, tk)
 
 
 def _lane8(x):  # [B, S, H] -> [B*H, S, 8] (TPU sublane x lane tiling)
@@ -406,14 +515,17 @@ def flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off, *,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             **_bwd_vmem(D, Dv))}
 
+    kv_map = _kv_index_map(causal, tq, tk)
+    q_map = _q_index_map(causal, tq, tk, Sq // tq)
+
     # pass 1: dq (K innermost, accumulates into the q tile's output)
     dq_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B * H, Sq // tq, Sk // tk),
         in_specs=[
             pl.BlockSpec((1, tq, D), lambda bh, qi, kj, o: (bh, qi, 0)),
-            pl.BlockSpec((1, tk, D), lambda bh, qi, kj, o: (bh, kj, 0)),
-            pl.BlockSpec((1, tk, Dv), lambda bh, qi, kj, o: (bh, kj, 0)),
+            pl.BlockSpec((1, tk, D), kv_map),
+            pl.BlockSpec((1, tk, Dv), kv_map),
             pl.BlockSpec((1, tq, Dv), lambda bh, qi, kj, o: (bh, qi, 0)),
             pl.BlockSpec((1, tq, 8), lambda bh, qi, kj, o: (bh, qi, 0)),
             pl.BlockSpec((1, tq, 8), lambda bh, qi, kj, o: (bh, qi, 0)),
@@ -436,13 +548,13 @@ def flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off, *,
         num_scalar_prefetch=1,
         grid=(B * H, Sk // tk, Sq // tq),
         in_specs=[
-            pl.BlockSpec((1, tq, D), lambda bh, kj, qi, o: (bh, qi, 0)),
+            pl.BlockSpec((1, tq, D), q_map),
             pl.BlockSpec((1, tk, D), lambda bh, kj, qi, o: (bh, kj, 0)),
             pl.BlockSpec((1, tk, Dv), lambda bh, kj, qi, o: (bh, kj, 0)),
-            pl.BlockSpec((1, tq, Dv), lambda bh, kj, qi, o: (bh, qi, 0)),
-            pl.BlockSpec((1, tq, 8), lambda bh, kj, qi, o: (bh, qi, 0)),
-            pl.BlockSpec((1, tq, 8), lambda bh, kj, qi, o: (bh, qi, 0)),
-            pl.BlockSpec((1, tq, 8), lambda bh, kj, qi, o: (bh, qi, 0)),
+            pl.BlockSpec((1, tq, Dv), q_map),
+            pl.BlockSpec((1, tq, 8), q_map),
+            pl.BlockSpec((1, tq, 8), q_map),
+            pl.BlockSpec((1, tq, 8), q_map),
         ],
         out_specs=[
             pl.BlockSpec((1, tk, D), lambda bh, kj, qi, o: (bh, kj, 0)),
@@ -553,4 +665,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
     works on a single chip at sequence lengths where dense attention is
     OOM-bound.
     """
+    if causal:
+        # trace-time gauges of the schedule the kernels run (docs/metrics.md)
+        sched = causal_schedule(q.shape[1], k.shape[1])
+        metrics.gauge("flash.dead_steps_fetching").set(sched["dead_fetching"])
+        metrics.gauge("flash.chunks_computed").set(sched["chunks_computed"])
+        metrics.gauge("flash.chunks_needed").set(sched["chunks_needed"])
     return _flash(q, k, v, causal, interpret)

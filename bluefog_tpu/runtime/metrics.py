@@ -526,6 +526,17 @@ _HELP_EXACT: Dict[str, str] = {
                       "submit to reply)",
     "slo.staleness_ver": "snapshot versions between the fence and the "
                          "version that answered each request",
+    "flash.dead_steps_fetching": "copies the causal flash kernels issue "
+                                 "for dead grid steps alone, one (batch, "
+                                 "head) and kernel of the last "
+                                 "flash_attention traced "
+                                 "(flash.causal_schedule)",
+    "flash.chunks_computed": "[tq, chunk] score chunks the live grid steps "
+                             "of one (batch, head) and kernel work on, last "
+                             "flash_attention traced",
+    "flash.chunks_needed": "score chunks of that call that hold an allowed "
+                           "(row, column) pair: the causal floor of "
+                           "flash.chunks_computed",
     "trace.requests": "serve requests traced into the flight ring "
                       "(BLUEFOG_TRACE_SERVE; docs/slo.md)",
 }
@@ -555,7 +566,7 @@ _HELP_PREFIX = (
 # segment). The bfcheck [metrics] analyzer enforces this plus HELP
 # resolution for every creation site in the package — a new family must
 # be added here (with curated HELP coverage) before it can ship.
-_PREFIX_FAMILIES = ("alert", "cp", "hb", "membership", "opt", "pushsum",
+_PREFIX_FAMILIES = ("alert", "cp", "flash", "hb", "membership", "opt", "pushsum",
                     "serve", "slo", "trace", "tune", "watchdog", "win")
 
 

@@ -15,8 +15,10 @@ benchmarks, with random weights from a seed:
   (``scaling.permute_start_slack``), the hierarchical optimizer on the host's
   machine mesh, and two ``DistributedWinPutOptimizer`` steps on a small MLP.
 * ``lm_flash``: the three compiled flash-attention kernels against the dense
-  f32-softmax reference, then the 4-layer d_model-2048 LM at 8192 tokens per
-  chip through ``bf.DistributedNeighborAllreduceOptimizer.step``.
+  f32-softmax reference at 2048 tokens (one K tile) and at 8192 (dead steps,
+  interior tiles and diagonal tiles of every chunk count), then the 4-layer
+  d_model-2048 LM at 8192 tokens per chip through
+  ``bf.DistributedNeighborAllreduceOptimizer.step``.
 * ``mla_moe``: the kernels again at latent attention's widths (q.k 192, v
   128); the three compiled kernels of ``grouped_matmul`` (the product and both
   gradients) against XLA matmuls on ragged loads with an empty expert; then
@@ -76,7 +78,10 @@ RESNET_BATCH, IMAGE, RESNET_STEPS = 128, 224, 5
 LM = dict(vocab_size=32768, num_layers=4, num_heads=16, d_model=2048,
           d_ff=8192)
 LM_SEQ, LM_STEPS = 8192, 4
-KERNEL_SHAPE = (1, 2048, 16, 128)  # B, S, H, D of the kernel-vs-reference leg
+# B, S, H, D of the kernel-vs-reference legs: one K tile, every step on the
+# diagonal; then the 16 x 4 grid of a benchmark sequence, one head of it (24
+# dead steps, 24 interior tiles, 16 diagonal tiles of one to four live chunks)
+KERNEL_SHAPES = {"s2048": (1, 2048, 16, 128), "s8192": (1, 8192, 1, 128)}
 # JoyAI-LLM-Flash's config.json, two layers and an eighth of the vocabulary
 MLA_MOE = LMConfig(
     vocab_size=16160, hidden_size=2048, num_hidden_layers=2, num_attention_heads=32,
@@ -278,10 +283,17 @@ def _mosaic_calls(fn, *args):
     return fn.lower(*args).as_text().count("tpu_custom_call")
 
 
-def _check_flash_kernels(d_v=KERNEL_SHAPE[3], d_qk=KERNEL_SHAPE[3]):
-    """Compiled forward, dq and dk/dv kernels against the dense reference."""
+def _check_flash_kernels(d_v=None, d_qk=None):
+    """Compiled forward, dq and dk/dv kernels against the dense reference, at
+    each of ``KERNEL_SHAPES`` (its width unless the caller gives two)."""
+    return {name: _check_flash_kernels_at(shape[:3], d_v or shape[3],
+                                          d_qk or shape[3])
+            for name, shape in KERNEL_SHAPES.items()}
+
+
+def _check_flash_kernels_at(bsh, d_v, d_qk):
     keys = jax.random.split(jax.random.PRNGKey(3), 4)
-    q, k, v, w = (jax.random.normal(kk, KERNEL_SHAPE[:3] + (d,), jnp.bfloat16)
+    q, k, v, w = (jax.random.normal(kk, bsh + (d,), jnp.bfloat16)
                   for kk, d in zip(keys, (d_qk, d_qk, d_v, d_v)))
 
     def weighted(attn):
