@@ -1,4 +1,4 @@
-# Test/bench targets, the analog of the reference's Makefile (whose targets
+# Test targets, the analog of the reference's Makefile (whose targets
 # wrap pytest under mpirun; here the multi-process harness is the 8-device
 # CPU-simulated mesh — see tests/conftest.py and SURVEY.md §4).
 
@@ -6,8 +6,8 @@ PYTEST      = python -m pytest
 MESH_ENV    = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 
 .PHONY: test test_fast test_ops test_win_ops test_optimizers test_parallel \
-        test_launcher test_models bench chaos dryrun native scaling \
-        lm_bench metrics-smoke flight-smoke soak-smoke obs-smoke \
+        test_launcher test_models chaos dryrun native scaling \
+        metrics-smoke flight-smoke soak-smoke obs-smoke \
         tune-smoke serve-smoke slo-smoke perf-gate lint bfcheck check \
         tsan asan
 
@@ -42,9 +42,6 @@ test_launcher:
 
 test_models:
 	$(PYTEST) tests/test_models.py tests/test_torch_interop.py -q
-
-bench:           ## headline benchmark on the default backend (real chip)
-	python bench.py
 
 metrics-smoke:   ## telemetry-plane acceptance: 2-rank in-process job with a
                  ## non-empty KV scrape + health snapshot + prometheus lint,
@@ -173,6 +170,3 @@ native:          ## build the native runtime extension
 
 scaling:         ## regenerate SCALING.md (compile-time scaling evidence)
 	JAX_PLATFORMS=cpu python -m bluefog_tpu.scaling
-
-lm_bench:        ## transformer tokens/s + MFU headline (real chip)
-	python scripts/lm_bench.py

@@ -23,7 +23,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -48,27 +47,21 @@ def _tile(s: int, candidates) -> int:
     return next(t for t in candidates if s % t == 0)
 
 
-def _env_tile(name: str, s: int, default: int) -> int:
-    """Tile override knob (perf sweeps): honored only when it divides s."""
-    v = int(os.environ.get(name, "0"))
-    return v if v > 0 and s % v == 0 else default
+# 512 x 2048 first: the r5 on-chip sweep (S=8192, D=128) had them ~5 % faster
+# a step than 256 x 1024, and 1024 x 4096 does not fit VMEM.
+_Q_TILES = (512, 256, 128, 64, 32, 16, 8, 4, 2, 1)
+# bound the [TQ, TK] f32 score tile (+ K/V tiles) well inside VMEM: holding
+# the whole K/V block per kernel invocation overflows the 16 MB scoped limit
+# past S~4k
+_K_TILES = (2048, 1024, 512, 256, 128, 64, 32, 16, 8, 4, 2, 1)
 
 
 def _q_tile(sq: int) -> int:
-    # 512/2048 defaults from the r5 on-chip sweep (S=8192, D=128): +5 %
-    # step time over the r4 256/1024 defaults; 1024/4096 fail to fit VMEM.
-    return _env_tile("BLUEFOG_FLASH_TQ", sq,
-                     _tile(sq, (512, 256, 128, 64, 32, 16, 8, 4, 2, 1)))
+    return _tile(sq, _Q_TILES)
 
 
 def _k_tile(sk: int) -> int:
-    # bound the [TQ, TK] f32 score tile (+ K/V tiles) well inside VMEM:
-    # holding the whole K/V block per kernel invocation overflows the 16 MB
-    # scoped limit past S~4k
-    return _env_tile("BLUEFOG_FLASH_TK", sk,
-                     _tile(sk, (2048, 1024, 512, 256, 128, 64, 32, 16, 8,
-                                4, 2, 1)))
-
+    return _tile(sk, _K_TILES)
 
 
 def _vma(*arrays) -> dict:

@@ -107,15 +107,6 @@ KNOBS: Tuple[Knob, ...] = (
          "groups (reference default kept; raise on high-latency links — "
          "window-mode dispatch cost is linear in group count, see PERF.md "
          "r5 optimizer matrix)"),
-    Knob("BLUEFOG_FLASH_TQ", "int", 0,
-         "flash-attention q tile size (r5 on-chip sweep default 512 when "
-         "unset/0); an override is honored only when it divides the "
-         "sequence block — sweep when changing head dim or VMEM pressure. "
-         "A causal tile on the diagonal is computed in column chunks of "
-         "this width where the k tile is several whole ones"),
-    Knob("BLUEFOG_FLASH_TK", "int", 0,
-         "flash-attention k tile size (sweep default 2048 when unset/0); "
-         "same divisibility rule as `BLUEFOG_FLASH_TQ`"),
     Knob("BLUEFOG_CYCLE_TIME", "float", 0.5,
          "ms; poll cadence of the host watchdog thread"),
     Knob("BLUEFOG_STALL_WARNING_TIME", "float", 60.0,

@@ -6,9 +6,10 @@ drives the gossip trainer once through the entry points a user calls —
 ``bf.neighbor_allreduce`` — at the full width of the two models the repo
 benchmarks, with random weights from a seed:
 
-* ``resnet50``: the step ``bench.py`` times (ResNet-50, bf16, 224x224, 128
-  images per chip, SGD-momentum, neighbor averaging), and the check that
-  rank ``r``'s slice of every state leaf lives on device ``r``.
+* ``resnet50``: the step of the benchmark's ``resnet50-b128-1chip`` cell
+  (ResNet-50, bf16, 224x224, 128 images per chip, SGD-momentum, neighbor
+  averaging), and the check that rank ``r``'s slice of every state leaf lives
+  on device ``r``.
 * ``gossip`` (more than one chip): ``bf.neighbor_allreduce`` against the
   topology's weight matrix, one ResNet-50 step per shift set of the dynamic
   one-peer Expo-2 schedule and where the compiler put each program's permutes

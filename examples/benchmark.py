@@ -7,6 +7,11 @@ model replica per chip, the chosen distributed optimizer doing the
 communication. The dynamic Expo-2 one-peer schedule is on by default exactly
 like the reference (``--disable-dynamic-topology`` restores the static graph).
 
+What this keeps is the reference's surface: every ``--dist-optimizer`` mode
+behind one command. The img/sec it prints is a count on whatever backend it
+ran on (the CPU mesh, in the tests) and goes into no record; the chip's
+numbers are the benchmark's (``benchmark/run.py``, PERF_LEDGER.jsonl).
+
 Run (single host, all chips):   python examples/benchmark.py
 Simulated 8-device CPU mesh:    bfrun --simulate 8 -- python examples/benchmark.py \
                                     --model mlp --batch-size 8 --num-iters 3

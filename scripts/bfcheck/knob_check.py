@@ -42,8 +42,7 @@ TABLE_BEGIN = "<!-- bfcheck:knob-table:begin (generated - edit "\
     "--write-docs`) -->"
 TABLE_END = "<!-- bfcheck:knob-table:end -->"
 
-PY_ROOTS = ("bluefog_tpu", "scripts", "tests", "bench.py",
-            "__graft_entry__.py")
+PY_ROOTS = ("bluefog_tpu", "scripts", "tests", "__graft_entry__.py")
 
 _CC_ENV_RE = re.compile(
     r'Env(?:Int|Seconds)\(\s*"(BLUEFOG_[A-Z0-9_]+)"\s*,\s*([-0-9.]+)')

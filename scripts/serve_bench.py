@@ -384,7 +384,7 @@ def bench_latency(args):
                  "slo.breach.serve_avail"):
         c = metrics_mod._REGISTRY._counters.get(name)
         if c is not None:
-            trace_rows[name] = c.value()
+            trace_rows[name] = c.value
     sc.close()
     try:
         pub_cl.close()

@@ -121,10 +121,10 @@ Q_OFFS = [0, 64, 24, 20, 61]
 
 @pytest.fixture
 def tiles_8x32(monkeypatch):
-    """The tile overrides are read while tracing: drop what was compiled
+    """The tile candidates are read while tracing: drop what was compiled
     under other tiles, before and after."""
-    monkeypatch.setenv("BLUEFOG_FLASH_TQ", str(TQ))
-    monkeypatch.setenv("BLUEFOG_FLASH_TK", str(TK))
+    monkeypatch.setattr(flash, "_Q_TILES", (TQ,))
+    monkeypatch.setattr(flash, "_K_TILES", (TK,))
     flash.flash_block.clear_cache()
     flash.flash_block_bwd.clear_cache()
     yield
@@ -243,9 +243,17 @@ def test_dead_steps_keep_their_neighbours_block(q_off, k_off):
     assert fetching == (0 if live.any() else 1)
 
 
+@pytest.mark.parametrize("env", [{}, {"BLUEFOG_FLASH_TQ": "8",
+                                      "BLUEFOG_FLASH_TK": "32"}],
+                         ids=["env_unset", "old_tile_knobs_set"])
 @pytest.mark.parametrize("s,want", [
     (8192, (64, 40, 24, 0, 136, 136)), (2048, (4, 4, 0, 0, 10, 10))])
-def test_causal_schedule_of_the_benchmarks_sequences(s, want):
+def test_causal_schedule_of_the_benchmarks_sequences(monkeypatch, s, want,
+                                                     env):
+    """The cells' geometry is the module's, whatever the environment holds."""
+    for name, value in env.items():
+        monkeypatch.setenv(name, value)
+    assert (flash._q_tile(s), flash._k_tile(s)) == (512, 2048)
     got = flash.causal_schedule(s, s, 0, 0)
     assert tuple(got[key] for key in (
         "steps", "live", "dead", "dead_fetching", "chunks_computed",
