@@ -64,8 +64,8 @@ def ring_attention_shard(q, k, v, *, axis_name: str, causal: bool = False,
     (``_ring_backward``): one more K/V trip around the ring with gradient
     blocks traveling alongside — residuals and carries are O(S/n) per chip.
     The flash path's per-step block gradients run in the pallas backward
-    kernels (``flash.flash_block_bwd``, flash-attention-2 dq + dk/dv
-    passes), so probability tiles stay in VMEM in the backward too; the
+    kernel (``flash.flash_block_bwd``: flash-attention-2's dq, dk and dv
+    from one pass), so probability tiles stay in VMEM in the backward too; the
     einsum path materializes one [S/n, S/n] f32 block per step via XLA.
     Reverse-mode only: the custom VJP means ``jax.jvp``/forward-over-reverse
     is unsupported on both ring paths.
@@ -134,9 +134,9 @@ def _ring_backward(axis_name: str, causal: bool, res, g,
     sequence-global (the standard ring-attention backward schedule).
 
     ``use_flash=True`` computes each step's (dq, dk, dv) partials with the
-    pallas backward kernels (``flash.flash_block_bwd``, flash-attention-2
-    dq + dk/dv passes) instead of XLA einsums — probability tiles live in
-    VMEM only, restoring the kernel forward's scores-never-reach-HBM
+    pallas backward kernel (``flash.flash_block_bwd``: flash-attention-2's
+    dq, dk and dv from one pass) instead of XLA einsums — probability tiles
+    live in VMEM only, restoring the kernel forward's scores-never-reach-HBM
     property for the backward as well.
     """
     q, k, v, out, m, l = res
@@ -274,7 +274,7 @@ def _ring_flash_fwd(q, k, v, axis_name, causal, interpret):
 def _ring_flash_bwd(axis_name, causal, interpret, res, g):
     # same reverse-rotation schedule as the einsum ring (the flash kernel's
     # (m, l) partials are the identical softmax statistics), with the
-    # per-block math in the pallas backward kernels
+    # per-block math in the pallas backward kernel
     return _ring_backward(axis_name, causal, res, g,
                           use_flash=True, interpret=interpret)
 

@@ -34,11 +34,12 @@ from ..runtime import metrics
 
 _NEG = -1e30
 
-# The three kernels as ``jax.named_scope``s, each around exactly one
+# The two kernels as ``jax.named_scope``s, each around exactly one
 # ``pallas_call``: the scope, not a function's name, is what a trace reducer
-# finds the kernel by (docs/timeline.md, "Names in a device trace").
+# finds the kernel by (docs/timeline.md, "Names in a device trace"). The
+# backward makes dq as well as dk and dv: "dkv" is the name its readers
+# know (benchmark/phases.KERNELS).
 SCOPE_FWD = "bf.flash.fwd"
-SCOPE_DQ = "bf.flash.dq"
 SCOPE_DKV = "bf.flash.dkv"
 
 
@@ -77,16 +78,36 @@ def _vma(*arrays) -> dict:
     return {"vma": frozenset().union(*(m for m in vmas if m is not None))}
 
 
-def _bwd_vmem(d: int, dv: int) -> dict:
-    """Scoped-VMEM limit of the two backward kernels, from the head widths.
-    Their 512 x 2048 tiles were sized (r5 sweep) for two 128-lane operands a
-    side inside Mosaic's default 16 MiB; a width is held in whole 128-lane
-    tiles, so a 192-wide q.k takes 256 lanes and the dk/dv kernel's stack
-    comes to 16.8 MiB. The limit grows with the lanes held instead of the
-    tiles shrinking; at 128 + 128 lanes nothing is passed and the kernels
-    lower as they always did."""
-    lanes = sum(-(-w // 128) * 128 for w in (d, dv))
-    return {} if lanes <= 256 else {"vmem_limit_bytes": (16 << 20) * lanes // 256}
+def _lanes(width: int) -> int:
+    """Lanes a width is held in: whole 128-lane tiles."""
+    return -(-width // 128) * 128
+
+
+# What the two buffers of the backward's resident dq block may take of a
+# core's VMEM (128 MiB on a v5e; the kernel's stack stands beside them):
+# 4 Mi rows x lanes of f32, i.e. 32k tokens of a 128-wide head, 16k at 192.
+_DQ_VMEM_BYTES = 32 << 20
+
+
+def _dq_rows(sq: int, d: int) -> int:
+    """Rows of q one backward call holds dq for: all of them where the
+    block fits ``_DQ_VMEM_BYTES`` twice over (Pallas double-buffers an
+    output), else the most whole q tiles that fit and divide ``sq``."""
+    tq = _q_tile(sq)
+    nq = sq // tq
+    fit = max(_DQ_VMEM_BYTES // (2 * 4 * _lanes(d) * tq), 1)
+    return tq * next(n for n in range(min(fit, nq), 0, -1) if nq % n == 0)
+
+
+def _bwd_vmem(rows: int, d: int, dv: int) -> int:
+    """Scoped-VMEM limit of the backward kernel, from the shapes alone. Its
+    512 x 2048 tiles were sized (r5 sweep) for two 128-lane operands a side
+    inside Mosaic's default 16 MiB; a width is held in whole 128-lane
+    tiles, so a 192-wide q.k takes 256 lanes and the stack comes to 16.8
+    MiB: it grows with the lanes held instead of the tiles shrinking.
+    Beside it stand the two buffers of the f32 dq block, ``rows`` long."""
+    stack = (16 << 20) * max(_lanes(d) + _lanes(dv), 256) // 256
+    return stack + 2 * rows * _lanes(d) * 4
 
 
 def _dot_prec(dtype):
@@ -133,7 +154,7 @@ def _live_chunks(offs, qi, kj, tq, tk, xp=jnp):
 
 
 def _kv_block(offs, qi, kj, tq, tk, xp=jnp):
-    """K/V block of a forward / dq grid step (kj innermost, so a row's dead
+    """K/V block of a forward grid step (kj innermost, so a row's dead
     steps are its last): block 0 on a dead step. Pallas copies a block only
     when its index differs from the previous step's: block 0 arrives behind
     the last live step's compute, stays through the dead steps and is what
@@ -143,7 +164,7 @@ def _kv_block(offs, qi, kj, tq, tk, xp=jnp):
 
 
 def _q_block(offs, qi, kj, tq, tk, nq, xp=jnp):
-    """q-side block (q, g, m, l, d) of a dk/dv grid step (qi innermost, so
+    """q-side block (q, g, m, l, d) of a backward grid step (qi innermost, so
     a row's dead steps are its first): the row's first live q tile on a dead
     step, the last q tile where the whole row is dead (what the row before
     ended on)."""
@@ -160,7 +181,7 @@ def _idle_fetches(block, live) -> int:
 
 
 def causal_schedule(sq: int, sk: int, q_off: int = 0, k_off: int = 0) -> dict:
-    """What the three causal kernels do for one (batch, head) of q [sq]
+    """What the causal kernels do for one (batch, head) of q [sq]
     against a K/V block [sk] at these offsets, counted from the helpers the
     kernels and their index maps are made of:
 
@@ -192,14 +213,14 @@ def causal_schedule(sq: int, sk: int, q_off: int = 0, k_off: int = 0) -> dict:
 
 
 def _kv_index_map(causal: bool, tq: int, tk: int):
-    """Index map of the K and V specs of the forward / dq grid (bh, qi, kj)."""
+    """Index map of the K and V specs of the forward grid (bh, qi, kj)."""
     if not causal:
         return lambda bh, qi, kj, offs: (bh, kj, 0)
     return lambda bh, qi, kj, offs: (bh, _kv_block(offs, qi, kj, tq, tk), 0)
 
 
 def _q_index_map(causal: bool, tq: int, tk: int, nq: int):
-    """Index map of the q-side specs of the dk/dv grid (bh, kj, qi)."""
+    """Index map of the q-side specs of the backward grid (bh, kj, qi)."""
     if not causal:
         return lambda bh, kj, qi, offs: (bh, qi, 0)
     return lambda bh, kj, qi, offs: (
@@ -360,14 +381,12 @@ def _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref,
     row statistics with the same offset-based causal mask as the forward
     kernel; the row normalizer rides the RETURNED g (see the inline note)
     so the [TQ, TK] tile is touched once less, and dS = P * (dP - D) is
-    the softmax-jacobian product both backward passes consume. One
-    definition keeps the dq and dk/dv kernels (and their masking) from
-    drifting apart. q is returned UNSCALED — the dk pass applies the
-    score scale itself."""
+    the softmax-jacobian product dk and dq are both made from. q is
+    returned UNSCALED — the dk product applies the score scale itself."""
     tq = q_ref.shape[1]
     tk = k_ref.shape[1]
     # native-dtype (bf16) dot operands, f32 accumulation — see _kernel; the
-    # scale moves AFTER the qk dot (q stays unscaled, so the dk pass
+    # scale moves AFTER the qk dot (q stays unscaled, so the dk product
     # applies it explicitly)
     q = q_ref[0]
     k = k_ref[0, :w, :]
@@ -406,40 +425,22 @@ def _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref,
     return q, k, g_scaled, p, ds
 
 
-def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref,
-               dq_ref, *, causal: bool, scale: float):
-    """dQ pass (flash-attention-2 backward): for each query tile, iterate
-    K/V tiles innermost and accumulate dq += dS @ K * scale — scores and
-    probabilities never reach HBM, same as the forward."""
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
-
-    @pl.when(kj == 0)
-    def _init():
-        dq_ref[0] = jnp.zeros_like(dq_ref[0])
-
-    def body(masked: bool, w: int):
-        _, k, _, _, ds = _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref,
-                                    g_ref, m_ref, l_ref, d_ref, masked, w,
-                                    scale)
-        dq_ref[0] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=_dot_prec(q_ref.dtype)) * scale
-
-    tq, tk = q_ref.shape[1], k_ref.shape[1]
-    if causal:
-        _when_causal(offs_ref, qi, kj, tq, tk, body)
-    else:
-        body(False, tk)
-
-
-def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref,
-                dk_ref, dv_ref, *, causal: bool, scale: float):
-    """dK/dV pass: for each K/V tile, iterate query tiles innermost and
-    accumulate dv += P^T @ dO and dk += dS^T @ (Q * scale)."""
+def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref,
+                dk_ref, dv_ref, dq_ref, *, causal: bool, scale: float):
+    """The backward (flash-attention-2): for each K/V tile, iterate query
+    tiles innermost and accumulate dv += P^T @ dO and dk += dS^T @ (Q *
+    scale) into the K tile's output blocks, and dq += dS @ K * scale into
+    rows [qi * tq, +tq) of dq_ref, one (batch, head)'s whole [Sq, D] f32:
+    that block's index moves with bh alone, so it stays in VMEM across the
+    head's (kj, qi) steps and is written back once. Scores and
+    probabilities never reach HBM, and a tile pair's are built once."""
     kj = pl.program_id(1)
     qi = pl.program_id(2)
+    tq, tk = q_ref.shape[1], k_ref.shape[1]
+
+    @pl.when((kj == 0) & (qi == 0))
+    def _init_head():
+        dq_ref[0] = jnp.zeros_like(dq_ref[0])
 
     @pl.when(qi == 0)
     def _init():
@@ -447,23 +448,26 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref,
         dv_ref[0] = jnp.zeros_like(dv_ref[0])
 
     def body(masked: bool, w: int):
-        q, _, g, p, ds = _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref,
+        q, k, g, p, ds = _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref,
                                     g_ref, m_ref, l_ref, d_ref, masked, w,
                                     scale)
+        prec = _dot_prec(q_ref.dtype)
         # rows of dk/dv behind the leading w belong to columns that are
         # masked for this whole q tile: nothing is added to them
         dv_ref[0, :w, :] += jax.lax.dot_general(
             p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=_dot_prec(q_ref.dtype))
+            preferred_element_type=jnp.float32, precision=prec)
+        ds = ds.astype(q.dtype)         # cast once for both products
         # q is unscaled in the shared tile recompute: apply the score scale
         # here (dK = dS^T @ (scale * Q))
         dk_ref[0, :w, :] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=_dot_prec(q_ref.dtype)) * scale
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec) * scale
+        dq_ref[0, pl.ds(pl.multiple_of(qi * tq, tq), tq), :] += (
+            jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec) * scale)
 
-    tq, tk = q_ref.shape[1], k_ref.shape[1]
     if causal:
         _when_causal(offs_ref, qi, kj, tq, tk, body)
     else:
@@ -479,7 +483,7 @@ def _lane8(x):  # [B, S, H] -> [B*H, S, 8] (TPU sublane x lane tiling)
 @functools.partial(jax.jit, static_argnames=("causal", "interpret"))
 def flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off, *,
                     causal: bool = True, interpret: bool = False):
-    """Gradients of q's attention against one K/V block (pallas kernels).
+    """Gradients of q's attention against one K/V block (one pallas kernel).
 
     Inputs: q [B, Sq, H, D]; k [B, Sk, H, D]; v [B, Sk, H, Dv];
     g = dOut [B, Sq, H, Dv];
@@ -489,7 +493,28 @@ def flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off, *,
     (context._ring_backward). Returns (dq_partial, dk, dv) in f32, shaped
     as q, k and v: the caller sums dq partials over blocks and ships dk/dv
     home with the ring.
+
+    The kernel holds a head's whole dq in VMEM. Where [Sq, D] is past what
+    ``_dq_rows`` allows (32k tokens at 128 lanes), q is walked in row
+    blocks that fit, each one call of the same kernel at its own ``q_off``,
+    and the blocks' dk/dv are summed: no second kernel for long shapes, and
+    no score built twice there either.
     """
+    Sq = q.shape[1]
+    rows = _dq_rows(Sq, q.shape[-1])
+
+    def block(r):
+        qb, gb, db, mb, lb = (x[:, r:r + rows] for x in (q, g, d_term, m, l))
+        return _bwd_call(qb, k, v, gb, db, mb, lb, q_off + r, k_off,
+                         causal, interpret)
+
+    dq, dk, dv = zip(*map(block, range(0, Sq, rows)))
+    return jnp.concatenate(dq, axis=1), sum(dk[1:], dk[0]), sum(dv[1:], dv[0])
+
+
+def _bwd_call(q, k, v, g, d_term, m, l, q_off, k_off, causal, interpret):
+    """One ``pallas_call`` of the backward kernel: (dq, dk, dv) of q's rows
+    against the K/V block, dq resident (``flash_block_bwd``)."""
     B, Sq, H, D = q.shape
     Sk, Dv = k.shape[1], v.shape[-1]
     scale = 1.0 / math.sqrt(D)
@@ -501,43 +526,13 @@ def flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off, *,
 
     offs = jnp.asarray([q_off, k_off], jnp.int32)
     kw = _vma(q, k, v, g)
-    operands = (offs, bhsd(q), bhsd(k), bhsd(v), bhsd(g),
-                _lane8(m), _lane8(l), _lane8(d_term))
+    # dq is summed over kj and dk/dv over qi: only bh is independent
     params = {} if interpret else {
         "compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            **_bwd_vmem(D, Dv))}
-
-    kv_map = _kv_index_map(causal, tq, tk)
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_bwd_vmem(Sq, D, Dv))}
     q_map = _q_index_map(causal, tq, tk, Sq // tq)
-
-    # pass 1: dq (K innermost, accumulates into the q tile's output)
-    dq_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B * H, Sq // tq, Sk // tk),
-        in_specs=[
-            pl.BlockSpec((1, tq, D), lambda bh, qi, kj, o: (bh, qi, 0)),
-            pl.BlockSpec((1, tk, D), kv_map),
-            pl.BlockSpec((1, tk, Dv), kv_map),
-            pl.BlockSpec((1, tq, Dv), lambda bh, qi, kj, o: (bh, qi, 0)),
-            pl.BlockSpec((1, tq, 8), lambda bh, qi, kj, o: (bh, qi, 0)),
-            pl.BlockSpec((1, tq, 8), lambda bh, qi, kj, o: (bh, qi, 0)),
-            pl.BlockSpec((1, tq, 8), lambda bh, qi, kj, o: (bh, qi, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tq, D), lambda bh, qi, kj, o: (bh, qi, 0)),
-        ],
-    )
-    with jax.named_scope(SCOPE_DQ):
-        (dq,) = pl.pallas_call(
-            functools.partial(_dq_kernel, causal=causal, scale=scale),
-            grid_spec=dq_spec,
-            out_shape=(jax.ShapeDtypeStruct((B * H, Sq, D), jnp.float32, **kw),),
-            interpret=interpret, **params,
-        )(*operands)
-
-    # pass 2: dk/dv (Q innermost, accumulates into the k tile's outputs)
-    dkv_spec = pltpu.PrefetchScalarGridSpec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B * H, Sk // tk, Sq // tq),
         in_specs=[
@@ -552,23 +547,28 @@ def flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off, *,
         out_specs=[
             pl.BlockSpec((1, tk, D), lambda bh, kj, qi, o: (bh, kj, 0)),
             pl.BlockSpec((1, tk, Dv), lambda bh, kj, qi, o: (bh, kj, 0)),
+            pl.BlockSpec((1, Sq, D), lambda bh, kj, qi, o: (bh, 0, 0)),
         ],
     )
+    # the operands are made outside the scope: it times the kernel alone
+    operands = (offs, bhsd(q), bhsd(k), bhsd(v), bhsd(g),
+                _lane8(m), _lane8(l), _lane8(d_term))
     with jax.named_scope(SCOPE_DKV):
-        dk, dv = pl.pallas_call(
-            functools.partial(_dkv_kernel, causal=causal, scale=scale),
-            grid_spec=dkv_spec,
+        dk, dv, dq = pl.pallas_call(
+            functools.partial(_bwd_kernel, causal=causal, scale=scale),
+            grid_spec=grid_spec,
             out_shape=(
                 jax.ShapeDtypeStruct((B * H, Sk, D), jnp.float32, **kw),
                 jax.ShapeDtypeStruct((B * H, Sk, Dv), jnp.float32, **kw),
+                jax.ShapeDtypeStruct((B * H, Sq, D), jnp.float32, **kw),
             ),
             interpret=interpret, **params,
         )(*operands)
 
-    def sbhd(x, s):
-        return x.reshape((B, H, s, x.shape[-1])).transpose(0, 2, 1, 3)
+    def sbhd(x):  # [B*H, S, C] -> [B, S, H, C]
+        return x.reshape((B, H) + x.shape[1:]).transpose(0, 2, 1, 3)
 
-    return sbhd(dq, Sq), sbhd(dk, Sk), sbhd(dv, Sk)
+    return sbhd(dq), sbhd(dk), sbhd(dv)
 
 
 def _blockwise_attention(q, k, v, causal: bool, tk: int):
@@ -576,7 +576,7 @@ def _blockwise_attention(q, k, v, causal: bool, tk: int):
     softmax, each step under jax.checkpoint. Numerically the same function
     as the pallas kernel, O(S*tk) live memory — kept as the independent
     test oracle for the kernel's values (tests/test_flash.py); the
-    production backward is the pallas kernel pair (flash_block_bwd)."""
+    production backward is the pallas kernel (flash_block_bwd)."""
     B, S, H, D = q.shape
     Sk = k.shape[1]
     nk = Sk // tk
@@ -630,9 +630,9 @@ def _flash_fwd(q, k, v, causal, interpret):
 
 
 def _flash_bwd(causal, interpret, res, g):
-    # flash-attention-2 style kernel backward: dq pass + dk/dv pass, both
-    # recomputing probability tiles in VMEM from the saved (m, l) stats —
-    # no autodiff-through-recompute, no [S, S] tensor in either direction
+    # flash-attention-2 style kernel backward: one kernel makes dq, dk and
+    # dv from probability tiles rebuilt in VMEM from the saved (m, l) stats
+    # — no autodiff-through-recompute, no [S, S] tensor in either direction
     q, k, v, out, m, l = res
     gf = g.astype(jnp.float32)
     d_term = jnp.sum(gf * out.astype(jnp.float32), axis=-1)
@@ -651,15 +651,17 @@ def flash_attention(q, k, v, *, causal: bool = True,
     latent attention has D = 192 and Dv = 128; the scale is 1/sqrt(D)).
 
     Differentiable: the forward runs the pallas VMEM kernel and the
-    backward runs the pallas flash-attention-2 kernel pair
-    (:func:`flash_block_bwd` — a dq pass and a dk/dv pass that rebuild
-    probability tiles in VMEM from the saved (m, l) stats), so neither
-    direction materializes the [S, S] score tensor — long-context training
-    works on a single chip at sequence lengths where dense attention is
-    OOM-bound.
+    backward one pallas flash-attention-2 kernel (:func:`flash_block_bwd`:
+    it rebuilds each probability tile once in VMEM from the saved (m, l)
+    stats and makes dq, dk and dv from it), so neither direction
+    materializes the [S, S] score tensor — long-context training works on
+    a single chip at sequence lengths where dense attention is OOM-bound.
     """
+    # trace-time gauges (docs/metrics.md): whether the backward of this
+    # shape is one call of the kernel, and the schedule the kernels run
+    metrics.gauge("flash.bwd_fused").set(
+        int(_dq_rows(q.shape[1], q.shape[-1]) == q.shape[1]))
     if causal:
-        # trace-time gauges of the schedule the kernels run (docs/metrics.md)
         sched = causal_schedule(q.shape[1], k.shape[1])
         metrics.gauge("flash.dead_steps_fetching").set(sched["dead_fetching"])
         metrics.gauge("flash.chunks_computed").set(sched["chunks_computed"])

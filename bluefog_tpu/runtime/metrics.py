@@ -537,6 +537,10 @@ _HELP_EXACT: Dict[str, str] = {
     "flash.chunks_needed": "score chunks of that call that hold an allowed "
                            "(row, column) pair: the causal floor of "
                            "flash.chunks_computed",
+    "flash.bwd_fused": "1 when the backward of the last flash_attention "
+                       "traced is one call of the kernel, a head's dq "
+                       "resident in VMEM; 0 when the shape rule "
+                       "(flash._dq_rows) walks q in row blocks",
     "trace.requests": "serve requests traced into the flight ring "
                       "(BLUEFOG_TRACE_SERVE; docs/slo.md)",
 }

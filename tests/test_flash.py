@@ -1,9 +1,9 @@
 """Pallas flash-attention kernel tests.
 
 This suite runs the kernels in interpret mode (pallas has no CPU lowering).
-The compiled kernels — forward, dq and dk/dv, inside the optimizer's
-shard_map where the operands carry vma — are checked against the reference
-on the chip by ``chip_smoke.py``'s ``lm_flash`` phase.
+The compiled kernels — the forward and the one backward, inside the
+optimizer's shard_map where the operands carry vma — are checked against the
+reference on the chip by ``chip_smoke.py``'s ``lm_flash`` phase.
 """
 
 import functools
@@ -216,6 +216,7 @@ def test_flash_attention_gradients_at_4to1_tiles(tiles_8x32):
     gauges = metrics.snapshot(include_native=False)["gauges"]
     assert (gauges["flash.dead_steps_fetching"], gauges["flash.chunks_computed"],
             gauges["flash.chunks_needed"]) == (0.0, 136.0, 136.0)
+    assert gauges["flash.bwd_fused"] == 1.0
 
 
 @pytest.mark.parametrize("q_off,k_off", [
@@ -265,3 +266,117 @@ def test_causal_schedule_computes_what_is_needed_at_ring_offsets(q_off, k_off):
     got = flash.causal_schedule(8192, 8192, q_off, k_off)
     assert got["chunks_computed"] == got["chunks_needed"] > 0
     assert got["dead_fetching"] == 0
+
+
+# -- the one backward kernel -------------------------------------------------
+# dq is an output of the dk/dv grid, a head's whole [Sq, D] resident across
+# its (kj, qi) steps; past ``flash._DQ_VMEM_BYTES`` q is walked in row blocks.
+
+def _wide(d, dv, seq=SEQ):
+    """q, k [1, seq, 2, d], v and the loss's weights [1, seq, 2, dv]."""
+    keys = jax.random.split(jax.random.PRNGKey(13), 4)
+    return tuple(jax.random.normal(kk, (1, seq, 2, w), jnp.float32)
+                 for kk, w in zip(keys, (d, d, dv, dv)))
+
+
+def _grads(attn, q, k, v, w):
+    return jax.grad(lambda q, k, v: jnp.sum(attn(q, k, v) * w),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("d,dv", [(8, 8), (192, 128)], ids=["8x8", "192x128"])
+def test_one_backward_kernel_matches_grad_of_blockwise(tiles_8x32, d, dv,
+                                                       causal):
+    """dq, dk and dv of the single backward against autodiff through the
+    XLA twin, on the 16 x 4 grid of an 8192-token sequence."""
+    q, k, v, w = _wide(d, dv)
+    got = _grads(functools.partial(flash_attention, causal=causal,
+                                   interpret=True), q, k, v, w)
+    want = _grads(functools.partial(flash._blockwise_attention, causal=causal,
+                                    tk=TK), q, k, v, w)
+    for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-5,
+                                   err_msg=name)
+
+
+def _block_attention(q, k, v, q_off, k_off):
+    """Dense causal attention of q's rows (at q_off) against this K/V block
+    alone (at k_off) -> (out, m, l, has): rows with no allowed column come
+    back 0 with the stats (0, 1) and ``has`` false."""
+    s = jnp.einsum("bqhd,bkhd->bqhk", q, k) / np.sqrt(q.shape[-1])
+    allowed = ((q_off + jnp.arange(q.shape[1]))[:, None, None]
+               >= (k_off + jnp.arange(k.shape[1]))[None, None, :])
+    has = allowed.any(axis=-1)                       # [Sq, 1]
+    m = jnp.where(has, jnp.max(jnp.where(allowed, s, -jnp.inf), axis=-1), 0.0)
+    p = jnp.where(allowed, jnp.exp(s - m[..., None]), 0.0)
+    l = jnp.where(has, jnp.sum(p, axis=-1), 1.0)
+    return jnp.einsum("bqhk,bkhd->bqhd", p, v) / l[..., None], m, l, has
+
+
+@pytest.mark.parametrize("q_off,k_off,dead_rows", [
+    (8192, 0, 0), (1000, 300, 0), (300, 1000, 700)])
+def test_one_backward_kernel_at_ring_offsets(q_off, k_off, dead_rows):
+    """The default tiles (2 x 2 of 512 x 2048) at a ring step's runtime
+    offsets: every tile interior; a diagonal K tile and a dead one; a dead q
+    tile, and a diagonal one whose first rows see no column (dq rows 0)."""
+    keys = jax.random.split(jax.random.PRNGKey(17), 4)
+    q, w = (jax.random.normal(kk, (1, 1024, 1, 8), jnp.float32)
+            for kk in keys[:2])
+    k, v = (jax.random.normal(kk, (1, 4096, 1, 8), jnp.float32)
+            for kk in keys[2:])
+    out, m, l, has = _block_attention(q, k, v, q_off, k_off)
+    assert int((~has).sum()) == dead_rows
+    want = _grads(lambda q, k, v: _block_attention(q, k, v, q_off, k_off)[0],
+                  q, k, v, w)
+    got = flash.flash_block_bwd(q, k, v, w, jnp.sum(w * out, axis=-1), m, l,
+                                q_off, k_off, causal=True, interpret=True)
+    for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
+                                   err_msg=name)
+    assert not np.asarray(got[0])[:, :dead_rows].any()
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("d,dv", [(8, 8), (192, 128)], ids=["8x8", "192x128"])
+def test_walked_backward_equals_the_resident_one(
+        tiles_8x32, monkeypatch, d, dv, causal):
+    """Both sides of the shape rule: the same sums, dk/dv of the row blocks
+    added in XLA. The gauge says which side was traced."""
+    q, k, v, w = _wide(d, dv)
+    attn = functools.partial(flash_attention, causal=causal, interpret=True)
+
+    def fused_gauge():
+        return metrics.snapshot(include_native=False)["gauges"][
+            "flash.bwd_fused"]
+
+    resident = _grads(attn, q, k, v, w)
+    assert fused_gauge() == 1.0
+    monkeypatch.setattr(flash, "_DQ_VMEM_BYTES", 2 * 4 * TQ * 128 * 4)
+    flash.flash_block_bwd.clear_cache()
+    # room for four q tiles of a width held in 128 lanes, two at 192
+    assert flash._dq_rows(SEQ, d) == 4 * TQ * 128 // flash._lanes(d)
+    walked = _grads(attn, q, k, v, w)
+    assert fused_gauge() == 0.0
+    for a, b, name in zip(walked, resident, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-6,
+                                   rtol=0, err_msg=name)
+
+
+MIB = 1 << 20
+
+
+@pytest.mark.parametrize("sq,d,dv,rows,vmem_mib", [
+    (8192, 128, 128, 8192, 24),      # pythia-s8192-*
+    (2048, 128, 128, 2048, 18),      # pythia-s2048-1chip
+    (8192, 192, 128, 8192, 40),      # joyai-*: 256 lanes of dq, 384 of stack
+    (32768, 128, 128, 32768, 48),    # the longest that stays one call
+    (65536, 128, 128, 32768, 48),
+    (32768, 192, 128, 16384, 56),
+    (512 * 96, 128, 128, 512 * 48, 40),   # 64 tiles fit; 48 divide 96
+    (24, 8, 8, 24, 16 + 24 * 1024 / MIB),  # one tile of 8: tq = 8, 3 tiles
+])
+def test_dq_rows_and_vmem_limit_follow_the_shapes(sq, d, dv, rows, vmem_mib):
+    assert flash._dq_rows(sq, d) == rows
+    assert sq % rows == 0 and rows % flash._q_tile(sq) == 0
+    assert flash._bwd_vmem(rows, d, dv) == vmem_mib * MIB

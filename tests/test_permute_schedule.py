@@ -2,7 +2,9 @@
 scheduled HLO text (``scaling.permute_start_slack``), the compile option the
 fused step derives from its plan and parameter tree, and -- where the TPU
 compiler can describe a v5e without one being attached -- the schedule it then
-gives a toy LM step. Nothing here runs on a chip, and no number is a time."""
+gives a toy LM step. The same described v5e compiles the flash backward kernel
+at the cells' shapes (this file holds every test that loads the TPU compiler, so
+that one xdist worker does). Nothing here runs on a chip, and no number is a time."""
 
 import contextlib
 import signal
@@ -18,6 +20,7 @@ from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec as P
 import bluefog_tpu as bf
 from bluefog_tpu import optimizers, scaling
 from bluefog_tpu.ops.plan import CombinePlan
+from bluefog_tpu.parallel import flash
 
 from conftest import cpu_devices
 
@@ -224,3 +227,26 @@ def test_a_toy_lm_step_compiled_for_a_v5e_has_every_permute_in_flight(v5e_mesh):
     assert found[False]["max_in_flight"] < permutes      # the compiler's own cap
     assert found[True]["max_in_flight"] >= permutes
     assert max(found[True]["slack"]) < max(found[False]["slack"])
+
+
+@pytest.mark.parametrize("b,s,h,d,dv,calls", [
+    (1, 8192, 16, 128, 128, 1),    # pythia-s8192-*: dq resident, 4 MiB a head
+    (4, 2048, 16, 128, 128, 1),    # pythia-s2048-1chip
+    (1, 8192, 32, 192, 128, 1),    # joyai-*: 256 lanes, 8 MiB a head
+    (1, 32768, 2, 192, 128, 2),    # past the dq budget: two row blocks of 16k
+], ids=["pythia-s8192", "pythia-s2048", "joyai-s8192", "walked-s32768"])
+def test_the_flash_backward_compiles_for_a_v5e_at_the_cells_shapes(v5e_mesh, b, s, h, d, dv,
+                                                                   calls):
+    """Mosaic takes the resident [Sq, D] dq block and the VMEM limit the shapes
+    give (``flash._bwd_vmem``): interpret mode cannot refuse either."""
+    one_chip = jax.sharding.SingleDeviceSharding(v5e_mesh.devices.flat[0])
+    shape = lambda *dims, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=one_chip)
+    qk, v = shape(b, s, h, d, dtype=jnp.bfloat16), shape(b, s, h, dv, dtype=jnp.bfloat16)
+    g, stat = shape(b, s, h, dv, dtype=jnp.float32), shape(b, s, h, dtype=jnp.float32)
+    assert flash._dq_rows(s, d) == s // calls
+    assert flash._bwd_vmem(s // calls, d, dv) <= 64 << 20      # half a v5e core's VMEM
+    with time_limit(300):
+        text = jax.jit(lambda *a: flash.flash_block_bwd(*a, 0, 0, causal=True)).lower(
+            qk, qk, v, g, stat, stat, stat).compile().as_text()
+    assert text.count("tpu_custom_call") == calls

@@ -166,19 +166,29 @@ def test_window_optimizers_local_step_is_registered(bf4):
     assert scopes_of(program.hlo_text()) == set(PHASES[:2])
 
 
-def _pallas_calls(jaxpr, above=""):
-    """The name stack of every ``pallas_call`` of a jaxpr, nested ones included."""
+def _eqns(jaxpr, above=""):
+    """(primitive name, name stack) of every equation of a jaxpr, nested ones included
+    (but a kernel's body)."""
     for eqn in jaxpr.eqns:
         stack = above + "/" + str(eqn.source_info.name_stack)
-        if eqn.primitive.name == "pallas_call":
-            yield stack
-        for sub in jax_core.jaxprs_in_params(eqn.params):
-            yield from _pallas_calls(sub, stack)
+        yield eqn.primitive.name, stack
+        if eqn.primitive.name != "pallas_call":     # a kernel's body is the kernel's
+            for sub in jax_core.jaxprs_in_params(eqn.params):
+                yield from _eqns(sub, stack)
 
 
-@pytest.fixture(scope="module")
-def flash_grad_calls():
-    q = jnp.ones((1, 16, 2, 8), jnp.float32)
+def _pallas_calls(jaxpr):
+    """The name stack of every ``pallas_call`` of a jaxpr; under a flash scope
+    nothing else may stand (the scope is what times the kernel)."""
+    eqns = list(_eqns(jaxpr))
+    strays = [(name, stack) for name, stack in eqns if name != "pallas_call"
+              and {flash.SCOPE_FWD, flash.SCOPE_DKV} & set(stack.split("/"))]
+    assert not strays, strays
+    return [stack for name, stack in eqns if name == "pallas_call"]
+
+
+def _flash_grad_calls(seq):
+    q = jnp.ones((1, seq, 2, 8), jnp.float32)
 
     def loss(q, k, v):
         with jax.named_scope(optimizers.SCOPE_GRAD):
@@ -189,14 +199,35 @@ def flash_grad_calls():
     return list(_pallas_calls(jaxpr.jaxpr)), lowered.as_text(debug_info=True)
 
 
-@pytest.mark.parametrize("scope", [flash.SCOPE_FWD, flash.SCOPE_DQ, flash.SCOPE_DKV])
-def test_each_flash_kernel_is_one_pallas_call_under_its_scope(flash_grad_calls, scope):
-    calls, text = flash_grad_calls
-    assert len(calls) == 3
+@pytest.fixture
+def dq_budget_of_one_q_tile(monkeypatch):
+    """The backward's shape rule holds dq for one 512-row q tile a call (of a
+    width held in 128 lanes); what was traced under another budget is dropped."""
+    monkeypatch.setattr(flash, "_DQ_VMEM_BYTES", 2 * 512 * 128 * 4)
+    flash.flash_block_bwd.clear_cache()
+    yield
+    flash.flash_block_bwd.clear_cache()
+
+
+@pytest.mark.parametrize("scope", [flash.SCOPE_FWD, flash.SCOPE_DKV])
+def test_each_flash_kernel_is_one_pallas_call_under_its_scope(scope):
+    calls, text = _flash_grad_calls(16)
+    assert len(calls) == 2
     (stack,) = [s for s in calls if scope in s.split("/")]
     # the scope is around the kernel alone: the call is its direct child
     assert stack.rstrip("/").endswith(scope)
     assert scope in text
+
+
+def test_a_walked_flash_backward_is_one_call_a_row_block(dq_budget_of_one_q_tile):
+    """Past the dq budget the same kernel runs once a row block of q, every call
+    the direct child of the backward's scope: 1024 rows at 512 a call are two."""
+    calls, _ = _flash_grad_calls(1024)
+    assert len(calls) == 3
+    assert sum(flash.SCOPE_FWD in s.split("/") for s in calls) == 1
+    walked = [s for s in calls if flash.SCOPE_DKV in s.split("/")]
+    assert len(walked) == 2
+    assert all(s.rstrip("/").endswith(flash.SCOPE_DKV) for s in walked)
 
 
 def test_step_span_holds_plan_and_build(bf4, tmp_path):
