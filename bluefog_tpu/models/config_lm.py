@@ -1,22 +1,37 @@
 """A decoder-only LM whose block is read from a configuration.
 
 Where :class:`~bluefog_tpu.models.TransformerLM` fixes its block in code, this
-one takes an :class:`LMConfig` -- the keys of a DeepSeek-V3 style
-``config.json`` -- and builds, layer by layer:
+one takes an :class:`LMConfig` -- the keys of a published ``config.json``, of
+the DeepSeek-V3 kind (latent attention, a biased sigmoid router, a shared
+expert, MTP) or of the grouped-query kind (SmallThinker: k/v heads shared by
+a group of query heads, layers that differ in mask and rotary, a router that
+reads the block's input) -- and builds, layer by layer:
 
   attention  ``"latent"``: multi-head latent attention (two low-rank paths with
              an RMSNorm on each latent, a no-rope part per head and one rope
              part shared by all heads, a q.k width of ``qk_nope + qk_rope`` and
-             a narrower v), or ``"equal"``: equal-width heads from one fused
-             projection. Both rotate pairs ``(2i, 2i+1)`` (``rope_interleave``)
-             or halves.
+             a narrower v); ``"equal"``: equal-width heads from one fused
+             projection; ``"grouped"``: separate q, k and v projections,
+             ``num_attention_heads`` query heads of ``head_dim`` over
+             ``num_key_value_heads`` k/v heads (query head h reads k/v head
+             h // group; the attention function is handed k and v at their
+             own head count). Rope turns pairs ``(2i, 2i+1)``
+             (``rope_interleave``) or halves. By layer: ``rope_layout[i]``
+             0 leaves layer i without rotary (NoPE), and
+             ``sliding_window_layout[i]`` 1 gives it a causal window of
+             ``sliding_window`` tokens (the attention function's ``window``)
+             where the others see the whole past.
   FFN        a SwiGLU of ``intermediate_size`` in the ``first_k_dense_replace``
              leading layers and wherever there are no experts; otherwise
              :class:`~bluefog_tpu.parallel.expert.RoutedExperts`: top-k of
-             ``n_routed_experts`` by sigmoid score plus a choice-only bias
-             (kept in the ``"routing"`` collection and moved by the
-             auxiliary-loss-free balancing rule, not by the optimizer),
-             the experts ``experts_held`` computed here, a shared expert.
+             ``n_routed_experts`` by sigmoid or softmax score, plus (unless
+             ``routing_bias`` is off) a choice-only bias kept in the
+             ``"routing"`` collection and moved by the auxiliary-loss-free
+             balancing rule, not by the optimizer; the experts
+             ``experts_held`` computed here, gated by ``expert_act`` (SiLU or
+             ReLU); ``n_shared_experts`` shared experts or none. The router
+             reads the FFN's normed input (``router_input="ffn"``) or the
+             attention's (``"block"``: its scores are made before attention).
   MTP        ``num_nextn_predict_layers`` multi-token-prediction modules after
              the last layer, sharing the embedding and the head.
 
@@ -25,10 +40,11 @@ residuals are sequential. Parameters are float32; ``dtype`` is the compute
 type; router scores and the top-k are float32 whatever it is.
 
 The parts run under ``jax.named_scope``s a trace reducer can find them by:
-``bf.mla.proj`` (projections, latent norms and rope), the three ``bf.flash.*``
-of the attention function, ``bf.moe.route`` / ``bf.moe.experts`` /
-``bf.moe.shared``, ``bf.ffn.dense``, ``bf.lm.head`` and ``bf.mtp`` around a
-whole MTP module.
+``bf.mla.proj`` (latent and equal-width attention outside its kernels:
+projections, latent norms and rope) or ``bf.attn.proj`` (the same of the
+grouped kind), the two ``bf.flash.*`` of the attention function,
+``bf.moe.route`` / ``bf.moe.experts`` / ``bf.moe.shared``, ``bf.ffn.dense``,
+``bf.lm.head`` and ``bf.mtp`` around a whole MTP module.
 """
 
 from __future__ import annotations
@@ -43,9 +59,10 @@ import optax
 from flax import linen as nn
 
 from ..parallel.context import reference_attention
-from ..parallel.expert import ROUTING, RoutedExperts, SwiGLU
+from ..parallel.expert import ROUTING, SCOPE_ROUTE, RoutedExperts, SwiGLU
 
 SCOPE_MLA_PROJ = "bf.mla.proj"
+SCOPE_ATTN_PROJ = "bf.attn.proj"   # the grouped kind's, under a name of its own
 SCOPE_DENSE_FFN = "bf.ffn.dense"
 SCOPE_HEAD = "bf.lm.head"
 SCOPE_MTP = "bf.mtp"
@@ -53,14 +70,20 @@ SCOPE_MTP = "bf.mtp"
 
 @dataclasses.dataclass(frozen=True)
 class LMConfig:
-    """The block, under the names a DeepSeek-V3 style ``config.json`` gives it."""
+    """The block, under the names a DeepSeek-V3 style ``config.json`` gives it
+    (and, for what that style lacks, a grouped-query one's)."""
 
     vocab_size: int
     hidden_size: int
     num_hidden_layers: int
     num_attention_heads: int
     intermediate_size: int
-    attention: str = "latent"            # "latent" | "equal"
+    attention: str = "latent"            # "latent" | "equal" | "grouped"
+    num_key_value_heads: int = 0         # "grouped": k/v heads (0: as many as q heads)
+    head_dim: int = 0                    # "grouped": a head's width
+    sliding_window: int = 0              # tokens a window layer sees
+    sliding_window_layout: Optional[Tuple[int, ...]] = None  # by layer, 1: window; None: none
+    rope_layout: Optional[Tuple[int, ...]] = None            # by layer, 0: no rotary; None: all
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -77,6 +100,9 @@ class LMConfig:
     scoring_func: str = "sigmoid"
     routed_scaling_factor: float = 1.0
     experts_held: Optional[Tuple[int, int]] = None  # ids computed here; None: all
+    expert_act: str = "silu"             # the experts' gate: "silu" | "relu"
+    routing_bias: bool = True            # False: no bias, the top-k is of the scores
+    router_input: str = "ffn"            # "ffn" | "block": the attention's normed input
     bias_update_speed: float = 0.0       # the balancing rule's step; 0: the bias stays
     num_nextn_predict_layers: int = 0
 
@@ -84,7 +110,8 @@ class LMConfig:
     def from_dict(cls, doc: dict, **overrides) -> "LMConfig":
         """From the keys of a ``config.json`` (others are ignored)."""
         names = {f.name for f in dataclasses.fields(cls)}
-        return cls(**{**{k: v for k, v in doc.items() if k in names}, **overrides})
+        picked = {**{k: v for k, v in doc.items() if k in names}, **overrides}
+        return cls(**{k: tuple(v) if isinstance(v, list) else v for k, v in picked.items()})
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -92,6 +119,19 @@ class LMConfig:
 
     def is_expert_layer(self, layer: int) -> bool:
         return self.n_routed_experts > 0 and layer >= self.first_k_dense_replace
+
+    def window_of(self, layer: int) -> Optional[int]:
+        """The sliding window of a layer of the trunk, None where it sees the whole past."""
+        layout = self.sliding_window_layout
+        return self.sliding_window if layout and layout[layer] else None
+
+    def rotary_in(self, layer: int) -> bool:
+        return self.rope_layout is None or bool(self.rope_layout[layer])
+
+    @property
+    def proj_scope(self) -> str:
+        """The scope of attention outside its kernels: the grouped kind's is its own."""
+        return SCOPE_ATTN_PROJ if self.attention == "grouped" else SCOPE_MLA_PROJ
 
 
 def rope(x, positions, theta: float, interleave: bool):
@@ -120,6 +160,7 @@ class Attention(nn.Module):
     cfg: LMConfig
     dtype: Any
     attn_fn: Callable
+    rotary: bool = True
 
     @nn.compact
     def __call__(self, h, positions):
@@ -129,10 +170,16 @@ class Attention(nn.Module):
         norm = partial(nn.RMSNorm, epsilon=cfg.rms_norm_eps, dtype=self.dtype,
                        param_dtype=jnp.float32)
         turn = partial(rope, positions=positions, theta=cfg.rope_theta,
-                       interleave=cfg.rope_interleave)
+                       interleave=cfg.rope_interleave) if self.rotary else (lambda x: x)
         lead = h.shape[:2]
-        with jax.named_scope(SCOPE_MLA_PROJ):
-            if cfg.attention == "equal":
+        with jax.named_scope(cfg.proj_scope):
+            if cfg.attention == "grouped":
+                kv_heads = cfg.num_key_value_heads or heads
+                q = dense(heads * cfg.head_dim, name="q")(h).reshape(lead + (heads, -1))
+                k = dense(kv_heads * cfg.head_dim, name="k")(h).reshape(lead + (kv_heads, -1))
+                v = dense(kv_heads * cfg.head_dim, name="v")(h).reshape(lead + (kv_heads, -1))
+                q, k = turn(q), turn(k)
+            elif cfg.attention == "equal":
                 q, k, v = jnp.split(dense(3 * d, name="qkv")(h), 3, axis=-1)
                 q, k, v = (t.reshape(lead + (heads, d // heads)) for t in (q, k, v))
                 q, k = turn(q), turn(k)
@@ -151,34 +198,45 @@ class Attention(nn.Module):
                     [kv[..., :nope], jnp.broadcast_to(k_rot, lead + (heads, rot))], axis=-1)
                 v = kv[..., nope:]
         a = self.attn_fn(q, k, v)
-        with jax.named_scope(SCOPE_MLA_PROJ):
+        with jax.named_scope(cfg.proj_scope):
             return dense(d, name="o")(a.reshape(lead + (-1,)))
 
 
 class Layer(nn.Module):
-    """One pre-norm block: attention, then a dense SwiGLU or the expert layer."""
+    """One pre-norm block: attention, then a dense SwiGLU or the expert layer.
+    ``attn_fn`` is the layer's own (its window bound, if it has one)."""
 
     cfg: LMConfig
     experts: bool
     dtype: Any
     attn_fn: Callable
     interpret: bool = False
+    rotary: bool = True
 
     @nn.compact
     def __call__(self, x, positions, choice=None):
         cfg = self.cfg
         norm = partial(nn.RMSNorm, epsilon=cfg.rms_norm_eps, dtype=self.dtype,
                        param_dtype=jnp.float32)
-        with jax.named_scope(SCOPE_MLA_PROJ):
+        with jax.named_scope(cfg.proj_scope):
             h = norm(name="attn_norm")(x)
-        x = x + Attention(cfg, self.dtype, self.attn_fn, name="attn")(h, positions)
+        router_logits = None
+        if self.experts and cfg.router_input == "block":
+            router = self.param("router", nn.initializers.lecun_normal(),
+                                (x.shape[-1], cfg.n_routed_experts), jnp.float32)
+            with jax.named_scope(SCOPE_ROUTE):
+                router_logits = jnp.dot(h.astype(jnp.float32), router,
+                                        precision=jax.lax.Precision.HIGHEST)
+        x = x + Attention(cfg, self.dtype, self.attn_fn, self.rotary, name="attn")(h, positions)
         if self.experts:
             h = norm(name="ffn_norm")(x)
             return x + RoutedExperts(
                 num_experts=cfg.n_routed_experts, experts_per_token=cfg.num_experts_per_tok,
                 d_ff=cfg.moe_intermediate_size, held=cfg.held, n_shared=cfg.n_shared_experts,
                 scoring=cfg.scoring_func, scaling=cfg.routed_scaling_factor,
-                bias_update_speed=cfg.bias_update_speed, dtype=self.dtype, interpret=self.interpret, name="ffn")(h, choice)
+                bias_update_speed=cfg.bias_update_speed, dtype=self.dtype, interpret=self.interpret,
+                activation=cfg.expert_act, routing_bias=cfg.routing_bias, name="ffn")(
+                    h, choice, router_logits)
         with jax.named_scope(SCOPE_DENSE_FFN):
             h = norm(name="ffn_norm")(x)
             return x + SwiGLU(cfg.intermediate_size, self.dtype, name="ffn")(h)
@@ -195,7 +253,9 @@ class ConfigLM(nn.Module):
     the sequence rolled by one, whose last position wraps).
 
     ``attn_fn(q, k, v) -> out`` defaults to dense causal attention;
-    ``partial(flash_attention, causal=True)`` is the kernel path. ``choices``
+    ``partial(flash_attention, causal=True)`` is the kernel path. A layer with
+    a sliding window calls it with ``window=<size>`` bound, and the grouped
+    kind hands it k and v at ``num_key_value_heads``. ``choices``
     (one ``[B, S, k]`` array of expert ids per expert layer, forward order)
     forces the experts each token takes. Every expert layer sows its counters
     and its choice (``mutable=["intermediates"]``; :func:`moe_counters`) and
@@ -218,7 +278,10 @@ class ConfigLM(nn.Module):
         self.embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=self.dtype,
                               param_dtype=jnp.float32)
         for i in range(cfg.num_hidden_layers):
-            setattr(self, f"layer_{i}", layer(experts=cfg.is_expert_layer(i)))
+            own = {} if cfg.window_of(i) is None else {
+                "attn_fn": partial(attn, window=cfg.window_of(i))}
+            setattr(self, f"layer_{i}", layer(experts=cfg.is_expert_layer(i),
+                                              rotary=cfg.rotary_in(i), **own))
         self.final_norm = norm()
         self.lm_head = nn.Dense(cfg.vocab_size, dtype=self.dtype, param_dtype=jnp.float32,
                                 use_bias=False)

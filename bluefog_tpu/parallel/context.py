@@ -32,14 +32,22 @@ def _pvary(x, axes):
     return lax.pcast(x, axes, to="varying")
 
 
-def reference_attention(q, k, v, causal: bool = False):
-    """Dense single-device attention; the correctness oracle for the tests."""
+def reference_attention(q, k, v, causal: bool = False, window=None):
+    """Dense single-device attention; the correctness oracle for the tests.
+    Fewer k/v heads than q heads are repeated (query head h reads k/v head
+    h // (Hq / Hkv)); ``window`` (with ``causal``) keeps the columns s of
+    row t with 0 <= t - s < window."""
     scale = 1.0 / math.sqrt(q.shape[-1])
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if causal:
         Sq, Sk = s.shape[-2], s.shape[-1]
-        mask = jnp.arange(Sq)[:, None] >= jnp.arange(Sk)[None, :]
+        behind = jnp.arange(Sq)[:, None] - jnp.arange(Sk)[None, :]
+        mask = behind >= 0 if window is None else (
+            (behind >= 0) & (behind < window))
         s = jnp.where(mask, s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))

@@ -541,12 +541,14 @@ def buffer_rows(bound: int, held: int) -> int:
 def route_top_k(scores, bias, experts_per_token: int, scaling: float,
                 choice=None):
     """``(ids [T, k] int32, weights [T, k] f32)``: the ``k`` experts with the
-    largest ``scores + bias`` of each token, and ``scaling * s / (sum of the
-    chosen s + 1e-20)`` from the scores themselves. The bias enters the choice
-    only, and the ids carry no gradient. A given ``choice`` of ids replaces the
-    top-k and leaves the weights to the scores."""
+    largest ``scores + bias`` of each token (``bias`` None: the largest
+    scores), and ``scaling * s / (sum of the chosen s + 1e-20)`` from the
+    scores themselves. The bias enters the choice only, and the ids carry no
+    gradient. A given ``choice`` of ids replaces the top-k and leaves the
+    weights to the scores."""
     if choice is None:
-        _, choice = lax.top_k(scores + bias, experts_per_token)
+        _, choice = lax.top_k(scores if bias is None else scores + bias,
+                              experts_per_token)
     chosen = jnp.take_along_axis(scores, choice, axis=-1)
     weights = scaling * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
     return choice.astype(jnp.int32), weights
@@ -611,7 +613,7 @@ def dispatch_held(ids, held: Tuple[int, int], bound: int):
 
 
 class RoutedExperts(nn.Module):
-    """The chip's share of a top-k expert layer with a shared expert.
+    """The chip's share of a top-k expert layer, with or without a shared expert.
 
     ``num_experts`` experts are scored (``scoring``: ``"sigmoid"`` or
     ``"softmax"``, in float32) and ``experts_per_token`` chosen by score plus
@@ -620,7 +622,9 @@ class RoutedExperts(nn.Module):
     of w_e * E_e(x)`` with ``w_e`` normalised over all the chosen ones and
     multiplied by ``scaling``: what the experts held elsewhere would add is
     left out (with ``held = (0, num_experts)`` nothing is). Every expert is a
-    SwiGLU of width ``d_ff``. Rows routed here are gathered in expert order
+    gated unit of width ``d_ff``, ``down(act(gate(x)) * up(x))`` with
+    ``activation`` ``"silu"`` (SwiGLU) or ``"relu"`` (ReGLU); ``n_shared = 0``
+    builds no shared expert. Rows routed here are gathered in expert order
     into a buffer of a static number of rows (:func:`routed_rows_bound`,
     :func:`buffer_rows`); per-expert counts are ragged inside it, so imbalance
     between experts costs nothing, and the grouped products do work only for
@@ -633,11 +637,16 @@ class RoutedExperts(nn.Module):
     balancing update (``noaux_tc``): ``bias += bias_update_speed *
     sign(mean load - load)`` from the tokens' choices of this pass over all
     ``num_experts`` (:func:`balance_bias`); the pass itself chose with the
-    bias as it came in.
+    bias as it came in. ``routing_bias=False`` is a router without one: no
+    such variable is made, the top-k is of the scores, nothing balances.
 
-    ``choice`` (``[..., k]`` ids) forces the chosen experts, for a comparison
-    with a reference that must not depend on which side of a near tie the
-    8th score fell. The counters of :func:`dispatch_held` are sowed under
+    ``router_logits`` (``[..., num_experts]`` float32) are the router's
+    outputs where the caller computes them -- a block whose router reads the
+    attention's input scores before attention and hands them over; the layer
+    then has no ``router`` parameter of its own. ``choice`` (``[..., k]``
+    ids) forces the chosen experts, for a comparison with a reference that
+    must not depend on which side of a near tie the last score fell. The
+    counters of :func:`dispatch_held` are sowed under
     ``intermediates/moe_counters``, the chosen ids under
     ``intermediates/moe_choice``.
     """
@@ -652,20 +661,25 @@ class RoutedExperts(nn.Module):
     bias_update_speed: float = 0.0
     dtype: Any = jnp.float32
     interpret: bool = False
+    activation: str = "silu"
+    routing_bias: bool = True
 
     @nn.compact
-    def __call__(self, x, choice=None):
+    def __call__(self, x, choice=None, router_logits=None):
         d = x.shape[-1]
         leading = x.shape[:-1]
         t = int(np.prod(leading))
         n_held = self.held[1] - self.held[0]
         k = self.experts_per_token
+        act = {"silu": nn.silu, "relu": nn.relu}[self.activation]
         init = nn.initializers.lecun_normal()
-        router = self.param("router", init, (d, self.num_experts), jnp.float32)
+        if router_logits is None:
+            router = self.param("router", init, (d, self.num_experts), jnp.float32)
         # small and seeded, not zero: a forgotten bias must change the choice
         bias = self.variable(
             ROUTING, "bias", lambda: nn.initializers.normal(0.02)(
-                self.make_rng("params"), (self.num_experts,), jnp.float32))
+                self.make_rng("params"), (self.num_experts,), jnp.float32)
+        ) if self.routing_bias else None
         expert_init = nn.initializers.variance_scaling(
             1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=(0,))
         gate = self.param("gate", expert_init, (n_held, d, self.d_ff), jnp.float32)
@@ -674,14 +688,18 @@ class RoutedExperts(nn.Module):
 
         xt = x.reshape(t, d).astype(self.dtype)
         with jax.named_scope(SCOPE_ROUTE):
-            logits = jnp.dot(xt.astype(jnp.float32), router,
-                             precision=lax.Precision.HIGHEST)
+            if router_logits is None:
+                logits = jnp.dot(xt.astype(jnp.float32), router,
+                                 precision=lax.Precision.HIGHEST)
+            else:
+                logits = router_logits.reshape(t, self.num_experts)
             scores = (jax.nn.sigmoid(logits) if self.scoring == "sigmoid"
                       else jax.nn.softmax(logits, axis=-1))
             ids, weights = route_top_k(
-                scores, bias.value, k, self.scaling,
+                scores, bias.value if self.routing_bias else None, k, self.scaling,
                 None if choice is None else choice.reshape(t, k))
-            if self.is_mutable_collection(ROUTING) and not self.is_initializing():
+            if (self.routing_bias and self.is_mutable_collection(ROUTING)
+                    and not self.is_initializing()):
                 bias.value = balance_bias(bias.value, ids, self.bias_update_speed)
             bound = routed_rows_bound(t, k, n_held, self.num_experts)
             slot, valid, tile_expert, tiles_used, counters = dispatch_held(
@@ -692,15 +710,17 @@ class RoutedExperts(nn.Module):
         with jax.named_scope(SCOPE_EXPERTS):
             mm = lambda rows, w: grouped_matmul(  # noqa: E731
                 rows, w.astype(self.dtype), tile_expert, tiles_used, self.interpret)
-            y = mm(nn.silu(mm(gathered, gate)) * mm(gathered, up), down)  # [rows, d]
+            y = mm(act(mm(gathered, gate)) * mm(gathered, up), down)  # [rows, d]
         with jax.named_scope(SCOPE_ROUTE):
             routed = jnp.zeros((t, d), jnp.float32).at[token].add(
                 y.astype(jnp.float32) * row_weight[:, None])
-        with jax.named_scope(SCOPE_SHARED):
-            shared = SwiGLU(self.n_shared * self.d_ff, self.dtype, name="shared")(xt)
+        if self.n_shared:
+            with jax.named_scope(SCOPE_SHARED):
+                shared = SwiGLU(self.n_shared * self.d_ff, self.dtype, name="shared")(xt)
         self.sow("intermediates", "moe_counters", counters)
         self.sow("intermediates", "moe_choice", ids.reshape(leading + (k,)))
-        return (routed.astype(self.dtype) + shared).reshape(leading + (d,)).astype(x.dtype)
+        out = routed.astype(self.dtype)
+        return (out + shared if self.n_shared else out).reshape(leading + (d,)).astype(x.dtype)
 
 
 class SwiGLU(nn.Module):

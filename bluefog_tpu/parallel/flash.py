@@ -126,15 +126,24 @@ def _dot_prec(dtype):
 # ``offs`` is (q_off, k_off), the global positions of element 0 of either
 # side: the scalar-prefetch ref in a kernel or an index map, a pair of ints
 # in ``causal_schedule``, which passes ``xp=np`` and whole grids of indices.
+# ``window`` (None: none) is the sliding window's size W: row t sees the
+# columns s with 0 <= t - s < W, so the allowed band has a second, trailing
+# edge W columns behind the diagonal and everything below takes it.
 
-def _causal_tile(offs, qi, kj, tq, tk):
+def _causal_tile(offs, qi, kj, tq, tk, window=None):
     """(live, interior) of the tile pair (qi, kj). Live: some column of the
-    K tile is at or before the q tile's last row; a dead step computes
-    nothing. Interior: the whole K tile is at or before its first row, so
-    the mask is a no-op. Live and not interior is a tile on the diagonal."""
+    K tile is at or before the q tile's last row (and inside the window of
+    its first); a dead step computes nothing. Interior: the whole K tile is
+    at or before its first row (and inside the window of its last), so the
+    mask is a no-op. Live and not interior is a tile on an edge: the
+    diagonal, the window's trailing edge, or both."""
     q_lo = offs[0] + qi * tq
     k_lo = offs[1] + kj * tk
-    return k_lo <= q_lo + tq - 1, k_lo + tk - 1 <= q_lo
+    live, interior = k_lo <= q_lo + tq - 1, k_lo + tk - 1 <= q_lo
+    if window is not None:
+        live = live & (k_lo + tk - 1 > q_lo - window)
+        interior = interior & (k_lo > q_lo + tq - 1 - window)
+    return live, interior
 
 
 def _chunk(tq: int, tk: int) -> int:
@@ -153,24 +162,57 @@ def _live_chunks(offs, qi, kj, tq, tk, xp=jnp):
     return xp.minimum((q_hi - offs[1] - kj * tk) // cw + 1, tk // cw)
 
 
-def _kv_block(offs, qi, kj, tq, tk, xp=jnp):
+def _first_chunk(offs, qi, kj, tq, tk, window, xp=jnp):
+    """Column chunks at the head of a LIVE K tile that lie behind the window
+    of every row of q tile qi (0 .. tk / chunk - 1): masked for every row,
+    as the chunks past ``_live_chunks`` are, and left out like them."""
+    cw = _chunk(tq, tk)
+    oldest = offs[0] + qi * tq - window + 1     # column the first row sees
+    return xp.maximum((oldest - offs[1] - kj * tk) // cw, 0)
+
+
+def _kv_block(offs, qi, kj, tq, tk, xp=jnp, window=None, nq=None, nk=None):
     """K/V block of a forward grid step (kj innermost, so a row's dead
     steps are its last): block 0 on a dead step. Pallas copies a block only
     when its index differs from the previous step's: block 0 arrives behind
     the last live step's compute, stays through the dead steps and is what
-    the next row starts with, so no copy is issued that no step reads."""
-    live, _ = _causal_tile(offs, qi, kj, tq, tk)
-    return xp.where(live, kj, 0)
+    the next row starts with, so no copy is issued that no step reads.
+
+    Under a window a row's live steps are a run in its middle: a dead step
+    before them names the row's first live block, one after them the block
+    the next row starts with (the last row stays where it is)."""
+    live, _ = _causal_tile(offs, qi, kj, tq, tk, window)
+    if window is None:
+        return xp.where(live, kj, 0)
+
+    def first(row):  # the K tile that holds the oldest column the row sees
+        return xp.clip((offs[0] + row * tq - window + 1 - offs[1]) // tk,
+                       0, nk - 1)
+
+    last = xp.clip((offs[0] + qi * tq + tq - 1 - offs[1]) // tk, 0, nk - 1)
+    after = xp.where(qi == nq - 1, last, first(qi + 1))
+    return xp.where(live, kj, xp.where(kj < first(qi), first(qi), after))
 
 
-def _q_block(offs, qi, kj, tq, tk, nq, xp=jnp):
+def _q_block(offs, qi, kj, tq, tk, nq, xp=jnp, window=None, nk=None):
     """q-side block (q, g, m, l, d) of a backward grid step (qi innermost, so
     a row's dead steps are its first): the row's first live q tile on a dead
     step, the last q tile where the whole row is dead (what the row before
-    ended on)."""
-    live, _ = _causal_tile(offs, qi, kj, tq, tk)
-    first = xp.maximum(offs[1] + kj * tk - offs[0], 0) // tq
-    return xp.where(live, qi, xp.minimum(first, nq - 1))
+    ended on). Under a window a K tile's row also ends in dead steps, the
+    q tiles that no longer see it: they name what the next row starts with
+    (the last row stays on its last live q tile)."""
+    live, _ = _causal_tile(offs, qi, kj, tq, tk, window)
+
+    def first(row):
+        return xp.minimum(xp.maximum(offs[1] + row * tk - offs[0], 0) // tq,
+                          nq - 1)
+
+    if window is None:
+        return xp.where(live, qi, first(kj))
+    last = xp.clip((offs[1] + kj * tk + tk - 1 + window - 1 - offs[0]) // tq,
+                   0, nq - 1)
+    after = xp.where(kj == nk - 1, last, first(kj + 1))
+    return xp.where(live, qi, xp.where(qi < first(kj), first(kj), after))
 
 
 def _idle_fetches(block, live) -> int:
@@ -180,10 +222,12 @@ def _idle_fetches(block, live) -> int:
     return int((np.add.reduceat(live.astype(np.int64), starts) == 0).sum())
 
 
-def causal_schedule(sq: int, sk: int, q_off: int = 0, k_off: int = 0) -> dict:
+def causal_schedule(sq: int, sk: int, q_off: int = 0, k_off: int = 0,
+                    window=None) -> dict:
     """What the causal kernels do for one (batch, head) of q [sq]
-    against a K/V block [sk] at these offsets, counted from the helpers the
-    kernels and their index maps are made of:
+    against a K/V block [sk] at these offsets (under a sliding ``window``,
+    if one is given), counted from the helpers the kernels and their index
+    maps are made of:
 
     ``steps`` / ``live`` / ``dead``: grid steps of one kernel and their
     causal classes; ``dead_fetching``: copies issued for dead steps only
@@ -195,13 +239,17 @@ def causal_schedule(sq: int, sk: int, q_off: int = 0, k_off: int = 0) -> dict:
     nq, nk, cw = sq // tq, sk // tk, _chunk(tq, tk)
     offs = (q_off, k_off)
     qi, kj = np.meshgrid(np.arange(nq), np.arange(nk), indexing="ij")
-    live, interior = _causal_tile(offs, qi, kj, tq, tk)
-    computed = np.where(interior, tk // cw, np.where(
-        live, _live_chunks(offs, qi, kj, tq, tk, xp=np), 0))
-    q_hi = q_off + np.arange(nq) * tq + tq - 1
-    needed = k_off + np.arange(sk // cw) * cw <= q_hi[:, None]
-    kv = _kv_block(offs, qi, kj, tq, tk, xp=np)
-    qb = _q_block(offs, qi, kj, tq, tk, nq, xp=np)
+    live, interior = _causal_tile(offs, qi, kj, tq, tk, window)
+    chunks = _live_chunks(offs, qi, kj, tq, tk, xp=np)
+    q_lo = q_off + np.arange(nq) * tq
+    column = k_off + np.arange(sk // cw) * cw
+    needed = column <= q_lo[:, None] + tq - 1
+    if window is not None:
+        chunks = chunks - _first_chunk(offs, qi, kj, tq, tk, window, xp=np)
+        needed &= column + cw - 1 > q_lo[:, None] - window
+    computed = np.where(interior, tk // cw, np.where(live, chunks, 0))
+    kv = _kv_block(offs, qi, kj, tq, tk, np, window, nq, nk)
+    qb = _q_block(offs, qi, kj, tq, tk, nq, np, window, nk)
     n_live = int(live.sum())
     return {
         "steps": nq * nk, "live": n_live, "dead": nq * nk - n_live,
@@ -212,40 +260,78 @@ def causal_schedule(sq: int, sk: int, q_off: int = 0, k_off: int = 0) -> dict:
     }
 
 
-def _kv_index_map(causal: bool, tq: int, tk: int):
+def _kv_head(group: int):
+    """The K/V row of q row ``bh`` of the [B * H, S, C] operands: q head h
+    reads k/v head h // group, and Hq = group * Hkv, so the row is
+    bh // group."""
+    return (lambda bh: bh) if group == 1 else (
+        lambda bh: jax.lax.div(bh, group))
+
+
+def _kv_index_map(causal: bool, tq: int, tk: int, group: int = 1,
+                  window=None, nq=None, nk=None):
     """Index map of the K and V specs of the forward grid (bh, qi, kj)."""
+    head = _kv_head(group)
     if not causal:
-        return lambda bh, qi, kj, offs: (bh, kj, 0)
-    return lambda bh, qi, kj, offs: (bh, _kv_block(offs, qi, kj, tq, tk), 0)
+        return lambda bh, qi, kj, offs: (head(bh), kj, 0)
+    return lambda bh, qi, kj, offs: (
+        head(bh), _kv_block(offs, qi, kj, tq, tk, jnp, window, nq, nk), 0)
 
 
-def _q_index_map(causal: bool, tq: int, tk: int, nq: int):
+def _q_index_map(causal: bool, tq: int, tk: int, nq: int, window=None,
+                 nk=None):
     """Index map of the q-side specs of the backward grid (bh, kj, qi)."""
     if not causal:
         return lambda bh, kj, qi, offs: (bh, qi, 0)
     return lambda bh, kj, qi, offs: (
-        bh, _q_block(offs, qi, kj, tq, tk, nq), 0)
+        bh, _q_block(offs, qi, kj, tq, tk, nq, jnp, window, nk), 0)
 
 
-def _when_causal(offs_ref, qi, kj, tq, tk, body):
-    """Run ``body(masked, width)`` for this step's causal class: not at all
+def _when_causal(offs_ref, qi, kj, tq, tk, body, window=None):
+    """Run ``body(masked, lo, hi)`` for this step's causal class: not at all
     on a dead step, unmasked over the whole K tile in the interior, and on
-    the diagonal masked over the leading ``width`` columns that hold an
-    allowed one — a static width a variant, picked by ``pl.when``."""
-    live, interior = _causal_tile(offs_ref, qi, kj, tq, tk)
-    pl.when(interior)(lambda: body(False, tk))
-    diagonal = live & ~interior
+    an edge masked over the columns [lo, hi) that hold an allowed one: the
+    chunks up to the diagonal's, and under a window from its trailing
+    edge's on — static bounds a variant, picked by ``pl.when``."""
+    live, interior = _causal_tile(offs_ref, qi, kj, tq, tk, window)
+    pl.when(interior)(lambda: body(False, 0, tk))
+    edge = live & ~interior
     cw = _chunk(tq, tk)
     if cw == tk:
-        pl.when(diagonal)(lambda: body(True, tk))
+        pl.when(edge)(lambda: body(True, 0, tk))
         return
     n = _live_chunks(offs_ref, qi, kj, tq, tk)
-    for c in range(1, tk // cw + 1):
-        pl.when(diagonal & (n == c))(functools.partial(body, True, c * cw))
+    if window is None:
+        for c in range(1, tk // cw + 1):
+            pl.when(edge & (n == c))(functools.partial(body, True, 0, c * cw))
+        return
+    first = _first_chunk(offs_ref, qi, kj, tq, tk, window)
+    # one tile crosses both edges only under a window narrower than a K tile
+    both = window < tk - 2 * cw - tq + 2
+    for a in range(tk // cw):
+        for c in range(a + 1, tk // cw + 1):
+            if both or a == 0 or c == tk // cw:
+                pl.when(edge & (first == a) & (n == c))(
+                    functools.partial(body, True, a * cw, c * cw))
+
+
+def _allowed(offs_ref, qi, kj, tq, tk, lo, hi, window):
+    """[tq, hi - lo] mask of an edge tile's columns [lo, hi): the column is
+    at or before the row and, under a window, less than ``window`` behind."""
+    q_pos = offs_ref[0] + qi * tq + jax.lax.broadcasted_iota(
+        jnp.int32, (tq, hi - lo), 0)
+    k_pos = offs_ref[1] + kj * tk + jax.lax.broadcasted_iota(
+        jnp.int32, (tq, hi - lo), 1)
+    if lo:
+        k_pos = k_pos + lo
+    allowed = q_pos >= k_pos
+    if window is not None:
+        allowed = allowed & (q_pos - k_pos < window)
+    return allowed
 
 
 def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
-            causal: bool, scale: float):
+            causal: bool, scale: float, window=None):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     tq = q_ref.shape[1]
@@ -259,25 +345,21 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
         m_ref[0] = jnp.full_like(m_ref[0], _NEG)
         l_ref[0] = jnp.zeros_like(l_ref[0])
 
-    def body(masked: bool, w: int):
+    def body(masked: bool, lo: int, hi: int):
         # Dots keep the inputs' NATIVE dtype (bf16) with f32 accumulation:
         # the MXU runs bf16x bf16 at 4x its f32 rate, and the operands are
         # already bf16 so the products are bit-identical; only the scale
         # (applied post-dot, in f32) and the p cast below round differently
         # — the standard flash-attention-2 precision recipe.
         q = q_ref[0]                                  # [TQ, D] native dtype
-        k = k_ref[0, :w, :]                           # [W, D], W <= TK
-        v = v_ref[0, :w, :]
+        k = k_ref[0, lo:hi, :]                        # [W, D], W <= TK
+        v = v_ref[0, lo:hi, :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=_dot_prec(q_ref.dtype)) * scale
         if masked:
-            q_pos = offs_ref[0] + qi * tq + jax.lax.broadcasted_iota(
-                jnp.int32, (tq, w), 0)
-            k_pos = offs_ref[1] + kj * tk + jax.lax.broadcasted_iota(
-                jnp.int32, (tq, w), 1)
-            allowed = q_pos >= k_pos
+            allowed = _allowed(offs_ref, qi, kj, tq, tk, lo, hi, window)
             s = jnp.where(allowed, s, _NEG)
         m_prev = m_ref[0][:, 0]                       # [TQ]
         l_prev = l_ref[0][:, 0]
@@ -297,42 +379,64 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
         l_ref[0] = jnp.broadcast_to(l_new[:, None], (tq, 8))
 
     if causal:
-        # Three tile classes: dead tiles (K entirely in the future) are
-        # skipped and fetch nothing (_kv_block); interior tiles (K entirely
-        # in the past) run unmasked; diagonal tiles pay the mask — two
-        # [TQ, W] iotas, compares and selects — over their live column
-        # chunks only. With TK = 4 TQ the diagonal is no small part: 16 of
-        # the 40 live tiles of an 8192-token sequence, every tile at 2048.
-        _when_causal(offs_ref, qi, kj, tq, tk, body)
+        # Three tile classes: dead tiles (K entirely in the future, or
+        # behind the window) are skipped and fetch nothing (_kv_block);
+        # interior tiles (K entirely in the past and inside the window) run
+        # unmasked; edge tiles pay the mask — two [TQ, W] iotas, compares
+        # and selects — over their live column chunks only. With TK = 4 TQ
+        # the diagonal is no small part: 16 of the 40 live tiles of an
+        # 8192-token sequence, every tile at 2048.
+        _when_causal(offs_ref, qi, kj, tq, tk, body, window)
     else:
-        body(False, tk)
+        body(False, 0, tk)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "interpret"))
-def flash_block(q, k, v, q_off, k_off, *, causal: bool = True,
+def _check_heads(q, k, v, causal: bool, window) -> int:
+    """Query heads a k/v head: q [B, Sq, Hq, D] against k [B, Sk, Hkv, D]
+    and v [B, Sk, Hkv, Dv] with Hq a multiple of Hkv."""
+    if k.shape[2] != v.shape[2] or q.shape[2] % k.shape[2]:
+        raise ValueError(
+            f"q has {q.shape[2]} heads, k {k.shape[2]} and v {v.shape[2]}: k "
+            "and v need the same number, and q a multiple of it")
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"window={window!r} needs causal=True and a size of at least 1: "
+            "row t sees the columns s with 0 <= t - s < window")
+    return q.shape[2] // k.shape[2]
+
+
+def _bhsd(x):  # [B, S, H, C] -> [B*H, S, C]
+    B, S, H, C = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B * H, S, C)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
+def flash_block(q, k, v, q_off, k_off, *, causal: bool = True, window=None,
                 interpret: bool = False):
     """Attention partials of q against one K/V block.
 
-    q: [B, Sq, H, D]; k: [B, Sk, H, D]; v: [B, Sk, H, Dv] (Dv may differ
-    from D: latent attention has a 192-wide q.k and a 128-wide v; the score
-    scale is 1/sqrt(D)); q_off/k_off: scalar global positions of element 0
-    (for causal masking across ring steps).
-    Returns (o, m, l): [B, Sq, H, Dv] f32 unnormalized output and [B, Sq, H]
-    f32 row max / row sum. Final output = o / l after merging blocks.
+    q: [B, Sq, Hq, D]; k: [B, Sk, Hkv, D]; v: [B, Sk, Hkv, Dv] (Dv may
+    differ from D: latent attention has a 192-wide q.k and a 128-wide v; the
+    score scale is 1/sqrt(D); Hq is a multiple of Hkv, query head h reading
+    k/v head h // (Hq / Hkv) through the block specs: K and V are not
+    repeated); q_off/k_off: scalar global positions of element 0 (for
+    causal masking across ring steps). ``window`` (with ``causal``): row t
+    sees the columns s with 0 <= t - s < window, in global positions.
+    Returns (o, m, l): [B, Sq, Hq, Dv] f32 unnormalized output and
+    [B, Sq, Hq] f32 row max / row sum. Final output = o / l after merging
+    blocks; a row that sees no column of this block has l = 0.
     """
     B, Sq, H, D = q.shape
     Sk, Dv = k.shape[1], v.shape[-1]
+    group = _check_heads(q, k, v, causal, window)
     scale = 1.0 / math.sqrt(D)
     tq = _q_tile(Sq)
-
-    def bhsd(x):  # [B, S, H, C] -> [B*H, S, C]
-        return x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], x.shape[3])
-
     tk = _k_tile(Sk)
     offs = jnp.asarray([q_off, k_off], jnp.int32)
     grid = (B * H, Sq // tq, Sk // tk)
-    kernel = functools.partial(_kernel, causal=causal, scale=scale)
-    kv_map = _kv_index_map(causal, tq, tk)
+    kernel = functools.partial(_kernel, causal=causal, scale=scale,
+                               window=window)
+    kv_map = _kv_index_map(causal, tq, tk, group, window, Sq // tq, Sk // tk)
     kw = _vma(q, k, v)
     out_shape = (
         jax.ShapeDtypeStruct((B * H, Sq, Dv), jnp.float32, **kw),
@@ -358,7 +462,7 @@ def flash_block(q, k, v, q_off, k_off, *, causal: bool = True,
     params = {} if interpret else {
         "compiler_params": pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))}
-    qkv = (bhsd(q), bhsd(k), bhsd(v))
+    qkv = (_bhsd(q), _bhsd(k), _bhsd(v))
     with jax.named_scope(SCOPE_FWD):
         o, m, l = pl.pallas_call(
             kernel, grid_spec=grid_spec, out_shape=out_shape,
@@ -372,10 +476,11 @@ def flash_block(q, k, v, q_off, k_off, *, causal: bool = True,
 
 
 def _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref,
-               d_ref, masked: bool, w: int, scale: float):
+               d_ref, masked: bool, lo: int, hi: int, scale: float,
+               window=None):
     """Shared backward-tile recompute -> (q, k, g*inv_l, P_unnorm, dS) over
-    the leading ``w`` columns of the K/V tile (all of it but on a diagonal
-    tile: _when_causal).
+    the columns [lo, hi) of the K/V tile (all of it but on an edge tile:
+    _when_causal).
 
     The probability tile is rebuilt in VMEM from the saved GLOBAL (m, l)
     row statistics with the same offset-based causal mask as the forward
@@ -389,8 +494,8 @@ def _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref,
     # scale moves AFTER the qk dot (q stays unscaled, so the dk product
     # applies it explicitly)
     q = q_ref[0]
-    k = k_ref[0, :w, :]
-    v = v_ref[0, :w, :]
+    k = k_ref[0, lo:hi, :]
+    v = v_ref[0, lo:hi, :]
     g = g_ref[0]
     m = m_ref[0][:, 0]
     inv_l = 1.0 / l_ref[0][:, 0]
@@ -399,11 +504,7 @@ def _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref,
                             preferred_element_type=jnp.float32,
                             precision=_dot_prec(q_ref.dtype)) * scale
     if masked:
-        q_pos = offs_ref[0] + qi * tq + jax.lax.broadcasted_iota(
-            jnp.int32, (tq, w), 0)
-        k_pos = offs_ref[1] + kj * tk + jax.lax.broadcasted_iota(
-            jnp.int32, (tq, w), 1)
-        allowed = q_pos >= k_pos
+        allowed = _allowed(offs_ref, qi, kj, tq, tk, lo, hi, window)
         s = jnp.where(allowed, s, _NEG)
     # VPU saver: the softmax row normalizer inv_l is folded into the
     # per-ROW quantities instead of the [TQ, TK] tile — p stays
@@ -426,7 +527,8 @@ def _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref,
 
 
 def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref,
-                dk_ref, dv_ref, dq_ref, *, causal: bool, scale: float):
+                dk_ref, dv_ref, dq_ref, *, causal: bool, scale: float,
+                window=None):
     """The backward (flash-attention-2): for each K/V tile, iterate query
     tiles innermost and accumulate dv += P^T @ dO and dk += dS^T @ (Q *
     scale) into the K tile's output blocks, and dq += dS @ K * scale into
@@ -447,20 +549,20 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref,
         dk_ref[0] = jnp.zeros_like(dk_ref[0])
         dv_ref[0] = jnp.zeros_like(dv_ref[0])
 
-    def body(masked: bool, w: int):
+    def body(masked: bool, lo: int, hi: int):
         q, k, g, p, ds = _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref,
-                                    g_ref, m_ref, l_ref, d_ref, masked, w,
-                                    scale)
+                                    g_ref, m_ref, l_ref, d_ref, masked, lo,
+                                    hi, scale, window)
         prec = _dot_prec(q_ref.dtype)
-        # rows of dk/dv behind the leading w belong to columns that are
-        # masked for this whole q tile: nothing is added to them
-        dv_ref[0, :w, :] += jax.lax.dot_general(
+        # rows of dk/dv outside [lo, hi) belong to columns that are masked
+        # for this whole q tile: nothing is added to them
+        dv_ref[0, lo:hi, :] += jax.lax.dot_general(
             p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec)
         ds = ds.astype(q.dtype)         # cast once for both products
         # q is unscaled in the shared tile recompute: apply the score scale
         # here (dK = dS^T @ (scale * Q))
-        dk_ref[0, :w, :] += jax.lax.dot_general(
+        dk_ref[0, lo:hi, :] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec) * scale
         dq_ref[0, pl.ds(pl.multiple_of(qi * tq, tq), tq), :] += (
@@ -469,9 +571,9 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref,
                 preferred_element_type=jnp.float32, precision=prec) * scale)
 
     if causal:
-        _when_causal(offs_ref, qi, kj, tq, tk, body)
+        _when_causal(offs_ref, qi, kj, tq, tk, body, window)
     else:
-        body(False, tk)
+        body(False, 0, tk)
 
 
 def _lane8(x):  # [B, S, H] -> [B*H, S, 8] (TPU sublane x lane tiling)
@@ -480,19 +582,26 @@ def _lane8(x):  # [B, S, H] -> [B*H, S, 8] (TPU sublane x lane tiling)
     return jnp.broadcast_to(t[:, :, None], (B * H, S, 8))
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
 def flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off, *,
-                    causal: bool = True, interpret: bool = False):
+                    causal: bool = True, window=None,
+                    interpret: bool = False):
     """Gradients of q's attention against one K/V block (one pallas kernel).
 
-    Inputs: q [B, Sq, H, D]; k [B, Sk, H, D]; v [B, Sk, H, Dv];
-    g = dOut [B, Sq, H, Dv];
+    Inputs: q [B, Sq, Hq, D]; k [B, Sk, Hkv, D]; v [B, Sk, Hkv, Dv];
+    g = dOut [B, Sq, Hq, Dv];
     ``d_term = sum(dOut * Out, -1)`` and the saved GLOBAL softmax row stats
-    ``m`` (row max) and ``l`` (row sum), all [B, Sq, H] f32 — the same
+    ``m`` (row max) and ``l`` (row sum), all [B, Sq, Hq] f32 — the same
     quantities the XLA ring backward reconstructs per block
     (context._ring_backward). Returns (dq_partial, dk, dv) in f32, shaped
     as q, k and v: the caller sums dq partials over blocks and ships dk/dv
-    home with the ring.
+    home with the ring. ``window`` as in :func:`flash_block`.
+
+    With fewer k/v heads than q heads the kernel reads a k/v head through
+    its block specs and writes dk/dv a q head (the grid keeps a q head's
+    steps together for its resident dq, so a K tile's output block cannot
+    be revisited by the next head of its group); the heads of a group are
+    summed in XLA, outside the kernel's scope.
 
     The kernel holds a head's whole dq in VMEM. Where [Sq, D] is past what
     ``_dq_rows`` allows (32k tokens at 128 lanes), q is walked in row
@@ -506,24 +615,22 @@ def flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off, *,
     def block(r):
         qb, gb, db, mb, lb = (x[:, r:r + rows] for x in (q, g, d_term, m, l))
         return _bwd_call(qb, k, v, gb, db, mb, lb, q_off + r, k_off,
-                         causal, interpret)
+                         causal, window, interpret)
 
     dq, dk, dv = zip(*map(block, range(0, Sq, rows)))
     return jnp.concatenate(dq, axis=1), sum(dk[1:], dk[0]), sum(dv[1:], dv[0])
 
 
-def _bwd_call(q, k, v, g, d_term, m, l, q_off, k_off, causal, interpret):
+def _bwd_call(q, k, v, g, d_term, m, l, q_off, k_off, causal, window,
+              interpret):
     """One ``pallas_call`` of the backward kernel: (dq, dk, dv) of q's rows
     against the K/V block, dq resident (``flash_block_bwd``)."""
     B, Sq, H, D = q.shape
     Sk, Dv = k.shape[1], v.shape[-1]
+    group = _check_heads(q, k, v, causal, window)
     scale = 1.0 / math.sqrt(D)
     tq = _q_tile(Sq)
     tk = _k_tile(Sk)
-
-    def bhsd(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], x.shape[3])
-
     offs = jnp.asarray([q_off, k_off], jnp.int32)
     kw = _vma(q, k, v, g)
     # dq is summed over kj and dk/dv over qi: only bh is independent
@@ -531,14 +638,15 @@ def _bwd_call(q, k, v, g, d_term, m, l, q_off, k_off, causal, interpret):
         "compiler_params": pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_bwd_vmem(Sq, D, Dv))}
-    q_map = _q_index_map(causal, tq, tk, Sq // tq)
+    q_map = _q_index_map(causal, tq, tk, Sq // tq, window, Sk // tk)
+    head = _kv_head(group)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B * H, Sk // tk, Sq // tq),
         in_specs=[
             pl.BlockSpec((1, tq, D), q_map),
-            pl.BlockSpec((1, tk, D), lambda bh, kj, qi, o: (bh, kj, 0)),
-            pl.BlockSpec((1, tk, Dv), lambda bh, kj, qi, o: (bh, kj, 0)),
+            pl.BlockSpec((1, tk, D), lambda bh, kj, qi, o: (head(bh), kj, 0)),
+            pl.BlockSpec((1, tk, Dv), lambda bh, kj, qi, o: (head(bh), kj, 0)),
             pl.BlockSpec((1, tq, Dv), q_map),
             pl.BlockSpec((1, tq, 8), q_map),
             pl.BlockSpec((1, tq, 8), q_map),
@@ -551,11 +659,12 @@ def _bwd_call(q, k, v, g, d_term, m, l, q_off, k_off, causal, interpret):
         ],
     )
     # the operands are made outside the scope: it times the kernel alone
-    operands = (offs, bhsd(q), bhsd(k), bhsd(v), bhsd(g),
+    operands = (offs, _bhsd(q), _bhsd(k), _bhsd(v), _bhsd(g),
                 _lane8(m), _lane8(l), _lane8(d_term))
     with jax.named_scope(SCOPE_DKV):
         dk, dv, dq = pl.pallas_call(
-            functools.partial(_bwd_kernel, causal=causal, scale=scale),
+            functools.partial(_bwd_kernel, causal=causal, scale=scale,
+                              window=window),
             grid_spec=grid_spec,
             out_shape=(
                 jax.ShapeDtypeStruct((B * H, Sk, D), jnp.float32, **kw),
@@ -568,7 +677,12 @@ def _bwd_call(q, k, v, g, d_term, m, l, q_off, k_off, causal, interpret):
     def sbhd(x):  # [B*H, S, C] -> [B, S, H, C]
         return x.reshape((B, H) + x.shape[1:]).transpose(0, 2, 1, 3)
 
-    return sbhd(dq), sbhd(dk), sbhd(dv)
+    def of_group(x):  # [B, S, Hq, C] -> [B, S, Hkv, C]: a k/v head's q heads
+        if group == 1:
+            return x
+        return x.reshape(x.shape[:2] + (H // group, group, -1)).sum(axis=3)
+
+    return sbhd(dq), of_group(sbhd(dk)), of_group(sbhd(dv))
 
 
 def _blockwise_attention(q, k, v, causal: bool, tk: int):
@@ -617,19 +731,21 @@ def _blockwise_attention(q, k, v, causal: bool, tk: int):
     return (o / l[..., None]).astype(q.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, causal, interpret):
-    o, m, l = flash_block(q, k, v, 0, 0, causal=causal, interpret=interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, causal, window, interpret):
+    o, m, l = flash_block(q, k, v, 0, 0, causal=causal, window=window,
+                          interpret=interpret)
     return (o / l[..., None]).astype(q.dtype)
 
 
-def _flash_fwd(q, k, v, causal, interpret):
-    o, m, l = flash_block(q, k, v, 0, 0, causal=causal, interpret=interpret)
+def _flash_fwd(q, k, v, causal, window, interpret):
+    o, m, l = flash_block(q, k, v, 0, 0, causal=causal, window=window,
+                          interpret=interpret)
     out = (o / l[..., None]).astype(q.dtype)
     return out, (q, k, v, out, m, l)
 
 
-def _flash_bwd(causal, interpret, res, g):
+def _flash_bwd(causal, window, interpret, res, g):
     # flash-attention-2 style kernel backward: one kernel makes dq, dk and
     # dv from probability tiles rebuilt in VMEM from the saved (m, l) stats
     # — no autodiff-through-recompute, no [S, S] tensor in either direction
@@ -637,18 +753,24 @@ def _flash_bwd(causal, interpret, res, g):
     gf = g.astype(jnp.float32)
     d_term = jnp.sum(gf * out.astype(jnp.float32), axis=-1)
     dq, dk, dv = flash_block_bwd(q, k, v, gf, d_term, m, l, 0, 0,
-                                 causal=causal, interpret=interpret)
+                                 causal=causal, window=window,
+                                 interpret=interpret)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(q, k, v, *, causal: bool = True,
+def flash_attention(q, k, v, *, causal: bool = True, window=None,
                     interpret: bool = False):
-    """Single-device flash attention: q, k [B, S, H, D] and v [B, S, H, Dv]
-    give the normalized output [B, S, H, Dv] (Dv = D for equal-width heads;
-    latent attention has D = 192 and Dv = 128; the scale is 1/sqrt(D)).
+    """Single-device flash attention: q [B, S, Hq, D], k [B, S, Hkv, D] and
+    v [B, S, Hkv, Dv] give the normalized output [B, S, Hq, Dv] (Dv = D for
+    equal-width heads; latent attention has D = 192 and Dv = 128; the scale
+    is 1/sqrt(D)). Grouped-query heads: Hq is a multiple of Hkv and query
+    head h reads k/v head h // (Hq / Hkv); K and V stay at Hkv heads in HBM,
+    forward and backward. ``window=W`` (with ``causal``) is a sliding
+    window: row t sees the columns s with 0 <= t - s < W, and the grid
+    steps wholly behind the window are skipped like those in the future.
 
     Differentiable: the forward runs the pallas VMEM kernel and the
     backward one pallas flash-attention-2 kernel (:func:`flash_block_bwd`:
@@ -661,9 +783,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
     # shape is one call of the kernel, and the schedule the kernels run
     metrics.gauge("flash.bwd_fused").set(
         int(_dq_rows(q.shape[1], q.shape[-1]) == q.shape[1]))
+    metrics.gauge("flash.kv_group").set(_check_heads(q, k, v, causal, window))
+    metrics.gauge("flash.window").set(window or 0)
     if causal:
-        sched = causal_schedule(q.shape[1], k.shape[1])
+        sched = causal_schedule(q.shape[1], k.shape[1], window=window)
         metrics.gauge("flash.dead_steps_fetching").set(sched["dead_fetching"])
         metrics.gauge("flash.chunks_computed").set(sched["chunks_computed"])
         metrics.gauge("flash.chunks_needed").set(sched["chunks_needed"])
-    return _flash(q, k, v, causal, interpret)
+    return _flash(q, k, v, causal, window, interpret)

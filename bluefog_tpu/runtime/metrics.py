@@ -541,6 +541,14 @@ _HELP_EXACT: Dict[str, str] = {
                        "traced is one call of the kernel, a head's dq "
                        "resident in VMEM; 0 when the shape rule "
                        "(flash._dq_rows) walks q in row blocks",
+    "flash.window": "sliding window of the last flash_attention traced: "
+                    "row t sees the columns s with 0 <= t - s < window; 0 "
+                    "without one. The three schedule gauges count both of "
+                    "the band's edges",
+    "flash.kv_group": "query heads a k/v head of the last flash_attention "
+                      "traced (Hq / Hkv; 1 without grouped-query heads): "
+                      "k and v are read through the block specs, never "
+                      "repeated",
     "trace.requests": "serve requests traced into the flight ring "
                       "(BLUEFOG_TRACE_SERVE; docs/slo.md)",
 }

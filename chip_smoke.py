@@ -17,7 +17,9 @@ benchmarks, with random weights from a seed:
   machine mesh, and two ``DistributedWinPutOptimizer`` steps on a small MLP.
 * ``lm_flash``: the two compiled flash-attention kernels against the dense
   f32-softmax reference at 2048 tokens (one K tile) and at 8192 (dead steps,
-  interior tiles and diagonal tiles of every chunk count), then the 4-layer
+  interior tiles and diagonal tiles of every chunk count), and at 28 query
+  heads over 4 k/v heads under a 1024-token window at 4096 tokens (dead steps
+  past either edge, tiles on the window's trailing edge), then the 4-layer
   d_model-2048 LM at 8192 tokens per chip through
   ``bf.DistributedNeighborAllreduceOptimizer.step``.
 * ``mla_moe``: the kernels again at latent attention's widths (q.k 192, v
@@ -83,6 +85,11 @@ LM_SEQ, LM_STEPS = 8192, 4
 # diagonal; then the 16 x 4 grid of a benchmark sequence, one head of it (24
 # dead steps, 24 interior tiles, 16 diagonal tiles of one to four live chunks)
 KERNEL_SHAPES = {"s2048": (1, 2048, 16, 128), "s8192": (1, 8192, 1, 128)}
+# SmallThinker's heads under a window a quarter of the sequence, as its 4096
+# is of 16384 (the dense reference holds [28, S, S] scores, so S is 4096): 28
+# query heads read 4 k/v heads, row t sees the 1024 columns up to t; a K tile
+# is dead behind the window as it is in the future
+GROUPED_WINDOW = dict(bsh=(1, 4096, 28), d_v=128, d_qk=128, kv_heads=4, window=1024)
 # JoyAI-LLM-Flash's config.json, two layers and an eighth of the vocabulary
 MLA_MOE = LMConfig(
     vocab_size=16160, hidden_size=2048, num_hidden_layers=2, num_attention_heads=32,
@@ -292,23 +299,28 @@ def _check_flash_kernels(d_v=None, d_qk=None):
             for name, shape in KERNEL_SHAPES.items()}
 
 
-def _check_flash_kernels_at(bsh, d_v, d_qk):
+def _check_flash_kernels_at(bsh, d_v, d_qk, kv_heads=None, window=None):
+    """``kv_heads`` (fewer than the query heads of ``bsh``) and ``window``
+    are handed to the kernels and to the dense reference alike."""
     keys = jax.random.split(jax.random.PRNGKey(3), 4)
-    q, k, v, w = (jax.random.normal(kk, bsh + (d,), jnp.bfloat16)
-                  for kk, d in zip(keys, (d_qk, d_qk, d_v, d_v)))
+    kv = bsh[:2] + (kv_heads or bsh[2],)
+    q, k, v, w = (jax.random.normal(kk, shape + (d,), jnp.bfloat16)
+                  for kk, shape, d in zip(keys, (bsh, kv, kv, bsh),
+                                          (d_qk, d_qk, d_v, d_v)))
+    flash = partial(FLASH, window=window)
 
     def weighted(attn):
         return lambda q, k, v: jnp.sum(
             attn(q, k, v).astype(jnp.float32) * w.astype(jnp.float32))
 
-    grad = jax.jit(jax.grad(weighted(FLASH), argnums=(0, 1, 2)))
+    grad = jax.jit(jax.grad(weighted(flash), argnums=(0, 1, 2)))
     calls = _mosaic_calls(grad, q, k, v)
     if calls != 2:
         raise RuntimeError(
             f"expected 2 Mosaic kernels under the flash gradient (the forward "
             f"and the one backward), found {calls}")
-    ref = partial(reference_attention, causal=True)
-    got = (jax.jit(FLASH)(q, k, v),) + grad(q, k, v)
+    ref = partial(reference_attention, causal=True, window=window)
+    got = (jax.jit(flash)(q, k, v),) + grad(q, k, v)
     want = (jax.jit(ref)(q, k, v),) + jax.jit(
         jax.grad(weighted(ref), argnums=(0, 1, 2)))(q, k, v)
     err = {}
@@ -323,6 +335,7 @@ def _check_flash_kernels_at(bsh, d_v, d_qk):
 def phase_lm_flash():
     n = bf.size()
     kernel_err = _check_flash_kernels()
+    kernel_err["s4096_28over4_w1024"] = _check_flash_kernels_at(**GROUPED_WINDOW)
     model = TransformerLM(dtype=jnp.bfloat16, attn_fn=FLASH, **LM)
     params = jax.jit(lambda k: model.init(
         k, jnp.zeros((1, LM_SEQ), jnp.int32))["params"])(jax.random.PRNGKey(0))

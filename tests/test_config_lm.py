@@ -7,6 +7,7 @@ Pallas kernels interpreted.
 
 import dataclasses
 import importlib.util
+import json
 import os
 from functools import partial
 
@@ -376,3 +377,244 @@ def test_a_dense_equal_width_configuration_runs_without_experts_or_mtp():
     loss, (routing, counters) = next_token_loss(net)(
         params, {}, (tokens, jnp.roll(tokens, -1, axis=1)))
     assert np.isfinite(float(loss)) and routing == {} and counters == {}
+
+
+# -- the grouped-query / window / ReGLU block (SmallThinker) ------------------
+# ``benchmark/families/gqa_window_moe_lm.py`` keeps the plain float32 reference
+# of this block, as ``mla_moe_lm.py`` keeps the latent one's; neither shares
+# code with ``bluefog_tpu``.
+
+def _gqa_family():
+    path = os.path.join(ROOT, "benchmark", "families", "gqa_window_moe_lm.py")
+    spec = importlib.util.spec_from_file_location("gqa_window_moe_lm_family", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+GQA = _gqa_family()
+
+# the published block at toy widths: two periods of the layout (NoPE global,
+# then three rope layers under a 16-token window), 4 query heads over 2 k/v
+# heads of 8 (28 over 4 of 128 -- the only ratio the toy changes), experts
+# [8, 16) of 32 held (share 1 of 4), top-4, ReGLU, no shared expert, no bias
+with open(os.path.join(ROOT, "benchmark", "tests", "toy", "toy-smallthinker.json")) as _f:
+    GQA_TOY = json.load(_f)
+GQA_BATCH = {"sequences": 2, "seq_len": 64}
+
+
+@pytest.fixture(scope="module")
+def gqa_toy():
+    """(cfg, params, batch of one rank) from fixed seeds."""
+    params, state = GQA.init(GQA_TOY, GQA_BATCH, jax.random.PRNGKey(0))
+    assert state == {}
+    batch = jax.tree_util.tree_map(
+        lambda x: x[0], GQA.make_batch(GQA_TOY, GQA_BATCH, jax.random.PRNGKey(1), 1))
+    return GQA_TOY, params, batch
+
+
+def _gqa_system_loss(cfg, params, batch):
+    return GQA.loss(cfg)[0](params, {}, batch)[0]
+
+
+def test_the_grouped_block_has_its_own_parameters_and_no_routing_state(gqa_toy):
+    cfg, params, _ = gqa_toy
+    variables = GQA.model(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 64), jnp.int32))
+    assert set(variables) == {"params"}                 # routing_bias=False: no collection
+    layer = params["layer_0"]
+    assert set(layer) == {"attn_norm", "router", "attn", "ffn_norm", "ffn"}
+    assert set(layer["attn"]) == {"q", "k", "v", "o"}
+    assert set(layer["ffn"]) == {"gate", "up", "down"}  # no router here, no shared expert
+    assert layer["router"].shape == (32, 32) and layer["ffn"]["gate"].shape == (8, 32, 16)
+    assert layer["attn"]["q"]["kernel"].shape == (32, 32)
+    assert layer["attn"]["k"]["kernel"].shape == layer["attn"]["v"]["kernel"].shape == (32, 16)
+    lm = GQA.lm_config(cfg)
+    assert [lm.window_of(i) for i in range(8)] == [None, 16, 16, 16] * 2
+    assert [lm.rotary_in(i) for i in range(8)] == [False, True, True, True] * 2
+
+
+# Both sides are float32 at the highest matmul precision and differ in the
+# order of their sums only (online softmax in tiles, rows gathered by expert,
+# eight layers deep): logits to 2e-5 of the largest, the loss to 2e-6, every
+# gradient leaf to 2e-4 of its largest element -- the limits of the latent
+# block's test above. Each fault below moves the loss by ten tolerances or
+# more (the least, bfloat16 parameters, by ~4e-5 of it).
+
+def test_grouped_logits_loss_and_gradients_match_the_plain_reference(gqa_toy):
+    cfg, params, batch = gqa_toy
+    tokens = batch[0][:1]
+    logits = GQA.model(cfg).apply({"params": params}, tokens)
+    want, _ = GQA.plain_forward(cfg, params, tokens, positions=64)
+    assert _rel(logits, want) <= 2e-5
+    loss, grads = jax.value_and_grad(partial(_gqa_system_loss, cfg))(params, batch)
+    want_loss, want_grads = jax.value_and_grad(
+        lambda p: GQA.plain_loss(cfg, p, {}, batch))(params)
+    assert abs(float(loss) - float(want_loss)) <= LOSS_RTOL * float(want_loss)
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, got), want in zip(flat, jax.tree_util.tree_leaves(want_grads)):
+        assert _rel(got, want) <= GRAD_RTOL, (jax.tree_util.keystr(path), _rel(got, want))
+
+
+def test_the_toy_smallthinker_through_opt_step_matches_the_plain_reference(gqa_toy, bf8):
+    """``DistributedNeighborAllreduceOptimizer(sgd(1)).step`` on eight ranks
+    that hold the same parameters and take the same batch: the mix of equal
+    parameters is those parameters, so ``before - after`` is the gradient the
+    step computed -- against ``jax.grad`` of the plain float32 loss. The limit
+    is GRAD_RTOL plus the float32 rounding of ``p - g`` and of the mix
+    (an ulp of a parameter of 0.5 is 6e-8; the largest gradients are 1e-2)."""
+    cfg, params, batch = gqa_toy
+    loss_fn, form = GQA.loss(cfg)
+    opt = bf.DistributedNeighborAllreduceOptimizer(optax.sgd(1.0), loss_fn, **form)
+    state = opt.init(params, model_state={})
+    stacked = jax.tree_util.tree_map(lambda x: jnp.broadcast_to(x, (8,) + x.shape), batch)
+    state, metrics = opt.step(state, stacked)
+    want_loss, want_grads = jax.value_and_grad(
+        lambda p: GQA.plain_loss(cfg, p, {}, batch))(params)
+    np.testing.assert_allclose(np.asarray(metrics["loss"]), float(want_loss),
+                               rtol=LOSS_RTOL)
+    after = jax.device_get(state.params)
+    flat = jax.tree_util.tree_flatten_with_path(params)[0]
+    for (path, before), new, want in zip(flat, jax.tree_util.tree_leaves(after),
+                                         jax.tree_util.tree_leaves(want_grads)):
+        for rank in (0, 7):
+            got = np.asarray(before) - new[rank]
+            assert _rel(got, want) <= GRAD_RTOL + 1e-4, (jax.tree_util.keystr(path), rank)
+    aux = jax.device_get(metrics["aux"])
+    assert np.all(aux["rows_overflowed"] == 0) and np.all(aux["rows_routed"] > 0)
+
+
+def _gqa_bf16_parameters(cfg, params):
+    return cfg, jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.bfloat16).astype(jnp.float32), params)
+
+
+def _gqa_window_dropped(cfg, params):
+    return {**cfg, "sliding_window_layout": [0] * 12}, params
+
+
+def _gqa_rope_everywhere(cfg, params):
+    return {**cfg, "rope_layout": [1] * 12}, params
+
+
+def _gqa_silu(cfg, params):
+    return {**cfg, "expert_act": "silu"}, params
+
+
+def _gqa_router_reads_the_ffn_input(cfg, params):
+    """The layer's router weight applied to ``norm_ffn(x)`` (see the monkeypatch)."""
+    return cfg, params
+
+
+def _gqa_kv_head_by_remainder(cfg, params):
+    """Query head h reads k/v head h % Hkv (see the monkeypatch)."""
+    return cfg, params
+
+
+@pytest.mark.parametrize("fault", [
+    _gqa_bf16_parameters, _gqa_window_dropped, _gqa_rope_everywhere, _gqa_silu,
+    _gqa_router_reads_the_ffn_input, _gqa_kv_head_by_remainder])
+def test_the_grouped_comparison_is_tight_enough_to_see(fault, gqa_toy, monkeypatch):
+    """What the tolerances must catch: the system computes with the fault, the
+    reference without. The four wrong-model controls run on the chip too
+    (PERF.md section 6, PR 32)."""
+    from bluefog_tpu.models import config_lm
+    from bluefog_tpu.parallel import flash
+
+    cfg, params, batch = gqa_toy
+    want = float(GQA.plain_loss(cfg, params, {}, batch))
+    if fault is _gqa_router_reads_the_ffn_input:
+        routed = config_lm.RoutedExperts.__call__
+
+        def misrouted(self, x, choice=None, router_logits=None):
+            router = self.parent.variables["params"]["router"]
+            return routed(self, x, choice, x.astype(jnp.float32) @ router)
+        monkeypatch.setattr(config_lm.RoutedExperts, "__call__", misrouted)
+    if fault is _gqa_kv_head_by_remainder:
+        monkeypatch.setattr(flash, "_kv_head", lambda group: (
+            lambda bh: jax.lax.rem(bh, 4 // group)))       # bh = b * 4 + h, two k/v heads
+        flash.flash_block.clear_cache()
+    try:
+        got = float(_gqa_system_loss(*fault(cfg, params), batch))
+    finally:
+        flash.flash_block.clear_cache()
+    assert abs(got - want) > 10 * LOSS_RTOL * want, (got, want)
+
+
+def test_the_eight_shares_of_a_reglu_layer_add_up_to_the_uncut_layer():
+    """The guide's share test at SmallThinker's shape: the outputs of the
+    eight shares of 8 of a 64-expert ReGLU layer, the router's logits handed
+    in from outside, add up to the uncut plain layer -- there is no shared
+    expert to count once."""
+    d, f, experts, top = 32, 16, 64, 6
+    layer = lambda held: expert.RoutedExperts(  # noqa: E731
+        num_experts=experts, experts_per_token=top, d_ff=f, held=held, n_shared=0,
+        scoring="softmax", activation="relu", routing_bias=False, interpret=True)
+    u = jax.random.normal(jax.random.PRNGKey(21), (1, 48, d), jnp.float32)
+    logits = jax.random.normal(jax.random.PRNGKey(22), (1, 48, experts), jnp.float32)
+    variables = layer((0, experts)).init(jax.random.PRNGKey(23), u, None, logits)
+    assert set(variables) == {"params"}
+    assert set(variables["params"]) == {"gate", "up", "down"}   # no router, shared or bias
+    cfg = {"moe_num_primary_experts": experts, "deployment": {"share": 0},
+           "moe_num_active_primary_experts": top, "expert_act": "relu"}
+    want, _ = GQA._expert_layer(cfg, variables["params"], logits[0], u[0])
+    total = 0.0
+    for r in range(8):
+        out = layer((8 * r, 8 * r + 8)).apply(_share_of(variables, 8 * r, 8 * r + 8),
+                                              u, None, logits)
+        total = total + out
+    assert _rel(total[0], want) <= 1e-5
+    assert _rel(out[0], want) > 1e-2                # one share alone is not it
+    # the same layer with its own router parameter scores the same way
+    own = layer((0, experts)).init(jax.random.PRNGKey(23), u)
+    assert set(own["params"]) == {"router", "gate", "up", "down"}
+
+
+@pytest.mark.parametrize("n_shared,routing_bias", [(0, False), (0, True), (1, False), (1, True)])
+def test_no_shared_expert_and_no_bias_create_no_variables(n_shared, routing_bias):
+    layer = expert.RoutedExperts(num_experts=32, experts_per_token=4, d_ff=16, held=(8, 16),
+                                 n_shared=n_shared, routing_bias=routing_bias, interpret=True)
+    h = jax.random.normal(jax.random.PRNGKey(24), (1, 16, 32), jnp.float32)
+    variables = layer.init(jax.random.PRNGKey(25), h)
+    assert ("shared" in variables["params"]) == bool(n_shared)
+    assert ("routing" in variables) == routing_bias
+    out, state = layer.apply(variables, h, mutable=["intermediates", "routing"])
+    assert ("routing" in state) == routing_bias and np.all(np.isfinite(np.asarray(out)))
+    if not routing_bias:
+        # the top-k is of the scores themselves
+        scores = jax.nn.sigmoid(h[0] @ variables["params"]["router"])
+        want, _ = expert.route_top_k(scores, None, 4, 1.0)
+        ids = np.asarray(state["intermediates"]["moe_choice"][0])[0]
+        np.testing.assert_array_equal(np.sort(ids, axis=1), np.sort(np.asarray(want), axis=1))
+
+
+def _flash_operands(jaxpr):
+    """Shapes of the operands of every ``pallas_call`` of a jaxpr, nested ones included."""
+    from jax._src import core as jax_core
+
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append([tuple(v.aval.shape) for v in eqn.invars])
+        else:
+            for sub in jax_core.jaxprs_in_params(eqn.params):
+                found += _flash_operands(sub)
+    return found
+
+
+def test_k_and_v_reach_the_kernels_at_their_own_head_count(gqa_toy):
+    """The step's jaxpr: no operand of a flash ``pallas_call`` is a k or v at
+    the query heads' count. q, o and dO are [Hq, S, D]; k and v stay [Hkv, S, D]
+    forward and backward; only the per-q-head dk/dv the backward returns for
+    XLA's group sum are Hq high, and they are outputs."""
+    cfg, params, batch = gqa_toy
+    cfg = {**cfg, "num_hidden_layers": 4}
+    params = {k: v for k, v in params.items() if k not in {f"layer_{i}" for i in range(4, 8)}}
+    jaxpr = jax.make_jaxpr(jax.grad(partial(_gqa_system_loss, cfg)))(params, batch)
+    calls = [shapes for shapes in _flash_operands(jaxpr.jaxpr) if shapes[0] == (2,)]
+    assert len(calls) == 8                            # forward and backward, four layers
+    hq, hkv, s, d = 2 * 4, 2 * 2, 64, 8               # two sequences a batch
+    for shapes in calls:
+        q, k, v = shapes[1:4]
+        assert q == (hq, s, d) and k == v == (hkv, s, d), shapes
+        # the rest are q-side: dO and the row statistics
+        assert all(shape[0] == hq for shape in shapes[4:]), shapes

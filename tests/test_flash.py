@@ -380,3 +380,193 @@ def test_dq_rows_and_vmem_limit_follow_the_shapes(sq, d, dv, rows, vmem_mib):
     assert flash._dq_rows(sq, d) == rows
     assert sq % rows == 0 and rows % flash._q_tile(sq) == 0
     assert flash._bwd_vmem(rows, d, dv) == vmem_mib * MIB
+
+
+# -- grouped-query heads and the sliding window ------------------------------
+# q at Hq heads against k and v at Hkv (query head h reads k/v head h // group
+# through the block specs), and a trailing edge W columns behind the diagonal:
+# a tile is dead past either edge, and an edge tile works on the chunks from
+# the window's first to the diagonal's last.
+
+def _grouped(hq, hkv, seq=SEQ, d=8):
+    """q [1, seq, hq, d], k and v [1, seq, hkv, d], the loss's weights as q."""
+    keys = jax.random.split(jax.random.PRNGKey(19), 4)
+    return tuple(jax.random.normal(kk, (1, seq, h, d), jnp.float32)
+                 for kk, h in zip(keys, (hq, hkv, hkv, hq)))
+
+
+# no window; narrower than a column chunk (8) -- one tile crosses both edges;
+# across two K tiles of 32
+WINDOWS = [None, 5, 40]
+
+
+@pytest.mark.parametrize("window", WINDOWS, ids=["none", "w5", "w40"])
+@pytest.mark.parametrize("hq,hkv", [(4, 4), (7, 1), (28, 4)])
+def test_grouped_heads_and_window_match_dense(tiles_8x32, hq, hkv, window):
+    """Forward and all three gradients against dense masked attention with
+    K and V repeated, on the 16 x 4 grid; dk and dv come back at Hkv heads."""
+    q, k, v, w = _grouped(hq, hkv)
+    attn = functools.partial(flash_attention, causal=True, window=window,
+                             interpret=True)
+    dense = functools.partial(reference_attention, causal=True, window=window)
+    got = (attn(q, k, v),) + _grads(attn, q, k, v, w)
+    want = (dense(q, k, v),) + _grads(dense, q, k, v, w)
+    assert [g.shape for g in got] == [q.shape, q.shape, k.shape, v.shape]
+    for a, b, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
+                                   err_msg=name)
+    gauges = metrics.snapshot(include_native=False)["gauges"]
+    assert gauges["flash.kv_group"] == hq // hkv
+    assert gauges["flash.window"] == (window or 0)
+    assert gauges["flash.dead_steps_fetching"] == 0.0
+    assert gauges["flash.chunks_computed"] == gauges["flash.chunks_needed"]
+
+
+def _window_blocks(q, k, v, w, q_off, window):
+    """``_blocks`` under a window and grouped heads: rows [q_off, +BLOCK) of
+    q against the two K/V blocks at their runtime offsets, merged the way the
+    ring merges them (a block a row sees nothing of has l = 0)."""
+    rows = slice(q_off, q_off + BLOCK)
+    qb, wb = q[:, rows], w[:, rows]
+    kw = dict(causal=True, window=window, interpret=True)
+    parts = [flash.flash_block(qb, k[:, o:o + BLOCK], v[:, o:o + BLOCK],
+                               jnp.int32(q_off), jnp.int32(o), **kw)
+             for o in range(0, SEQ, BLOCK)]
+    m = functools.reduce(jnp.maximum, (p[1] for p in parts))
+    l = sum(p[2] * jnp.exp(p[1] - m) for p in parts)
+    out = sum(p[0] * jnp.exp(p[1] - m)[..., None] for p in parts) / l[..., None]
+    d_term = jnp.sum(wb * out, axis=-1)
+    grads = [flash.flash_block_bwd(qb, k[:, o:o + BLOCK], v[:, o:o + BLOCK],
+                                   wb, d_term, m, l, jnp.int32(q_off),
+                                   jnp.int32(o), **kw)
+             for o in range(0, SEQ, BLOCK)]
+    return (out, sum(g[0] for g in grads),
+            jnp.concatenate([g[1] for g in grads], axis=1),
+            jnp.concatenate([g[2] for g in grads], axis=1))
+
+
+@pytest.mark.parametrize("window", [5, 40])
+@pytest.mark.parametrize("q_off", Q_OFFS)
+def test_window_and_groups_at_runtime_offsets(tiles_8x32, q_off, window):
+    """``flash_block`` keeps working in a ring under a window: the offsets
+    are traced values (one compiled kernel serves every ring step), and the
+    window is measured in global positions across the two K/V blocks."""
+    q, k, v, w = _grouped(4, 2)
+    rows = slice(q_off, q_off + BLOCK)
+    dense = functools.partial(reference_attention, causal=True, window=window)
+    want_out = dense(q, k, v)[:, rows]
+    dq, dk, dv = jax.grad(
+        lambda q, k, v: jnp.sum(dense(q, k, v)[:, rows] * w[:, rows]),
+        argnums=(0, 1, 2))(q, k, v)
+    got = _window_blocks(q, k, v, w, q_off, window)
+    for a, b, name in zip(got, (want_out, dq[:, rows], dk, dv),
+                          ("out", "dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
+                                   err_msg=f"{name} at q_off {q_off}")
+
+
+def _chunks_by_rows(sq, sk, q_off, k_off, window, tq, cw):
+    """[q tiles, column chunks]: whether any row of the q tile is allowed any
+    column of the chunk, marked row by row from the definition."""
+    t = q_off + np.arange(sq)
+    first = np.maximum(t - window + 1 if window else np.full(sq, k_off), k_off)
+    last = np.minimum(t, k_off + sk - 1)
+    seen = np.zeros((sq // tq, sk // cw), bool)
+    for row in np.flatnonzero(first <= last):
+        seen[row // tq, (first[row] - k_off) // cw:(last[row] - k_off) // cw + 1] = True
+    return seen
+
+
+@pytest.mark.parametrize("sq,sk,q_off,k_off,window", [
+    (16384, 16384, 0, 0, 4096),      # smallthinker-s16384-*: 252 chunks of 528
+    (16384, 16384, 0, 0, None),
+    (8192, 8192, 0, 0, 4096), (8192, 8192, 8192, 0, 4096),
+    (8192, 8192, 1000, 300, 4096), (8192, 8192, 300, 1000, 700),
+    (4096, 8192, 5000, 0, 300), (2048, 2048, 0, 0, 512), (2048, 2048, 0, 0, 1),
+])
+def test_causal_schedule_under_a_window_computes_what_is_needed(
+        sq, sk, q_off, k_off, window):
+    """At the default 512 x 2048 tiles: the chunks the live steps work on are
+    the chunks that hold an allowed pair, counted by brute force, and no copy
+    is issued for dead steps alone -- both edges."""
+    got = flash.causal_schedule(sq, sk, q_off, k_off, window)
+    needed = int(_chunks_by_rows(sq, sk, q_off, k_off, window, 512, 512).sum())
+    assert got["chunks_computed"] == got["chunks_needed"] == needed > 0
+    assert got["dead_fetching"] == 0
+    assert got["live"] + got["dead"] == got["steps"]
+    if (sq, q_off, window) == (16384, 0, 4096):
+        assert (got["live"], needed) == (84, 252)   # of 144 live and 528
+
+
+@pytest.mark.parametrize("window", [1, 5, 8, 9, 31, 32, 40, 64, 127, 128, 500])
+@pytest.mark.parametrize("q_off,k_off", [
+    (0, 0), (64, 0), (24, 0), (20, 0), (3, 40), (61, 64)])
+def test_windowed_dead_steps_keep_a_neighbours_block(q_off, k_off, window):
+    """8 x 32 tiles over a 64 x 64 block: a live step names its own block, and
+    in either grid order no run of one block index is all dead steps (but
+    where a whole block pair is dead, and the one copy a pipeline starts on)."""
+    nq, nk = BLOCK // TQ, BLOCK // TK
+    offs = (q_off, k_off)
+    qi, kj = np.meshgrid(np.arange(nq), np.arange(nk), indexing="ij")
+    live, interior = flash._causal_tile(offs, qi, kj, TQ, TK, window)
+    seen = _chunks_by_rows(BLOCK, BLOCK, q_off, k_off, window, TQ, TK)
+    np.testing.assert_array_equal(live, seen)
+    assert not (interior & ~live).any()
+    kv = flash._kv_block(offs, qi, kj, TQ, TK, np, window, nq, nk)
+    qb = flash._q_block(offs, qi, kj, TQ, TK, nq, np, window, nk)
+    np.testing.assert_array_equal(kv[live], kj[live])
+    np.testing.assert_array_equal(qb[live], qi[live])
+    fetching = max(flash._idle_fetches(kv.ravel(), live.ravel()),
+                   flash._idle_fetches(qb.T.ravel(), live.T.ravel()))
+    # rows that are wholly dead in the middle of the grid (a window narrower
+    # than the gap between the blocks) have no neighbour to borrow from
+    whole_rows_dead = (~live.any(axis=1)).sum() + (~live.any(axis=0)).sum()
+    assert fetching <= (1 if not live.any() else whole_rows_dead)
+    if live.all(axis=None) or (live.any(axis=1).all() and live.any(axis=0).all()):
+        assert fetching == 0
+
+
+def test_heads_and_window_are_checked():
+    q, k, v, _ = _grouped(4, 3, seq=16)
+    with pytest.raises(ValueError, match="multiple"):
+        flash_attention(q, k, v, interpret=True)
+    q, k, v, _ = _grouped(4, 2, seq=16)
+    with pytest.raises(ValueError, match="causal=True"):
+        flash_attention(q, k, v, causal=False, window=4, interpret=True)
+    with pytest.raises(ValueError, match="at least 1"):
+        flash_attention(q, k, v, window=0, interpret=True)
+
+
+# sha256 of the printed jaxpr of value_and_grad of ``flash_attention(q, k, v,
+# causal=True)`` -- both pallas_calls with their kernel bodies, grids and
+# index maps -- at the head layouts of the cells that had the kernels before
+# grouped heads and the window came (PR 32), taken from the parent commit's
+# ``flash.py`` under jax 0.9.0. A ``window`` of None and as many k/v heads as
+# query heads must trace to the program they traced to then: the step
+# programs of the accepted cells do not move.
+_PINNED_JAX = "0.9.0"
+_PARENT_JAXPRS = {
+    "pythia-s8192": ((1, 8192, 16, 128), 128,
+                     "81cd84cd6d968d87dfa726076ad081c4b0846873ee7a07ad2162ec4699aa3452"),
+    "pythia-s2048x4": ((4, 2048, 16, 128), 128,
+                       "c88260c691d57893ff051bdc9f88008b055f0881e646250563e97e3b5c6af6f2"),
+    "joyai-s8192": ((1, 8192, 32, 192), 128,
+                    "ceb9478d73301d3d373f0b081ed9fe1d81221462120c01ae4d14855c3021edbf"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_PARENT_JAXPRS))
+def test_plain_causal_heads_trace_to_the_parents_program(cell):
+    import hashlib
+
+    if jax.__version__ != _PINNED_JAX:
+        pytest.skip(f"the hashes are of jaxprs printed by jax {_PINNED_JAX}")
+    shape, dv, want = _PARENT_JAXPRS[cell]
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    v = jax.ShapeDtypeStruct(shape[:3] + (dv,), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    text = str(jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, q, v))
+    assert hashlib.sha256(text.encode()).hexdigest() == want

@@ -250,3 +250,25 @@ def test_the_flash_backward_compiles_for_a_v5e_at_the_cells_shapes(v5e_mesh, b, 
         text = jax.jit(lambda *a: flash.flash_block_bwd(*a, 0, 0, causal=True)).lower(
             qk, qk, v, g, stat, stat, stat).compile().as_text()
     assert text.count("tpu_custom_call") == calls
+
+
+@pytest.mark.parametrize("window", [4096, None], ids=["window-4096", "global"])
+def test_grouped_windowed_flash_compiles_for_a_v5e_at_the_cells_shape(v5e_mesh, window):
+    """smallthinker-s16384-*: 28 query heads over 4 k/v heads of 128, 16,384
+    tokens, the window layers and the global one -- forward and the one
+    backward, through the custom VJP. Mosaic takes the k/v index maps that
+    divide the head, the variants an edge tile's [lo, hi) columns are picked
+    from, and a 16 MiB resident dq beside the stack; k and v go in at 4 heads."""
+    one_chip = jax.sharding.SingleDeviceSharding(v5e_mesh.devices.flat[0])
+    shape = lambda heads: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, 16384, heads, 128), jnp.bfloat16, sharding=one_chip)
+    assert flash._dq_rows(16384, 128) == 16384
+    assert flash._bwd_vmem(16384, 128, 128) == 32 << 20
+    loss = lambda q, k, v: jnp.sum(flash.flash_attention(  # noqa: E731
+        q, k, v, causal=True, window=window).astype(jnp.float32))
+    with time_limit(300):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            shape(28), shape(4), shape(4)).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    sched = flash.causal_schedule(16384, 16384, window=window)
+    assert sched["dead_fetching"] == 0 and sched["chunks_computed"] == sched["chunks_needed"]

@@ -187,15 +187,17 @@ def _pallas_calls(jaxpr):
     return [stack for name, stack in eqns if name == "pallas_call"]
 
 
-def _flash_grad_calls(seq):
-    q = jnp.ones((1, seq, 2, 8), jnp.float32)
+def _flash_grad_calls(seq, heads=2, kv_heads=2, window=None):
+    q = jnp.ones((1, seq, heads, 8), jnp.float32)
+    kv = jnp.ones((1, seq, kv_heads, 8), jnp.float32)
 
     def loss(q, k, v):
         with jax.named_scope(optimizers.SCOPE_GRAD):
-            return jnp.sum(flash.flash_attention(q, k, v, causal=True, interpret=True) ** 2)
+            return jnp.sum(flash.flash_attention(q, k, v, causal=True, window=window,
+                                                 interpret=True) ** 2)
 
-    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
-    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q)
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv)
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv)
     return list(_pallas_calls(jaxpr.jaxpr)), lowered.as_text(debug_info=True)
 
 
@@ -217,6 +219,27 @@ def test_each_flash_kernel_is_one_pallas_call_under_its_scope(scope):
     # the scope is around the kernel alone: the call is its direct child
     assert stack.rstrip("/").endswith(scope)
     assert scope in text
+
+
+def test_the_group_sum_of_dk_and_dv_is_outside_the_backwards_scope():
+    """Grouped-query heads under a window: still two calls, each the direct
+    child of its scope, and the sum of a group's per-q-head dk/dv (a
+    ``reduce_sum`` in XLA) stands under neither scope -- ``_pallas_calls``
+    refuses anything but the kernel there -- so the scope times the kernel
+    alone and the sum is counted with the model's other ops."""
+    calls, text = _flash_grad_calls(16, heads=4, kv_heads=2, window=4)
+    assert len(calls) == 2
+    for scope in (flash.SCOPE_FWD, flash.SCOPE_DKV):
+        (stack,) = [s for s in calls if scope in s.split("/")]
+        assert stack.rstrip("/").endswith(scope)
+    jaxpr = jax.make_jaxpr(lambda *a: flash.flash_block_bwd(
+        *a, 0, 0, causal=True, window=4, interpret=True))(
+            jnp.ones((1, 16, 4, 8)), jnp.ones((1, 16, 2, 8)), jnp.ones((1, 16, 2, 8)),
+            jnp.ones((1, 16, 4, 8)), *(jnp.ones((1, 16, 4)),) * 3)
+    sums = [stack for name, stack in _eqns(jaxpr.jaxpr) if name == "reduce_sum"]
+    assert len(sums) == 2 and not any(flash.SCOPE_DKV in s.split("/") for s in sums)
+    assert [v.aval.shape for v in jaxpr.jaxpr.outvars] == [
+        (1, 16, 4, 8), (1, 16, 2, 8), (1, 16, 2, 8)]
 
 
 def test_a_walked_flash_backward_is_one_call_a_row_block(dq_budget_of_one_q_tile):
