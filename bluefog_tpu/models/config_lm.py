@@ -342,14 +342,16 @@ def _sowed(intermediates, name: str) -> list:
 
 def moe_counters(intermediates) -> dict:
     """The expert layers' counters of one forward pass, from the collection a
-    ``mutable=["intermediates"]`` apply returns: ``rows_routed`` and
-    ``rows_overflowed`` summed over the layers, ``load_max_over_mean`` the
-    largest of them. Empty for a model without expert layers."""
+    ``mutable=["intermediates"]`` apply returns: ``rows_routed``,
+    ``rows_overflowed`` and ``tiles_in_use`` summed over the layers,
+    ``load_max_over_mean`` the largest of them. Empty for a model without
+    expert layers."""
     layers = _sowed(intermediates, "moe_counters")
     if not layers:
         return {}
     return {"rows_routed": sum(c["rows_routed"] for c in layers),
             "rows_overflowed": sum(c["rows_overflowed"] for c in layers),
+            "tiles_in_use": sum(c["tiles_in_use"] for c in layers),
             "load_max_over_mean": jnp.max(jnp.stack(
                 [c["load_max_over_mean"] for c in layers]))}
 

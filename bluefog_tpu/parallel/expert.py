@@ -17,9 +17,12 @@ the dense oracle (``SwitchFFN``'s plain ``__call__``).
 deployed (top-k of hundreds of experts, sigmoid scores with a choice-only
 bias, a shared expert, no token dropped): it is told which experts it holds,
 scores all of them, and computes its own experts' part of the result with
-grouped matrix products over ragged per-expert row counts. On one chip it
-runs without an exchange; the all-to-all that would bring other chips'
-tokens is not here.
+grouped matrix products over ragged per-expert row counts. Rows enter the
+held experts' buffer and leave it through a pair of movers (:func:`rows_in`,
+:func:`rows_out`) that walk the buffer in chunks of whole tiles and stop with
+the last tile in use, as the grouped products do: the buffer's size costs its
+zero fills, not passes over it. On one chip it runs without an exchange; the
+all-to-all that would bring other chips' tokens is not here.
 """
 
 from __future__ import annotations
@@ -37,6 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..runtime import metrics
 from .flash import _dot_prec, _tile, _vma
 
 
@@ -349,7 +353,7 @@ def ep_apply(params, x, mesh: Mesh, capacity_factor: float = 2.0,
 # ---------------------------------------------------------------------------
 
 # ``jax.named_scope``s a trace reducer finds the layer's parts by
-SCOPE_ROUTE = "bf.moe.route"      # scores, top-k, sort, gather, weighted scatter back
+SCOPE_ROUTE = "bf.moe.route"      # scores, top-k, sort, the row movers in and out
 SCOPE_EXPERTS = "bf.moe.experts"  # the grouped products
 SCOPE_SHARED = "bf.moe.shared"    # the shared expert, computed on every chip in full
 # the flax collection of what routing keeps beside the parameters: the bias
@@ -517,6 +521,145 @@ def _grouped_matmul_bwd(interpret, res, g):
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
+# Tiles a trip of the row movers' loops walks: 1,024 rows. Small against the
+# rows a layer routes here (the rounding to whole chunks stays a few per cent
+# of them), large enough that a trip's gather or scatter moves megabytes.
+CHUNK_TILES = 8
+
+
+def chunk_tiles(rows: int) -> int:
+    """Tiles of a chunk of a buffer of ``rows`` rows: ``CHUNK_TILES``, or the
+    whole of a smaller buffer."""
+    return min(CHUNK_TILES, rows // ROW_TILE)
+
+
+def _fill(shape, dtype, *like):
+    """Zeros for a mover's loop to carry: varying over the mesh axes the
+    operands ``like`` vary over (inside ``shard_map``), as the body's result
+    will be, or the carry's type would not match its body's."""
+    zeros = jnp.zeros(shape, dtype)
+    vma = _vma(*like).get("vma")
+    return lax.pcast(zeros, tuple(vma), to="varying") if vma else zeros
+
+
+def _walk(tiles_used, rows: int, body, init):
+    """``body(start, n, fresh, carry) -> carry`` for each chunk (rows ``[start,
+    start + n)``) of a ``rows``-row buffer, up to the chunk that holds tile
+    ``tiles_used - 1``: a loop with a run-time trip count, so the chunks past
+    the tiles in use cost nothing and ``carry`` keeps there what ``init`` held.
+    The last chunk of a buffer that is no whole number of chunks starts early
+    and overlaps the one before it; ``fresh`` ``[n]`` marks its rows no earlier
+    chunk has walked. (A chunk that divides the buffer would need neither, but
+    a buffer has ``bound / ROW_TILE + held`` tiles, which can be prime.)"""
+    n = chunk_tiles(rows) * ROW_TILE
+    trips = lax.div(tiles_used[0] * ROW_TILE + (n - 1), n)
+
+    def trip(i, carry):
+        start = jnp.minimum(i * n, rows - n)
+        return body(start, n, start + jnp.arange(n) >= i * n, carry)
+
+    return lax.fori_loop(0, trips, trip, init)
+
+
+def rows_in(xt, token, valid, tiles_used):
+    """The gather into the held experts' buffer: row ``i`` of the result
+    ``[rows, d]`` is ``xt[token[i]]`` where ``valid[i]`` and zero elsewhere,
+    for the rows of the first ``tiles_used`` tiles (``token``, ``valid``,
+    ``tiles_used`` as :func:`dispatch_held` gives them); the rows past them
+    are zero. Walked in chunks of :func:`chunk_tiles` tiles that stop with the
+    last tile in use, here and in the gradient (the scatter-add of the rows'
+    cotangent into ``[T, d]``, summed in float32)."""
+    return _rows_in(xt.shape[0], xt, token, valid, tiles_used)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _rows_in(tokens: int, xt, token, valid, tiles_used):
+    del tokens  # the gradient's row count
+
+    def chunk(start, n, fresh, out):
+        del fresh  # a row walked twice is written the same twice
+        rows = jnp.where(lax.dynamic_slice_in_dim(valid, start, n)[:, None],
+                         xt[lax.dynamic_slice_in_dim(token, start, n)], 0)
+        return lax.dynamic_update_slice_in_dim(out, rows, start, axis=0)
+
+    rows = token.shape[0]
+    return _walk(tiles_used, rows, chunk, _fill((rows, xt.shape[1]), xt.dtype, xt, token))
+
+
+def _rows_in_fwd(tokens, xt, token, valid, tiles_used):
+    return _rows_in(tokens, xt, token, valid, tiles_used), (token, valid, tiles_used)
+
+
+def _rows_in_bwd(tokens, res, g):
+    token, valid, tiles_used = res
+
+    def chunk(start, n, fresh, acc):
+        keep = lax.dynamic_slice_in_dim(valid, start, n) & fresh
+        return acc.at[lax.dynamic_slice_in_dim(token, start, n)].add(
+            jnp.where(keep[:, None], lax.dynamic_slice_in_dim(g, start, n), 0).astype(jnp.float32))
+
+    acc = _walk(tiles_used, g.shape[0], chunk,
+                _fill((tokens, g.shape[1]), jnp.float32, g, token))
+    return acc.astype(g.dtype), None, None, None
+
+
+_rows_in.defvjp(_rows_in_fwd, _rows_in_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def rows_out(y, row_weight, token, tiles_used, tokens: int):
+    """The weighted scatter back: ``[tokens, d]`` in ``y``'s type, row ``t`` the
+    float32 sum of ``row_weight[i] * y[i]`` over the buffer's rows ``i`` with
+    ``token[i] == t`` (``row_weight`` is zero on padding), taken over the first
+    ``tiles_used`` tiles in chunks of :func:`chunk_tiles` tiles.
+
+    **``y`` has to be zero past the tiles in use**, as :func:`grouped_matmul`
+    leaves its result: the value does not read those rows, but the gradient
+    walks the same chunks and writes ``d_y`` over ``y``, which it needs no
+    longer (no second buffer, no fill) -- ``d_y = g[token] * row_weight`` up to
+    the last chunk in use and ``y``'s own rows past it, which are the zeros
+    ``d_y`` holds there only if ``y`` did. ``d_row_weight = sum(g[token] * y,
+    -1)``."""
+    def chunk(start, n, fresh, acc):
+        weight = jnp.where(fresh, lax.dynamic_slice_in_dim(row_weight, start, n), 0)
+        return acc.at[lax.dynamic_slice_in_dim(token, start, n)].add(
+            lax.dynamic_slice_in_dim(y, start, n).astype(jnp.float32) * weight[:, None])
+
+    return _walk(tiles_used, y.shape[0], chunk,
+                 _fill((tokens, y.shape[1]), jnp.float32, y, row_weight, token)).astype(y.dtype)
+
+
+def _rows_out_fwd(y, row_weight, token, tiles_used, tokens):
+    return rows_out(y, row_weight, token, tiles_used, tokens), (y, row_weight, token, tiles_used)
+
+
+def _rows_out_bwd(tokens, res, g):
+    del tokens
+    y, row_weight, token, tiles_used = res
+
+    def chunk(start, n, fresh, carry):
+        d_y, d_weight = carry
+        rows = g[lax.dynamic_slice_in_dim(token, start, n)].astype(jnp.float32)
+        # d_y starts as y: a chunk's rows of y are read here, then written over;
+        # a row walked twice holds d_y by then, and keeps its first sum
+        d_row = jnp.sum(rows * lax.dynamic_slice_in_dim(d_y, start, n).astype(jnp.float32),
+                        axis=-1)
+        d_weight = lax.dynamic_update_slice_in_dim(
+            d_weight, jnp.where(fresh, d_row, lax.dynamic_slice_in_dim(d_weight, start, n)),
+            start, axis=0)
+        weight = lax.dynamic_slice_in_dim(row_weight, start, n)
+        d_y = lax.dynamic_update_slice_in_dim(
+            d_y, (rows * weight[:, None]).astype(y.dtype), start, axis=0)
+        return d_y, d_weight
+
+    d_y, d_weight = _walk(tiles_used, y.shape[0], chunk,
+                          (y, _fill(row_weight.shape, jnp.float32, y, row_weight, token, g)))
+    return d_y, d_weight.astype(row_weight.dtype), None, None
+
+
+rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
+
+
 def routed_rows_bound(tokens: int, experts_per_token: int, held: int,
                       num_experts: int) -> int:
     """How many of a step's (token, chosen expert) slots the held experts'
@@ -526,7 +669,19 @@ def routed_rows_bound(tokens: int, experts_per_token: int, held: int,
     bounded, and what exceeds it is counted as overflow. Four, not two: with
     seeded initial weights the tokens' hidden states share a component, the
     router's choice is skewed by it, and the fullest of 32 shares of 8 experts
-    was measured at up to 2.84 times the uniform share (PERF.md section 6, PR 27)."""
+    was measured at up to 2.84 times the uniform share (PERF.md section 6, PR 27).
+    The bound sizes the buffer, and the buffer's size costs memory and zero
+    fills only: the row movers and the grouped products stop with the tiles in
+    use (:func:`rows_in`, :func:`rows_out`, :func:`grouped_matmul`). What the
+    movers cost follows ``tiles_in_use / moe.buffer_tiles``, and not in the
+    movers' favour everywhere: XLA:TPU adds a chunk's rows into ``[T, d]`` one
+    row at a time and a whole buffer's after one sort, so the chunked walk is
+    the cheaper one while under about 45 % of the buffer's tiles are in use,
+    costs the same there, and costs more past it (with the buffer full a
+    layer's movers take 1.75 times the whole-buffer expressions they replaced;
+    PERF.md section 6, PR 33). A deployment whose held experts fill more than
+    that half of the bound, step after step, wants a wider bound or the gather
+    form of the two scatters described there."""
     slots = tokens * experts_per_token
     return min(slots, -(-4 * slots * held // num_experts))
 
@@ -580,7 +735,9 @@ def dispatch_held(ids, held: Tuple[int, int], bound: int):
     scalars -- ``rows_routed`` (slots that chose a held expert),
     ``load_max_over_mean`` (the fullest held expert's rows over the mean),
     ``rows_overflowed`` (slots past ``bound``, cut from the end of the expert
-    order: their contribution is lost, and this is where it shows)."""
+    order: their contribution is lost, and this is where it shows),
+    ``tiles_in_use`` (``tiles_used``: where the grouped products and the row
+    movers stop)."""
     lo, hi = held
     n_held = hi - lo
     rows = buffer_rows(bound, n_held)
@@ -608,6 +765,7 @@ def dispatch_held(ids, held: Tuple[int, int], bound: int):
         "rows_routed": routed,
         "load_max_over_mean": jnp.max(sizes) * n_held / jnp.maximum(routed, 1).astype(jnp.float32),
         "rows_overflowed": routed - jnp.sum(kept),
+        "tiles_in_use": tile_ends[-1],
     }
     return slot, valid, tile_expert, tile_ends[-1:].astype(jnp.int32), counters
 
@@ -627,8 +785,13 @@ class RoutedExperts(nn.Module):
     builds no shared expert. Rows routed here are gathered in expert order
     into a buffer of a static number of rows (:func:`routed_rows_bound`,
     :func:`buffer_rows`); per-expert counts are ragged inside it, so imbalance
-    between experts costs nothing, and the grouped products do work only for
-    the tiles in use (:func:`grouped_matmul`).
+    between experts costs nothing, and all work on the buffer is done for the
+    tiles in use only: the grouped products skip the others
+    (:func:`grouped_matmul`), and the gather into the buffer, the weighted
+    scatter back and both gradients (:func:`rows_in`, :func:`rows_out`) walk
+    it in chunks of :func:`chunk_tiles` tiles and stop with the last tile in
+    use. Past it the buffer holds its zero fill. The gauges
+    ``moe.buffer_tiles`` and ``moe.chunk_tiles`` are set while tracing.
 
     The bias is no parameter: it gets no gradient and lives in the
     ``"routing"`` collection (``model_state`` of the ``bf`` optimizers, as
@@ -704,23 +867,23 @@ class RoutedExperts(nn.Module):
             bound = routed_rows_bound(t, k, n_held, self.num_experts)
             slot, valid, tile_expert, tiles_used, counters = dispatch_held(
                 ids, self.held, bound)
+            metrics.gauge("moe.buffer_tiles").set(slot.shape[0] // ROW_TILE)
+            metrics.gauge("moe.chunk_tiles").set(chunk_tiles(slot.shape[0]))
             token = lax.div(slot, k)                                # of each row
-            gathered = jnp.where(valid[:, None], xt[token], 0)      # [rows, d]
+            gathered = rows_in(xt, token, valid, tiles_used)        # [rows, d]
             row_weight = jnp.where(valid, weights.reshape(-1)[slot], 0.0)
         with jax.named_scope(SCOPE_EXPERTS):
             mm = lambda rows, w: grouped_matmul(  # noqa: E731
                 rows, w.astype(self.dtype), tile_expert, tiles_used, self.interpret)
             y = mm(act(mm(gathered, gate)) * mm(gathered, up), down)  # [rows, d]
         with jax.named_scope(SCOPE_ROUTE):
-            routed = jnp.zeros((t, d), jnp.float32).at[token].add(
-                y.astype(jnp.float32) * row_weight[:, None])
+            routed = rows_out(y, row_weight, token, tiles_used, t)  # [t, d]
         if self.n_shared:
             with jax.named_scope(SCOPE_SHARED):
                 shared = SwiGLU(self.n_shared * self.d_ff, self.dtype, name="shared")(xt)
         self.sow("intermediates", "moe_counters", counters)
         self.sow("intermediates", "moe_choice", ids.reshape(leading + (k,)))
-        out = routed.astype(self.dtype)
-        return (out + shared if self.n_shared else out).reshape(leading + (d,)).astype(x.dtype)
+        return (routed + shared if self.n_shared else routed).reshape(leading + (d,)).astype(x.dtype)
 
 
 class SwiGLU(nn.Module):

@@ -549,6 +549,12 @@ _HELP_EXACT: Dict[str, str] = {
                       "traced (Hq / Hkv; 1 without grouped-query heads): "
                       "k and v are read through the block specs, never "
                       "repeated",
+    "moe.buffer_tiles": "128-row tiles of the held experts' buffer of the "
+                        "last RoutedExperts layer traced "
+                        "(expert.buffer_rows / ROW_TILE)",
+    "moe.chunk_tiles": "tiles a trip of that layer's row movers walks "
+                       "(expert.chunk_tiles): the movers stop after the "
+                       "chunk that holds tile tiles_in_use - 1",
     "trace.requests": "serve requests traced into the flight ring "
                       "(BLUEFOG_TRACE_SERVE; docs/slo.md)",
 }
@@ -578,7 +584,7 @@ _HELP_PREFIX = (
 # segment). The bfcheck [metrics] analyzer enforces this plus HELP
 # resolution for every creation site in the package — a new family must
 # be added here (with curated HELP coverage) before it can ship.
-_PREFIX_FAMILIES = ("alert", "cp", "flash", "hb", "membership", "opt", "pushsum",
+_PREFIX_FAMILIES = ("alert", "cp", "flash", "hb", "membership", "moe", "opt", "pushsum",
                     "serve", "slo", "trace", "tune", "watchdog", "win")
 
 

@@ -24,12 +24,14 @@ benchmarks, with random weights from a seed:
   ``bf.DistributedNeighborAllreduceOptimizer.step``.
 * ``mla_moe``: the kernels again at latent attention's widths (q.k 192, v
   128); the three compiled kernels of ``grouped_matmul`` (the product and both
-  gradients) against XLA matmuls on ragged loads with an empty expert; then
+  gradients) against XLA matmuls on ragged loads with an empty expert, and the
+  row movers on both sides of them (``rows_in``, ``rows_out``, forward and
+  gradients) against the plain gather and scatter-add on the same loads; then
   ``bf.models.ConfigLM`` at JoyAI-LLM-Flash's widths (one dense and one expert
   layer and the MTP module, experts [0, 8) of 256 held, top-8) at 8192 tokens
   per chip through the same optimizer with the routing biases as its model
-  state: the expert layers' counters of the last step are printed, and an
-  overflowed row raises.
+  state: the expert layers' counters of the last step are printed
+  (``tiles_in_use`` among them), and an overflowed row raises.
 
 It refuses to start unless every rank is a TPU device, and a failing phase
 raises (nothing is caught). A run that passed ends with two JSON lines on
@@ -101,6 +103,7 @@ MLA_MOE = LMConfig(
 # rows of each held expert in the grouped products' check: ragged, one empty,
 # one of a single row, one of exactly a tile
 GROUPED_LOADS = (700, 0, 130, 1, 300, 128, 5, 900)
+GROUPED_SLOTS = sum(GROUPED_LOADS) + 1000    # and 1,000 slots held elsewhere
 KERNEL_TOL = 3e-2
 FLASH = partial(flash_attention, causal=True)
 
@@ -364,19 +367,72 @@ def phase_lm_flash():
     return out
 
 
+def _grouped_dispatch(key):
+    """``GROUPED_LOADS`` slots on the held experts and 1,000 held elsewhere, one
+    a token, shuffled: ``(slot, valid, tile_expert, tiles_used)`` of a buffer
+    with tiles to spare."""
+    held = len(GROUPED_LOADS)
+    ids = np.concatenate([np.full(count, e) for e, count in enumerate(GROUPED_LOADS)]
+                         + [np.full(GROUPED_SLOTS - sum(GROUPED_LOADS), held + 3)])
+    ids = jax.random.permutation(key, jnp.asarray(ids, jnp.int32))[:, None]
+    slot, valid, tile_expert, used, counters = expert.dispatch_held(ids, (0, held), 4096)
+    tiles = sum(max(-(-count // expert.ROW_TILE), 1) for count in GROUPED_LOADS)
+    if (int(counters["rows_routed"]) != sum(GROUPED_LOADS) or int(counters["rows_overflowed"])
+            or int(counters["tiles_in_use"]) != tiles):
+        raise RuntimeError(f"dispatch_held miscounted {GROUPED_LOADS}: {counters}")
+    return slot, valid, tile_expert, used
+
+
+def _check_row_movers():
+    """The compiled row movers and their gradients at the expert layer's width
+    against the plain expressions they replace -- a gather under the rows'
+    mask, a weighted scatter-add, autodiff's transposes -- on the same ragged
+    loads: the loops stop after 3 chunks of 8 tiles of the buffer's 5."""
+    d = MLA_MOE.hidden_size
+    keys = jax.random.split(jax.random.PRNGKey(6), 5)
+    token, valid, _, used = _grouped_dispatch(keys[0])       # one slot a token
+    x = jax.random.normal(keys[1], (GROUPED_SLOTS, d), jnp.bfloat16)
+    # stands for the experts' result: anything on padding rows, zero past the tiles in use
+    extra = jnp.where(jnp.arange(token.shape[0])[:, None] < used[0] * expert.ROW_TILE,
+                      jax.random.normal(keys[2], (token.shape[0], d), jnp.bfloat16), 0)
+    weight = jax.random.uniform(keys[3], (GROUPED_SLOTS,), jnp.float32, 0.5, 1.5)
+    cot = jax.random.normal(keys[4], (GROUPED_SLOTS, d), jnp.float32)
+
+    def moved(x, extra, weight):
+        y = expert.rows_in(x, token, valid, used) + extra
+        return expert.rows_out(y, jnp.where(valid, weight[token], 0.0), token, used,
+                               GROUPED_SLOTS).astype(jnp.float32)
+
+    def plain(x, extra, weight):
+        y = jnp.where(valid[:, None], x[token], 0) + extra
+        return jnp.zeros((GROUPED_SLOTS, d), jnp.float32).at[token].add(
+            y.astype(jnp.float32) * jnp.where(valid, weight[token], 0.0)[:, None]
+        ).astype(jnp.bfloat16).astype(jnp.float32)
+
+    def with_gradients(fn):
+        grad = jax.grad(lambda *args: jnp.sum(fn(*args) * cot), argnums=(0, 1, 2))
+        return (jax.jit(fn)(x, extra, weight),) + jax.jit(grad)(x, extra, weight)
+
+    err = {}
+    for name, a, b in zip(("out", "d_x", "d_y", "d_weight"),
+                          with_gradients(moved), with_gradients(plain)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        # one row a token, so no sum's order differs: the two differ where
+        # XLA rounds y to bfloat16 on its way into the product
+        np.testing.assert_allclose(a, b, atol=1e-2 * np.max(np.abs(b)), rtol=1e-2,
+                                   err_msg=f"row movers {name} vs XLA")
+        err[name] = round(float(np.max(np.abs(a - b)) / np.max(np.abs(b))), 5)
+    return err
+
+
 def _check_grouped_matmul():
     """The compiled grouped product, its rows' gradient and its weights'
     gradient at the expert layer's widths against one XLA matmul per expert
     under its rows' mask, on ``GROUPED_LOADS`` in a buffer with tiles to spare."""
     d, f, held = MLA_MOE.hidden_size, MLA_MOE.moe_intermediate_size, len(GROUPED_LOADS)
     keys = jax.random.split(jax.random.PRNGKey(5), 4)
-    ids = np.concatenate([np.full(count, e) for e, count in enumerate(GROUPED_LOADS)]
-                         + [np.full(1000, held + 3)])           # and slots held elsewhere
-    ids = jax.random.permutation(keys[0], jnp.asarray(ids, jnp.int32))[:, None]
-    slot, valid, tile_expert, used, counters = expert.dispatch_held(ids, (0, held), 4096)
-    if int(counters["rows_routed"]) != sum(GROUPED_LOADS) or int(counters["rows_overflowed"]):
-        raise RuntimeError(f"dispatch_held miscounted {GROUPED_LOADS}: {counters}")
-    x = jax.random.normal(keys[1], (ids.shape[0], d), jnp.bfloat16)
+    slot, valid, tile_expert, used = _grouped_dispatch(keys[0])
+    x = jax.random.normal(keys[1], (GROUPED_SLOTS, d), jnp.bfloat16)
     rows = jnp.where(valid[:, None], x[slot], 0)
     weights = (jax.random.normal(keys[2], (held, d, f), jnp.float32) / np.sqrt(d)).astype(
         jnp.bfloat16)
@@ -426,6 +482,7 @@ def phase_mla_moe():
         d_v=MLA_MOE.v_head_dim,
         d_qk=MLA_MOE.qk_nope_head_dim + MLA_MOE.qk_rope_head_dim)
     grouped_err = _check_grouped_matmul()
+    movers_err = _check_row_movers()
     model = ConfigLM(MLA_MOE, dtype=jnp.bfloat16, attn_fn=FLASH)
     variables = jax.jit(lambda k: model.init(
         k, jnp.zeros((1, LM_SEQ), jnp.int32)))(jax.random.PRNGKey(0))
@@ -452,6 +509,7 @@ def phase_mla_moe():
     out = _report(compile_s, steady, losses)
     out["kernel_max_abs_err"] = kernel_err
     out["grouped_matmul_max_rel_err"] = grouped_err
+    out["row_movers_max_rel_err"] = movers_err
     out["moe_counters_per_rank"] = counters
     return out
 

@@ -481,6 +481,15 @@ def test_the_toy_smallthinker_through_opt_step_matches_the_plain_reference(gqa_t
             assert _rel(got, want) <= GRAD_RTOL + 1e-4, (jax.tree_util.keystr(path), rank)
     aux = jax.device_get(metrics["aux"])
     assert np.all(aux["rows_overflowed"] == 0) and np.all(aux["rows_routed"] > 0)
+    # where the row movers and the grouped products stopped, summed over the layers
+    _, state = GQA.model(cfg).apply({"params": params}, batch[0], mutable=["intermediates"])
+    k, held = cfg["moe_num_active_primary_experts"], cfg["moe_num_primary_experts"]
+    bound = expert.routed_rows_bound(batch[0].size, k, held,
+                                     cfg["published"]["moe_num_primary_experts"])
+    used = [expert.dispatch_held(ids.reshape(-1, k), GQA.held_range(cfg), bound)[3]
+            for ids in moe_choices(state["intermediates"])]
+    assert len(used) == 8 and np.all(aux["tiles_in_use"] == int(sum(used)[0]))
+    assert 8 * held <= int(sum(used)[0]) <= 8 * expert.buffer_rows(bound, held) // expert.ROW_TILE
 
 
 def _gqa_bf16_parameters(cfg, params):
