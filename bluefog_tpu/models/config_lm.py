@@ -3,9 +3,10 @@
 Where :class:`~bluefog_tpu.models.TransformerLM` fixes its block in code, this
 one takes an :class:`LMConfig` -- the keys of a published ``config.json``, of
 the DeepSeek-V3 kind (latent attention, a biased sigmoid router, a shared
-expert, MTP) or of the grouped-query kind (SmallThinker: k/v heads shared by
+expert, MTP), of the grouped-query kind (SmallThinker: k/v heads shared by
 a group of query heads, layers that differ in mask and rotary, a router that
-reads the block's input) -- and builds, layer by layer:
+reads the block's input) or of the looped kind (Ouro: one stack of layers run
+``total_ut_steps`` times on the same weights) -- and builds, layer by layer:
 
   attention  ``"latent"``: multi-head latent attention (two low-rank paths with
              an RMSNorm on each latent, a no-rope part per head and one rope
@@ -35,16 +36,44 @@ reads the block's input) -- and builds, layer by layer:
   MTP        ``num_nextn_predict_layers`` multi-token-prediction modules after
              the last layer, sharing the embedding and the head.
 
-Every norm is an RMSNorm with a learned scale, there are no biases, and the
-residuals are sequential. Parameters are float32; ``dtype`` is the compute
-type; router scores and the top-k are float32 whatever it is.
+and around the layers:
+
+  the loop   ``total_ut_steps`` R > 1 runs the stack R times over the same
+             ``layer_i`` submodules (the parameter tree has L layers, not
+             R x L: a weight's gradient is the sum over its R uses). After
+             every pass the one ``final_norm`` is applied and its output is
+             what the next pass reads; the one head reads it too.
+  sandwich   ``sandwich_norms``: a second norm on what attention returns
+             (``attn_out_norm``) and on what the FFN returns
+             (``ffn_out_norm``), each before its residual add: four norms a
+             layer.
+  exit gate  ``exit_gate``: one ``Linear(hidden -> 1)`` with a bias, shared by
+             the passes, gives a float32 logit a token and pass from the
+             normed state; :func:`exit_distribution` turns the R logits into
+             the probability of leaving after each pass and
+             :func:`looped_exit_loss` is the expected-exit objective.
+  recompute  ``remat_layers``: every layer application runs under
+             ``jax.checkpoint`` (flax's ``nn.remat`` of :class:`Layer`): its
+             input is all the backward pass keeps of it, and the application
+             -- the forward flash kernel included -- runs again there. Memory
+             is then state for L layers beside R x L saved inputs, where the
+             un-recomputed loop holds R x L applications' activations.
+
+Every norm is an RMSNorm with a learned scale, there are no biases but the
+gate's, and the residuals are sequential. Parameters are float32; ``dtype`` is
+the compute type; router scores, the top-k and the gate are float32 whatever it
+is.
 
 The parts run under ``jax.named_scope``s a trace reducer can find them by:
 ``bf.mla.proj`` (latent and equal-width attention outside its kernels:
 projections, latent norms and rope) or ``bf.attn.proj`` (the same of the
 grouped kind), the two ``bf.flash.*`` of the attention function,
 ``bf.moe.route`` / ``bf.moe.experts`` / ``bf.moe.shared``, ``bf.ffn.dense``,
-``bf.lm.head`` and ``bf.mtp`` around a whole MTP module.
+``bf.lm.head`` (the final norm, the gate, the head and its loss), ``bf.mtp``
+around a whole MTP module and, in a looped or gated model only, ``bf.loop.<t>``
+(t from 0) around everything pass t runs, outside the others. Gauges set while
+tracing (docs/metrics.md): ``loop.passes``, ``loop.layer_applications`` (R x L),
+``loop.recomputed``.
 """
 
 from __future__ import annotations
@@ -60,12 +89,14 @@ from flax import linen as nn
 
 from ..parallel.context import reference_attention
 from ..parallel.expert import ROUTING, SCOPE_ROUTE, RoutedExperts, SwiGLU
+from ..runtime import metrics
 
 SCOPE_MLA_PROJ = "bf.mla.proj"
 SCOPE_ATTN_PROJ = "bf.attn.proj"   # the grouped kind's, under a name of its own
 SCOPE_DENSE_FFN = "bf.ffn.dense"
 SCOPE_HEAD = "bf.lm.head"
 SCOPE_MTP = "bf.mtp"
+SCOPE_LOOP = "bf.loop."            # + the pass, from 0: looped models only
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,6 +136,21 @@ class LMConfig:
     router_input: str = "ffn"            # "ffn" | "block": the attention's normed input
     bias_update_speed: float = 0.0       # the balancing rule's step; 0: the bias stays
     num_nextn_predict_layers: int = 0
+    total_ut_steps: int = 1              # passes over the one stack of layers
+    sandwich_norms: bool = False         # a norm on attention's and the FFN's output too
+    exit_gate: bool = False              # Linear(hidden -> 1) + bias on every pass's state
+    remat_layers: bool = False           # jax.checkpoint around every layer application
+
+    def __post_init__(self):
+        # the loop, the gate and the output norms are the dense block's: an
+        # expert layer sows one set of counters and keeps one routing bias a
+        # step, and an MTP module reads the un-normed trunk
+        looped = self.total_ut_steps > 1 or self.exit_gate
+        if ((looped or self.sandwich_norms) and self.n_routed_experts) or (
+                looped and self.num_nextn_predict_layers):
+            raise ValueError("a looped stack (total_ut_steps > 1, exit_gate) or sandwich norms "
+                             "with expert layers, or a looped stack with MTP modules, is not "
+                             "supported")
 
     @classmethod
     def from_dict(cls, doc: dict, **overrides) -> "LMConfig":
@@ -203,8 +249,10 @@ class Attention(nn.Module):
 
 
 class Layer(nn.Module):
-    """One pre-norm block: attention, then a dense SwiGLU or the expert layer.
-    ``attn_fn`` is the layer's own (its window bound, if it has one)."""
+    """One pre-norm block: attention, then a dense SwiGLU or the expert layer;
+    with ``sandwich_norms`` (dense blocks) each part's output is normed too
+    before its residual add. ``attn_fn`` is the layer's own (its window bound, if it has
+    one)."""
 
     cfg: LMConfig
     experts: bool
@@ -227,7 +275,11 @@ class Layer(nn.Module):
             with jax.named_scope(SCOPE_ROUTE):
                 router_logits = jnp.dot(h.astype(jnp.float32), router,
                                         precision=jax.lax.Precision.HIGHEST)
-        x = x + Attention(cfg, self.dtype, self.attn_fn, self.rotary, name="attn")(h, positions)
+        a = Attention(cfg, self.dtype, self.attn_fn, self.rotary, name="attn")(h, positions)
+        if cfg.sandwich_norms:
+            with jax.named_scope(cfg.proj_scope):
+                a = norm(name="attn_out_norm")(a)
+        x = x + a
         if self.experts:
             h = norm(name="ffn_norm")(x)
             return x + RoutedExperts(
@@ -239,7 +291,8 @@ class Layer(nn.Module):
                     h, choice, router_logits)
         with jax.named_scope(SCOPE_DENSE_FFN):
             h = norm(name="ffn_norm")(x)
-            return x + SwiGLU(cfg.intermediate_size, self.dtype, name="ffn")(h)
+            m = SwiGLU(cfg.intermediate_size, self.dtype, name="ffn")(h)
+            return x + (norm(name="ffn_out_norm")(m) if cfg.sandwich_norms else m)
 
 
 class ConfigLM(nn.Module):
@@ -261,6 +314,13 @@ class ConfigLM(nn.Module):
     and its choice (``mutable=["intermediates"]``; :func:`moe_counters`) and
     keeps its routing bias in the ``"routing"`` collection, which ``init``
     returns beside ``"params"`` and ``apply`` takes beside them.
+
+    A looped model (``total_ut_steps`` R > 1) gives the last pass's logits;
+    with ``all_passes=True`` it gives ``(states [R, B, S, d], gate_logits
+    [R, B, S])`` instead -- every pass's normed state and, with an exit gate,
+    its float32 logit (else ``None``) -- and leaves the head to the caller
+    (:meth:`head`), so that one pass's ``[T, V]`` logits at a time need be
+    alive (:func:`looped_exit_loss`).
     """
 
     cfg: LMConfig
@@ -274,7 +334,9 @@ class ConfigLM(nn.Module):
         attn = self.attn_fn or partial(reference_attention, causal=True)
         norm = partial(nn.RMSNorm, epsilon=cfg.rms_norm_eps, dtype=self.dtype,
                        param_dtype=jnp.float32)
-        layer = partial(Layer, cfg, dtype=self.dtype, attn_fn=attn, interpret=self.interpret)
+        # recomputed, an application keeps its input and nothing else
+        layer = partial(nn.remat(Layer) if cfg.remat_layers else Layer, cfg, dtype=self.dtype,
+                        attn_fn=attn, interpret=self.interpret)
         self.embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=self.dtype,
                               param_dtype=jnp.float32)
         for i in range(cfg.num_hidden_layers):
@@ -285,6 +347,8 @@ class ConfigLM(nn.Module):
         self.final_norm = norm()
         self.lm_head = nn.Dense(cfg.vocab_size, dtype=self.dtype, param_dtype=jnp.float32,
                                 use_bias=False)
+        if cfg.exit_gate:
+            self.exit_gate = nn.Dense(1, dtype=jnp.float32, param_dtype=jnp.float32)
         # an MTP module: norms of the trunk's output and of the next token's
         # embedding, a 2d -> d projection, one block, its own final norm
         for k in range(cfg.num_nextn_predict_layers):
@@ -299,18 +363,49 @@ class ConfigLM(nn.Module):
         with jax.named_scope(SCOPE_HEAD):
             return self.lm_head(final_norm(x)).astype(jnp.float32)
 
+    def head(self, state):
+        """The logits ``[..., V]`` in float32 of a normed state ``[..., d]``
+        (a pass's, from ``all_passes=True``)."""
+        with jax.named_scope(SCOPE_HEAD):
+            return self.lm_head(state).astype(jnp.float32)
+
     def __call__(self, tokens, positions=None, next_tokens=None,
-                 choices: Optional[Sequence] = None):
+                 choices: Optional[Sequence] = None, all_passes: bool = False):
         cfg = self.cfg
         if positions is None:
             positions = jnp.arange(tokens.shape[1])
         choices = iter(choices) if choices is not None else None
         take = lambda block: next(choices) if block.experts and choices is not None else None
+        passes = cfg.total_ut_steps
+        # trace-time gauges (docs/metrics.md), of the last model traced
+        metrics.gauge("loop.passes").set(passes)
+        metrics.gauge("loop.layer_applications").set(passes * cfg.num_hidden_layers)
+        metrics.gauge("loop.recomputed").set(int(cfg.remat_layers))
+
+        def stack(x):
+            for i in range(cfg.num_hidden_layers):
+                block = getattr(self, f"layer_{i}")
+                x = block(x, positions, take(block))
+            return x
+
         x = self.embed(tokens)
-        for i in range(cfg.num_hidden_layers):
-            block = getattr(self, f"layer_{i}")
-            x = block(x, positions, take(block))
-        logits = self._head(x, self.final_norm)
+        if passes == 1 and not (cfg.exit_gate or all_passes):
+            # a plain model's path as it was: flax puts ``_head`` on its ops' paths
+            x = stack(x)
+            logits = self._head(x, self.final_norm)
+        else:
+            states, gates = [], []
+            for t in range(passes):
+                with jax.named_scope(f"{SCOPE_LOOP}{t}"):
+                    x = stack(x)
+                    with jax.named_scope(SCOPE_HEAD):
+                        x = self.final_norm(x)      # the normed state is what is fed on
+                        if cfg.exit_gate:
+                            gates.append(self.exit_gate(x.astype(jnp.float32))[..., 0])
+                    states.append(x)
+            if all_passes:
+                return jnp.stack(states), jnp.stack(gates) if gates else None
+            logits = self.head(x)
         if not cfg.num_nextn_predict_layers:
             return logits
         if next_tokens is None:
@@ -391,5 +486,64 @@ def next_token_loss(model: ConfigLM, mtp_weight: float = 0.3):
                         extra, jnp.roll(batch[2], -k, axis=1)).mean()
         return loss, (state.get(ROUTING, routing),
                       moe_counters(state.get("intermediates", {})))
+
+    return loss_fn
+
+
+def exit_distribution(gate_logits):
+    """The probability of leaving after each pass, ``[R, ...]`` in float32 and
+    its logarithm, from the gate's logits ``[R, ...]``: with lambda_t =
+    sigmoid(logit_t), ``p_t = lambda_t prod_{j<t} (1 - lambda_j)`` for t < R
+    and ``p_R = prod_{j<R} (1 - lambda_j)`` -- whoever has not left by the
+    last pass leaves there, so the R of them sum to 1 and the last pass's own
+    logit plays no part."""
+    g = gate_logits.astype(jnp.float32)
+    before = jnp.concatenate([                                   # log prod_{j<t} (1 - lambda_j)
+        jnp.zeros_like(g[:1]), jnp.cumsum(jax.nn.log_sigmoid(-g[:-1]), axis=0)])
+    log_p = jnp.concatenate([before[:-1] + jax.nn.log_sigmoid(g[:-1]), before[-1:]])
+    return jnp.exp(log_p), log_p
+
+
+def looped_exit_loss(model: ConfigLM, beta: float):
+    """``loss_fn(params, state, batch) -> (loss, (state, aux))`` for the
+    ``bf.Distributed*Optimizer``s with ``with_model_state=True`` (the state
+    is empty: ``opt.init(params, model_state={})``): the expected-exit
+    objective of a looped model with an exit gate, in float32 -- the mean over
+    tokens of ``sum_t p_t CE(head(state_t), target) - beta H(p)``, p the
+    :func:`exit_distribution` of the token's R gate logits and H its entropy.
+
+    The head and its cross-entropy run once a pass, each under
+    ``jax.checkpoint``: a pass keeps its ``[B, S]`` losses and the backward
+    pass makes its ``[T, V]`` logits again, so no more than one pass's logits
+    and their gradient are alive at a time. ``batch`` is ``(tokens,
+    targets)``. ``opt.step``'s ``metrics["aux"]`` carries ``loss_by_pass``
+    ``[R]`` (each pass's mean cross-entropy), ``exit_mass_by_pass`` ``[R]``
+    (the mean of p, summing to 1), ``exit_entropy`` (the mean of H) and
+    ``expected_exit_pass`` (the mean of ``sum_t t p_t``, passes counted from
+    1)."""
+    ce = optax.softmax_cross_entropy_with_integer_labels
+
+    @jax.checkpoint
+    def pass_loss(head, state, targets):
+        return ce(model.apply({"params": {"lm_head": head}}, state, method=ConfigLM.head), targets)
+
+    def loss_fn(params, model_state, batch):
+        tokens, targets = batch[0], batch[1]
+        states, gate_logits = model.apply({"params": params}, tokens, all_passes=True)
+        by_pass = []
+        for t in range(states.shape[0]):
+            with jax.named_scope(f"{SCOPE_LOOP}{t}"), jax.named_scope(SCOPE_HEAD):
+                by_pass.append(pass_loss(params["lm_head"], states[t], targets))
+        with jax.named_scope(SCOPE_HEAD):
+            by_pass = jnp.stack(by_pass)                          # [R, B, S]
+            p, log_p = exit_distribution(gate_logits)
+            entropy = -jnp.sum(p * log_p, axis=0)
+            loss = jnp.mean(jnp.sum(p * by_pass, axis=0) - beta * entropy)
+            number = jnp.arange(1, p.shape[0] + 1, dtype=jnp.float32)
+            aux = {"loss_by_pass": by_pass.mean(axis=(1, 2)),
+                   "exit_mass_by_pass": p.mean(axis=(1, 2)),
+                   "exit_entropy": entropy.mean(),
+                   "expected_exit_pass": jnp.tensordot(number, p, 1).mean()}
+        return loss, (model_state, aux)
 
     return loss_fn

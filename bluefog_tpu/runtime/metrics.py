@@ -555,6 +555,16 @@ _HELP_EXACT: Dict[str, str] = {
     "moe.chunk_tiles": "tiles a trip of that layer's row movers walks "
                        "(expert.chunk_tiles): the movers stop after the "
                        "chunk that holds tile tiles_in_use - 1",
+    "loop.passes": "times the last ConfigLM traced runs its one stack of "
+                   "layers on the same weights (LMConfig.total_ut_steps; 1 "
+                   "for a model that is not looped)",
+    "loop.layer_applications": "layer applications of one forward pass of "
+                               "that model: passes x layers, where its "
+                               "parameters are of the layers alone",
+    "loop.recomputed": "1 when every layer application of that model runs "
+                       "under jax.checkpoint (LMConfig.remat_layers: its "
+                       "input is kept, the rest made again in the backward "
+                       "pass), else 0",
     "trace.requests": "serve requests traced into the flight ring "
                       "(BLUEFOG_TRACE_SERVE; docs/slo.md)",
 }
@@ -584,7 +594,7 @@ _HELP_PREFIX = (
 # segment). The bfcheck [metrics] analyzer enforces this plus HELP
 # resolution for every creation site in the package — a new family must
 # be added here (with curated HELP coverage) before it can ship.
-_PREFIX_FAMILIES = ("alert", "cp", "flash", "hb", "membership", "moe", "opt", "pushsum",
+_PREFIX_FAMILIES = ("alert", "cp", "flash", "hb", "loop", "membership", "moe", "opt", "pushsum",
                     "serve", "slo", "trace", "tune", "watchdog", "win")
 
 

@@ -32,6 +32,12 @@ benchmarks, with random weights from a seed:
   per chip through the same optimizer with the routing biases as its model
   state: the expert layers' counters of the last step are printed
   (``tiles_in_use`` among them), and an overflowed row raises.
+* ``looped``: ``ConfigLM`` as a looped model at Ouro's head layout (16 heads
+  of 128, two layers run four times, sandwich norms, the exit gate) at 1,024
+  tokens with every layer application recomputed -- ``jax.checkpoint`` around
+  the flash kernels' ``custom_vjp`` -- its loss and gradients against the same
+  model without recomputation, and the Mosaic calls each program holds (the
+  recomputed one runs the forward kernel twice an application).
 
 It refuses to start unless every rank is a TPU device, and a failing phase
 raises (nothing is caught). A run that passed ends with two JSON lines on
@@ -47,6 +53,7 @@ smaller compile times.
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import math
 import os
@@ -65,7 +72,7 @@ import optax  # noqa: E402
 
 import bluefog_tpu as bf  # noqa: E402
 from bluefog_tpu.models import (ConfigLM, LMConfig, MLP, ResNet50,  # noqa: E402
-                                TransformerLM, next_token_loss)
+                                TransformerLM, looped_exit_loss, next_token_loss)
 from bluefog_tpu.optimizers import PERMUTES_IN_FLIGHT_MAX  # noqa: E402
 from bluefog_tpu.parallel import expert  # noqa: E402
 from bluefog_tpu.parallel.context import reference_attention  # noqa: E402
@@ -100,6 +107,13 @@ MLA_MOE = LMConfig(
     n_routed_experts=256, num_experts_per_tok=8, moe_intermediate_size=768,
     routed_scaling_factor=2.5, experts_held=(0, 8), bias_update_speed=0.001,
     num_nextn_predict_layers=1)
+# Ouro's block, two layers run four times, a sixth of the vocabulary
+LOOPED = LMConfig(
+    vocab_size=8192, hidden_size=2048, num_hidden_layers=2, num_attention_heads=16,
+    intermediate_size=5632, attention="grouped", num_key_value_heads=16, head_dim=128,
+    rope_theta=1e6, rope_interleave=False, total_ut_steps=4, sandwich_norms=True,
+    exit_gate=True)
+LOOPED_SEQ = 1024
 # rows of each held expert in the grouped products' check: ragged, one empty,
 # one of a single row, one of exactly a tile
 GROUPED_LOADS = (700, 0, 130, 1, 300, 128, 5, 900)
@@ -514,6 +528,47 @@ def phase_mla_moe():
     return out
 
 
+def phase_looped():
+    """The first ``jax.checkpoint`` around the kernels' ``custom_vjp`` on a
+    chip: value and gradients of the expected-exit loss with every layer
+    application recomputed against the same program without."""
+    applications = LOOPED.total_ut_steps * LOOPED.num_hidden_layers
+    tokens = jax.random.randint(jax.random.PRNGKey(7), (1, LOOPED_SEQ), 0, LOOPED.vocab_size)
+    batch = (tokens, jnp.roll(tokens, -1, axis=1))
+    out = {}
+    for name, remat, kernels in (("recomputed", True, 3 * applications),
+                                 ("kept", False, 2 * applications)):
+        model = ConfigLM(dataclasses.replace(LOOPED, remat_layers=remat), dtype=jnp.bfloat16,
+                         attn_fn=FLASH)
+        if not out:
+            params = jax.jit(lambda k: model.init(k, tokens)["params"])(jax.random.PRNGKey(0))
+        grad = jax.jit(jax.value_and_grad(looped_exit_loss(model, 0.1), has_aux=True)).lower(
+            params, {}, batch).compile()
+        # the compiled program's: the lowered text holds a kernel once, its callers many times
+        calls = grad.as_text().count('custom_call_target="tpu_custom_call"')
+        if calls != kernels:
+            raise RuntimeError(f"expected {kernels} Mosaic kernels in the {name} looped "
+                               f"gradient ({applications} applications), found {calls}")
+        (loss, (_, aux)), grads = grad(params, {}, batch)
+        out[name] = (loss, grads)
+        mass = float(aux["exit_mass_by_pass"].sum())
+        if abs(mass - 1.0) > 1e-5:
+            raise RuntimeError(f"the exit masses of the {name} program sum to {mass}")
+    (loss, grads), (want_loss, want_grads) = out["recomputed"], out["kept"]
+    err, leaf = max(
+        (float(jnp.max(jnp.abs(g - w)) / jnp.max(jnp.abs(w))), jax.tree_util.keystr(path))
+        for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                                jax.tree_util.tree_leaves(want_grads)))
+    # the two programs round alike but fuse apart: bf16 noise, not a fault
+    if not (abs(float(loss) - float(want_loss)) <= 1e-3 * abs(float(want_loss))
+            and err <= KERNEL_TOL):
+        raise RuntimeError(f"recomputed looped gradients differ from the kept ones: loss "
+                           f"{float(loss)} for {float(want_loss)}, worst leaf {leaf} {err}")
+    return {"loss_recomputed": round(float(loss), 5), "loss_kept": round(float(want_loss), 5),
+            "grad_max_rel_err": round(err, 5), "worst_leaf": leaf,
+            "mosaic_calls": {"recomputed": 3 * applications, "kept": 2 * applications}}
+
+
 def _device_stamp():
     """The device as JAX reports it; exits unless every rank is a TPU chip and
     every chip is a rank."""
@@ -559,6 +614,8 @@ def main():
     print("lm_flash:", phases["lm_flash"], flush=True)
     phases["mla_moe"] = phase_mla_moe()
     print("mla_moe:", phases["mla_moe"], flush=True)
+    phases["looped"] = phase_looped()
+    print("looped:", phases["looped"], flush=True)
     peak_gib = [round(d.memory_stats()["peak_bytes_in_use"] / 2**30, 2)
                 for d in bf.mesh().devices.flat]
     bf.shutdown()

@@ -627,3 +627,75 @@ def test_k_and_v_reach_the_kernels_at_their_own_head_count(gqa_toy):
         assert q == (hq, s, d) and k == v == (hkv, s, d), shapes
         # the rest are q-side: dO and the row statistics
         assert all(shape[0] == hq for shape in shapes[4:]), shapes
+
+
+@pytest.mark.parametrize("keys", [
+    {"total_ut_steps": 2, "n_routed_experts": 8}, {"exit_gate": True, "n_routed_experts": 8},
+    {"sandwich_norms": True, "n_routed_experts": 8},
+    {"total_ut_steps": 2, "num_nextn_predict_layers": 1},
+    {"exit_gate": True, "num_nextn_predict_layers": 1}], ids=lambda keys: "+".join(keys))
+def test_a_loop_over_expert_layers_or_mtp_modules_is_refused(keys):
+    """The loop, the gate and the output norms are the dense block's: the
+    counters and the balancing step are a layer's, not an application's, and
+    an MTP module reads the un-normed trunk. Said at construction."""
+    with pytest.raises(ValueError, match="looped stack"):
+        LMConfig(vocab_size=64, hidden_size=32, num_hidden_layers=2, num_attention_heads=4,
+                 intermediate_size=48, **keys)
+
+
+def test_the_new_keys_are_off_by_default_and_each_adds_only_its_own_parameters():
+    dense = LMConfig(vocab_size=64, hidden_size=32, num_hidden_layers=2, num_attention_heads=4,
+                     intermediate_size=48, attention="equal")
+    assert (dense.total_ut_steps, dense.sandwich_norms, dense.exit_gate, dense.remat_layers) == (
+        1, False, False, False)
+    tokens = jnp.zeros((1, 16), jnp.int32)
+    tree = lambda cfg: ConfigLM(cfg).init(jax.random.PRNGKey(0), tokens)["params"]  # noqa: E731
+    base = tree(dense)
+    assert set(base) == {"embed", "layer_0", "layer_1", "final_norm", "lm_head"}
+    assert set(base["layer_0"]) == {"attn_norm", "attn", "ffn_norm", "ffn"}
+    shape = jax.tree_util.tree_structure
+    same = lambda a, b: shape(a) == shape(b)  # noqa: E731
+    assert same(tree(dataclasses.replace(dense, total_ut_steps=3)), base)
+    assert same(tree(dataclasses.replace(dense, remat_layers=True)), base)
+    gated = tree(dataclasses.replace(dense, exit_gate=True))
+    assert set(gated) - set(base) == {"exit_gate"} and same(gated["layer_0"], base["layer_0"])
+    normed = tree(dataclasses.replace(dense, sandwich_norms=True))
+    assert set(normed) == set(base)
+    assert set(normed["layer_0"]) - set(base["layer_0"]) == {"attn_out_norm", "ffn_out_norm"}
+    # one pass of a plain model, asked for all passes: its normed state, no gate
+    model = ConfigLM(dense)
+    states, gates = model.apply({"params": base}, tokens, all_passes=True)
+    assert states.shape == (1, 1, 16, 32) and gates is None
+    logits = model.apply({"params": base}, states[0], method=ConfigLM.head)
+    assert _rel(logits, model.apply({"params": base}, tokens)) <= 1e-6
+
+
+# sha256 of the printed jaxpr of value_and_grad of the two accepted ConfigLM
+# cells' losses at their toy sizes, taken from the parent commit's
+# ``config_lm.py`` under jax 0.9.0 and this suite's matmul precision: an
+# ``LMConfig`` without the loop, the sandwich norms, the gate and the
+# recomputation must trace to the program it traced to before they came
+# (PR 34): no new parameter, no new equation, no checkpoint.
+_PARENT_LOSS_JAXPRS = {
+    "joyai": "5d717bd5036d62d27e36265088bbfae7c808def8c843af5c256f0eadd273cbfd",
+    "smallthinker": "287d0ebbea729dcac6ed15f53d5a69bb3ce95bec22e7fbaf213f76348f9c3f6b",
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_PARENT_LOSS_JAXPRS))
+def test_a_config_at_its_defaults_traces_to_the_parents_program(cell, toy, gqa_toy):
+    import hashlib
+
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the hashes are of jaxprs printed by jax 0.9.0")
+    if cell == "joyai":
+        (cfg, params, routing, batch), family = toy, FAMILY
+    else:
+        (cfg, params, batch), routing, family = gqa_toy, {}, GQA
+    lm = family.lm_config(cfg)
+    assert (lm.total_ut_steps, lm.sandwich_norms, lm.exit_gate, lm.remat_layers) == (
+        1, False, False, False)
+    text = str(jax.make_jaxpr(jax.value_and_grad(family.loss(cfg)[0], has_aux=True))(
+        params, routing, batch))
+    assert "remat" not in text and "checkpoint" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == _PARENT_LOSS_JAXPRS[cell]
