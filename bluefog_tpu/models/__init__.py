@@ -9,8 +9,8 @@ with float32 parameters/batch-stats, channel counts that are multiples of
 128 where the architecture allows, and no data-dependent Python control flow.
 """
 
-from .config_lm import (ConfigLM, LMConfig, exit_distribution, looped_exit_loss,
-                        moe_choices, moe_counters, next_token_loss)
+from .config_lm import (ConfigLM, LMConfig, exit_distribution, label_cross_entropy,
+                        looped_exit_loss, moe_choices, moe_counters, next_token_loss)
 from .mlp import MLP, LeNet5
 from .fold import fold_batchnorm
 from .resnet import ResNet, ResNet18, ResNet34, ResNet50, ResNet101
@@ -21,6 +21,7 @@ __all__ = [
     "ConfigLM",
     "LMConfig",
     "exit_distribution",
+    "label_cross_entropy",
     "looped_exit_loss",
     "moe_choices",
     "moe_counters",

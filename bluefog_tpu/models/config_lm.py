@@ -73,7 +73,7 @@ grouped kind), the two ``bf.flash.*`` of the attention function,
 around a whole MTP module and, in a looped or gated model only, ``bf.loop.<t>``
 (t from 0) around everything pass t runs, outside the others. Gauges set while
 tracing (docs/metrics.md): ``loop.passes``, ``loop.layer_applications`` (R x L),
-``loop.recomputed``.
+``loop.recomputed``; by the two losses, ``loss.compare_heads``.
 """
 
 from __future__ import annotations
@@ -84,7 +84,6 @@ from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-import optax
 from flax import linen as nn
 
 from ..parallel.context import reference_attention
@@ -457,6 +456,43 @@ def moe_choices(intermediates) -> list:
     return _sowed(intermediates, "moe_choice")
 
 
+@jax.custom_vjp
+def label_cross_entropy(logits, labels):
+    """The cross-entropy ``[...]`` in float32 of integer ``labels`` ``[...]``
+    under ``logits`` ``[..., V]``: ``logsumexp(z) - z[label]``, the label's
+    logit picked by comparing a vocabulary iota with the label inside the
+    reduce that reads ``z`` -- no gather from the ``[tokens, V]`` array, no
+    reshape of it.
+
+    The gradient is its own: from the logits as they came, the ``[...]``
+    log-sum-exp and the labels, ``(exp(z - lse) - (iota == label)) * g`` in
+    float32, rounded once to the logits' dtype -- one elementwise pass that
+    reads the logits and writes their gradient, where the transpose of a
+    gather scatters into a zeroed float32 ``[tokens, V]`` array."""
+    return _label_cross_entropy_fwd(logits, labels)[0]
+
+
+def _is_label(shape, labels):
+    return jax.lax.broadcasted_iota(labels.dtype, shape, len(shape) - 1) == labels[..., None]
+
+
+def _label_cross_entropy_fwd(logits, labels):
+    z = logits.astype(jnp.float32)
+    lse = jax.nn.logsumexp(z, axis=-1)
+    picked = jnp.sum(jnp.where(_is_label(z.shape, labels), z, 0.0), axis=-1)
+    return lse - picked, (logits, lse, labels)
+
+
+def _label_cross_entropy_bwd(saved, g):
+    logits, lse, labels = saved
+    softmax = jnp.exp(logits.astype(jnp.float32) - lse[..., None])
+    d_logits = (softmax - _is_label(logits.shape, labels)) * g[..., None]
+    return d_logits.astype(logits.dtype), None
+
+
+label_cross_entropy.defvjp(_label_cross_entropy_fwd, _label_cross_entropy_bwd)
+
+
 def next_token_loss(model: ConfigLM, mtp_weight: float = 0.3):
     """``loss_fn(params, routing, batch) -> (loss, (routing, counters))`` for
     the ``bf.Distributed*Optimizer``s with ``with_model_state=True``: the mean
@@ -467,23 +503,25 @@ def next_token_loss(model: ConfigLM, mtp_weight: float = 0.3):
     balancing rule. ``batch`` is ``(tokens, targets)`` or, with MTP,
     ``(tokens, targets, mtp_targets)``: ``targets`` are the tokens one on
     (they are also what the first MTP module embeds), ``mtp_targets`` two on.
-    ``opt.step``'s ``metrics["aux"]`` carries :func:`moe_counters`."""
-    ce = optax.softmax_cross_entropy_with_integer_labels
+    ``opt.step``'s ``metrics["aux"]`` carries :func:`moe_counters`. Every
+    head's cross-entropy is :func:`label_cross_entropy`; the gauge
+    ``loss.compare_heads`` counts them while tracing."""
 
     def loss_fn(params, routing, batch):
         tokens, targets = batch[0], batch[1]
         out, state = model.apply({"params": params, ROUTING: routing}, tokens,
                                  next_tokens=targets, mutable=["intermediates", ROUTING])
         if not model.cfg.num_nextn_predict_layers:
-            loss = ce(out, targets).mean()
+            loss = label_cross_entropy(out, targets).mean()
         else:
             logits, mtp_logits = out
             with jax.named_scope(SCOPE_HEAD):
-                loss = ce(logits, targets).mean()
+                loss = label_cross_entropy(logits, targets).mean()
             with jax.named_scope(SCOPE_MTP):
                 for k, extra in enumerate(mtp_logits):
-                    loss = loss + mtp_weight * ce(
+                    loss = loss + mtp_weight * label_cross_entropy(
                         extra, jnp.roll(batch[2], -k, axis=1)).mean()
+        metrics.gauge("loss.compare_heads").set(1 + model.cfg.num_nextn_predict_layers)
         return loss, (state.get(ROUTING, routing),
                       moe_counters(state.get("intermediates", {})))
 
@@ -520,12 +558,13 @@ def looped_exit_loss(model: ConfigLM, beta: float):
     ``[R]`` (each pass's mean cross-entropy), ``exit_mass_by_pass`` ``[R]``
     (the mean of p, summing to 1), ``exit_entropy`` (the mean of H) and
     ``expected_exit_pass`` (the mean of ``sum_t t p_t``, passes counted from
-    1)."""
-    ce = optax.softmax_cross_entropy_with_integer_labels
+    1). A pass's cross-entropy is :func:`label_cross_entropy`; the gauge
+    ``loss.compare_heads`` counts the passes' while tracing."""
 
     @jax.checkpoint
     def pass_loss(head, state, targets):
-        return ce(model.apply({"params": {"lm_head": head}}, state, method=ConfigLM.head), targets)
+        return label_cross_entropy(
+            model.apply({"params": {"lm_head": head}}, state, method=ConfigLM.head), targets)
 
     def loss_fn(params, model_state, batch):
         tokens, targets = batch[0], batch[1]
@@ -534,6 +573,7 @@ def looped_exit_loss(model: ConfigLM, beta: float):
         for t in range(states.shape[0]):
             with jax.named_scope(f"{SCOPE_LOOP}{t}"), jax.named_scope(SCOPE_HEAD):
                 by_pass.append(pass_loss(params["lm_head"], states[t], targets))
+        metrics.gauge("loss.compare_heads").set(len(by_pass))
         with jax.named_scope(SCOPE_HEAD):
             by_pass = jnp.stack(by_pass)                          # [R, B, S]
             p, log_p = exit_distribution(gate_logits)

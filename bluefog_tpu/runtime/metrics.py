@@ -565,6 +565,12 @@ _HELP_EXACT: Dict[str, str] = {
                        "under jax.checkpoint (LMConfig.remat_layers: its "
                        "input is kept, the rest made again in the backward "
                        "pass), else 0",
+    "loss.compare_heads": "head cross-entropies of the last ConfigLM loss "
+                          "traced (next_token_loss: 1 + MTP modules; "
+                          "looped_exit_loss: passes), each "
+                          "models.label_cross_entropy: the label's logit "
+                          "picked by comparison, no gather or scatter of a "
+                          "[tokens, vocab] array",
     "trace.requests": "serve requests traced into the flight ring "
                       "(BLUEFOG_TRACE_SERVE; docs/slo.md)",
 }
@@ -594,8 +600,8 @@ _HELP_PREFIX = (
 # segment). The bfcheck [metrics] analyzer enforces this plus HELP
 # resolution for every creation site in the package — a new family must
 # be added here (with curated HELP coverage) before it can ship.
-_PREFIX_FAMILIES = ("alert", "cp", "flash", "hb", "loop", "membership", "moe", "opt", "pushsum",
-                    "serve", "slo", "trace", "tune", "watchdog", "win")
+_PREFIX_FAMILIES = ("alert", "cp", "flash", "hb", "loop", "loss", "membership", "moe", "opt",
+                    "pushsum", "serve", "slo", "trace", "tune", "watchdog", "win")
 
 
 def help_for(name: str) -> str:

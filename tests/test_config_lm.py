@@ -19,8 +19,9 @@ import jax.numpy as jnp
 import optax
 
 import bluefog_tpu as bf
-from bluefog_tpu.models import (ConfigLM, LMConfig, moe_choices, moe_counters,
-                                next_token_loss)
+from bluefog_tpu.models import (ConfigLM, LMConfig, config_lm, label_cross_entropy, moe_choices,
+                                moe_counters, next_token_loss)
+from bluefog_tpu.runtime import metrics
 from bluefog_tpu.parallel import expert
 from bluefog_tpu.parallel.context import reference_attention
 from bluefog_tpu.parallel.flash import flash_attention
@@ -671,11 +672,13 @@ def test_the_new_keys_are_off_by_default_and_each_adds_only_its_own_parameters()
 
 
 # sha256 of the printed jaxpr of value_and_grad of the two accepted ConfigLM
-# cells' losses at their toy sizes, taken from the parent commit's
+# cells' losses at their toy sizes, taken from PR 34's parent commit's
 # ``config_lm.py`` under jax 0.9.0 and this suite's matmul precision: an
 # ``LMConfig`` without the loop, the sandwich norms, the gate and the
 # recomputation must trace to the program it traced to before they came
-# (PR 34): no new parameter, no new equation, no checkpoint.
+# (PR 34): no new parameter, no new equation, no checkpoint. The losses of
+# that commit took their cross-entropy from optax, so it is put back here:
+# ``label_cross_entropy`` (PR 35) is then all that differs.
 _PARENT_LOSS_JAXPRS = {
     "joyai": "5d717bd5036d62d27e36265088bbfae7c808def8c843af5c256f0eadd273cbfd",
     "smallthinker": "287d0ebbea729dcac6ed15f53d5a69bb3ce95bec22e7fbaf213f76348f9c3f6b",
@@ -683,11 +686,13 @@ _PARENT_LOSS_JAXPRS = {
 
 
 @pytest.mark.parametrize("cell", sorted(_PARENT_LOSS_JAXPRS))
-def test_a_config_at_its_defaults_traces_to_the_parents_program(cell, toy, gqa_toy):
+def test_a_config_at_its_defaults_traces_to_the_parents_program(cell, toy, gqa_toy, monkeypatch):
     import hashlib
 
     if jax.__version__ != "0.9.0":
         pytest.skip("the hashes are of jaxprs printed by jax 0.9.0")
+    monkeypatch.setattr(config_lm, "label_cross_entropy",
+                        optax.softmax_cross_entropy_with_integer_labels)
     if cell == "joyai":
         (cfg, params, routing, batch), family = toy, FAMILY
     else:
@@ -699,3 +704,77 @@ def test_a_config_at_its_defaults_traces_to_the_parents_program(cell, toy, gqa_t
         params, routing, batch))
     assert "remat" not in text and "checkpoint" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == _PARENT_LOSS_JAXPRS[cell]
+
+
+def _optax_ce(logits, labels):
+    """The parent's cross-entropy as ``ConfigLM``'s head fed it: float32."""
+    return optax.softmax_cross_entropy_with_integer_labels(logits.astype(jnp.float32), labels)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", ["2x8x64", "vocabulary 200", "labels at 0 and V-1", "checkpoint"])
+def test_label_cross_entropy_is_optaxs_in_value_and_gradient(case, dtype):
+    """``label_cross_entropy`` against the gather form it replaces: values to
+    1e-6 and the gradient with respect to the logits -- float32 logits to
+    1e-6; bfloat16 logits to the one rounding of the float32 gradient that the
+    transpose of the head's ``.astype(float32)`` made at the parent, a
+    bfloat16 ulp -- under weights that differ by token, as the expected-exit
+    objective's do."""
+    shape, vocab = ((3, 5), 200) if case == "vocabulary 200" else ((2, 8), 64)
+    keys = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+    logits = (4.0 * jax.random.normal(keys[0], shape + (vocab,))).astype(dtype)
+    labels = jax.random.randint(keys[1], shape, 0, vocab)
+    if case == "labels at 0 and V-1":
+        labels = labels.at[0, 0].set(0).at[1, 3].set(vocab - 1).at[1, 7].set(0)
+    weights = jax.random.uniform(keys[2], shape)
+    wrap = jax.checkpoint if case == "checkpoint" else (lambda f: f)
+    value = lambda ce: lambda z: jnp.sum(weights * wrap(ce)(z, labels))  # noqa: E731
+
+    got, want = label_cross_entropy(logits, labels), _optax_ce(logits, labels)
+    assert got.dtype == jnp.float32 and got.shape == shape
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    d_got = jax.grad(value(label_cross_entropy))(logits)
+    d_want = jax.grad(value(_optax_ce))(logits)
+    assert d_got.dtype == dtype and d_got.shape == logits.shape
+    np.testing.assert_allclose(d_got.astype(jnp.float32), d_want.astype(jnp.float32),
+                               rtol=2 ** -7 if dtype == jnp.bfloat16 else 1e-6, atol=1e-7)
+    # the label's column alone is negative
+    at_label = jnp.take_along_axis(d_got.astype(jnp.float32), labels[..., None], axis=-1)[..., 0]
+    assert (at_label < 0).all() and ((d_got < 0).sum(axis=-1) == 1).all()
+
+
+def tokens_by_vocab_movers(fn, *args, tokens: int, vocab: int):
+    """The equations of ``fn``'s jaxpr, nested ones included, that gather from,
+    scatter into or update a slice of a ``[..., vocab]`` array of ``tokens *
+    vocab`` elements -- the logits or their gradient -- as (primitive, shapes)."""
+    from jax._src import core as jax_core
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            name = eqn.primitive.name
+            shapes = [tuple(v.aval.shape) for v in (*eqn.invars, *eqn.outvars)]
+            if ("gather" in name or "scatter" in name or name == "dynamic_update_slice") and any(
+                    shape[-1:] == (vocab,) and int(np.prod(shape)) == tokens * vocab
+                    for shape in shapes):
+                yield name, shapes
+            for sub in jax_core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def test_no_logit_is_gathered_or_scattered_between_a_head_and_its_gradients(toy, monkeypatch):
+    """``value_and_grad`` of the MTP toy's loss: no gather, scatter or
+    ``dynamic_update_slice`` touches a ``[tokens, vocab]`` array, both heads
+    are counted; with optax's cross-entropy put back (the parent) the same
+    walk finds a gather and a scatter-add a head."""
+    cfg, params, routing, batch = toy
+    size = {"tokens": batch[0].size, "vocab": cfg["vocab_size"]}
+    step = lambda: jax.value_and_grad(FAMILY.loss(cfg)[0], has_aux=True)  # noqa: E731
+    metrics.gauge("loss.compare_heads").set(0)
+    assert tokens_by_vocab_movers(step(), params, routing, batch, **size) == []
+    assert metrics.gauge("loss.compare_heads").value == 2
+    monkeypatch.setattr(config_lm, "label_cross_entropy",
+                        optax.softmax_cross_entropy_with_integer_labels)
+    found = [name for name, _ in tokens_by_vocab_movers(step(), params, routing, batch, **size)]
+    assert sorted(found) == ["gather", "gather", "scatter-add", "scatter-add"], found

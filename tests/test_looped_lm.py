@@ -21,7 +21,7 @@ import optax
 import bluefog_tpu as bf
 from bluefog_tpu.models import ConfigLM
 
-from test_config_lm import GRAD_RTOL, LOSS_RTOL, ROOT, _rel
+from test_config_lm import GRAD_RTOL, LOSS_RTOL, ROOT, _rel, tokens_by_vocab_movers
 
 def _by_path(name, *parts):
     spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT, *parts))
@@ -148,6 +148,27 @@ def test_recomputation_changes_the_program_and_not_the_numbers(loop_toy):
     assert _checkpoints(jax.grad(states(plain)), params) == 0
     assert _checkpoints(jax.grad(partial(_loop_loss, cfg)), params, batch) == 12
     assert _checkpoints(jax.grad(partial(_loop_loss, plain)), params, batch) == 4
+
+
+def test_no_pass_gathers_or_scatters_its_logits(loop_toy, monkeypatch):
+    """``value_and_grad`` of the looped toy's loss, the recomputed passes
+    included: no gather, scatter or ``dynamic_update_slice`` touches a
+    ``[tokens, vocab]`` array, and the four passes' cross-entropies are
+    counted; with optax's put back (the parent) each pass gathers forward and
+    scatter-adds backward."""
+    from bluefog_tpu.models import config_lm
+    from bluefog_tpu.runtime import metrics
+
+    cfg, params, batch = loop_toy
+    size = {"tokens": batch[0].size, "vocab": cfg["vocab_size"]}
+    step = lambda: jax.value_and_grad(LOOPED.loss(cfg)[0], has_aux=True)  # noqa: E731
+    metrics.gauge("loss.compare_heads").set(0)
+    assert tokens_by_vocab_movers(step(), params, {}, batch, **size) == []
+    assert metrics.gauge("loss.compare_heads").value == cfg["total_ut_steps"] == 4
+    monkeypatch.setattr(config_lm, "label_cross_entropy",
+                        optax.softmax_cross_entropy_with_integer_labels)
+    found = [name for name, _ in tokens_by_vocab_movers(step(), params, {}, batch, **size)]
+    assert sorted(found) == ["gather"] * 4 + ["scatter-add"] * 4, found
 
 
 def test_a_looped_matrix_takes_the_sum_of_its_four_uses_gradients(loop_toy):
