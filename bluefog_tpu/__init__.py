@@ -17,6 +17,10 @@ Ranks are devices of a ``jax.sharding.Mesh``; every op runs as one SPMD
 program with ``ppermute``/``psum`` collectives over ICI.
 """
 
+import time as _time
+
+_stamps = [("begin", _time.perf_counter())]  # (import group that ended, clock)
+
 from . import topology as topology_util
 from .version import __version__
 
@@ -78,6 +82,8 @@ from .runtime.metrics import cluster_health
 from .runtime import flight
 from .runtime.flight import step_report
 
+_stamps.append(("runtime", _time.perf_counter()))
+
 
 def flight_dump(reason: str = "explicit", path=None):
     """Dump the flight recorder NOW (ring tail + native transport events +
@@ -136,6 +142,8 @@ from .ops import (
     win_wait,
 )
 
+_stamps.append(("ops", _time.perf_counter()))
+
 # optimizer wrappers (reference: torch/optimizers.py)
 from .optimizers import (
     TrainState,
@@ -152,6 +160,8 @@ from .optimizers import (
     step_programs,
 )
 
+_stamps.append(("optimizers", _time.perf_counter()))
+
 # parameter/optimizer-state sync utilities (reference: torch/utility.py)
 from .utils import (
     broadcast_parameters,
@@ -161,12 +171,33 @@ from .utils import (
     vgg_from_torch,
 )
 
+_stamps.append(("utils", _time.perf_counter()))
+
 from . import checkpoint
+
+_stamps.append(("checkpoint", _time.perf_counter()))
+
 from . import models
+
+_stamps.append(("models", _time.perf_counter()))
+
 from . import parallel
+
+_stamps.append(("parallel", _time.perf_counter()))
 
 # serving plane: versioned snapshot distribution + batched read-only
 # inference over the control-plane wire (docs/serving.md). bf.serve_client()
 # attaches from inside a job; standalone serving processes import
 # ``bluefog_tpu.serving`` through the lean bootstrap instead (no jax).
 from .serving import RequestShed, ServeClient, serve_client
+
+_stamps.append(("serving", _time.perf_counter()))
+
+# Seconds ``import bluefog_tpu`` took, by import group and in ``"total"``
+# (a module imported before by someone else costs its group nothing). Kept
+# here because bf.init() zeroes the registry; it writes them after that reset
+# as the gauges ``import.total_sec`` and ``import.<group>_sec``.
+IMPORT_SECONDS = {group: t - before for (_, before), (group, t)
+                  in zip(_stamps, _stamps[1:])}
+IMPORT_SECONDS["total"] = _stamps[-1][1] - _stamps[0][1]
+del _stamps

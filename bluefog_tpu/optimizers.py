@@ -60,7 +60,7 @@ import contextlib
 import functools
 import os
 import time
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -87,7 +87,7 @@ from .runtime.config import knob_env
 from .runtime.logging import logger
 from .runtime.native import PeerLostError
 from .runtime.state import _global_state
-from .runtime.timeline import timeline_context
+from .runtime.timeline import BuildRecord, build_context, timeline_context
 
 
 # Consensus-gauge cadence (seconds): matches the time-series sampler's
@@ -349,25 +349,63 @@ def _shape_of(x) -> jax.ShapeDtypeStruct:
                                 sharding=x.sharding if placed else None)
 
 
+def _hbm_peak_bytes(mesh) -> Optional[int]:
+    """The high-water mark of live arrays on the fullest of the mesh's local
+    devices; None on a backend that keeps no ``memory_stats()``."""
+    stats = [d.memory_stats() for d in mesh.local_devices]
+    peaks = [s["peak_bytes_in_use"] for s in stats if s and "peak_bytes_in_use" in s]
+    return max(peaks) if peaks else None
+
+
+class ProgramMemory(NamedTuple):
+    """A compiled program's ``memory_analysis()``, bytes a device."""
+
+    argument_bytes: int
+    output_bytes: int
+    alias_bytes: int       # outputs written over donated arguments
+    temp_bytes: int        # the scratch the program works in
+    code_bytes: int
+
+    @property
+    def resident_bytes(self) -> int:
+        """What a device holds while the program runs."""
+        return (self.argument_bytes + self.output_bytes - self.alias_bytes
+                + self.temp_bytes + self.code_bytes)
+
+
 class StepProgram:
     """One step program a fused optimizer built, as :func:`step_programs`
     lists it: ``name`` of the optimizer, its cache ``key`` (whether the step
-    communicates, and the plan's edge shifts) and, on demand, the compiled
-    HLO. Holds the jitted function and the arguments' shapes and shardings
-    (the small host weight matrix as it is), no device array."""
+    communicates, and the plan's edge shifts), what the ``build`` cost
+    (:class:`BuildRecord`) and, on demand, the compiled HLO and its memory.
+    Holds the jitted function and the arguments' shapes and shardings (the
+    small host weight matrix as it is), no device array."""
 
-    def __init__(self, name: str, key, fn, args) -> None:
-        self.name, self.key, self._fn = name, key, fn
+    def __init__(self, name: str, key, fn, args,
+                 build: Optional[BuildRecord] = None) -> None:
+        self.name, self.key, self._fn, self.build = name, key, fn, build
         self._avals = (args[0],) + jax.tree_util.tree_map(_shape_of, tuple(args[1:]))
+
+    def _compiled(self):
+        """Lowers from the cached trace (the loss is not traced again) and
+        finds the executable this process already has (0.04-0.3 s on a v5e,
+        no compile); never called on the step path."""
+        return self._fn.lower(*self._avals).compile()
 
     def hlo_text(self) -> str:
         """The optimized HLO of the program as the device runs it: every
         instruction under the name a profiler trace gives its op, with
-        ``metadata={op_name="...bf.update/..."}``. Lowers from the cached
-        trace (the loss is not traced again) and finds the executable this
-        process already has (0.04-0.3 s on a v5e, no compile); never called on
-        the step path."""
-        return self._fn.lower(*self._avals).compile().as_text()
+        ``metadata={op_name="...bf.update/..."}``."""
+        return self._compiled().as_text()
+
+    def memory(self) -> ProgramMemory:
+        """The executable's ``memory_analysis()``: arguments, outputs, what
+        of them is aliased, temporaries and generated code, bytes a device,
+        and their sum ``resident_bytes``."""
+        m = self._compiled().memory_analysis()
+        return ProgramMemory(
+            m.argument_size_in_bytes, m.output_size_in_bytes, m.alias_size_in_bytes,
+            m.temp_size_in_bytes, m.generated_code_size_in_bytes)
 
     def __repr__(self) -> str:
         return f"StepProgram({self.name!r}, key={self.key!r})"
@@ -411,6 +449,21 @@ class _FusedOptimizer:
     # -- state ------------------------------------------------------------
 
     def init(self, params, model_state=None) -> TrainState:
+        """The rank-stacked training state from single-rank ``params`` (and
+        model state), made under the span ``<optimizer>.INIT``. Set-up only,
+        so it waits for its own result: ``opt.init_sec`` is the whole of it
+        and ``opt.init_hbm_peak_bytes`` the devices' high-water mark with the
+        state in place."""
+        t0 = time.perf_counter()
+        with timeline_context(self.name, "INIT"):
+            state = jax.block_until_ready(self._init_state(params, model_state))
+        _metrics.gauge("opt.init_sec").set(time.perf_counter() - t0)
+        peak = _hbm_peak_bytes(_global_state().mesh)
+        if peak is not None:
+            _metrics.gauge("opt.init_hbm_peak_bytes").set(peak)
+        return state
+
+    def _init_state(self, params, model_state) -> TrainState:
         """Replicate single-rank params (+ model state) and init optax state."""
         opt_state = self.base.init(params)
         return TrainState(
@@ -444,21 +497,38 @@ class _FusedOptimizer:
         return plan, plan.weight_array(), (plan.shifts, plan.use_gather)
 
     def _compile(self, key, plan, do_comm: bool, args):
-        """A cache miss: build the step for ``key``, keep it, and register it
-        with the shapes of the ``args`` it is about to be called with."""
-        with timeline_context(self.name, "BUILD"):
-            fn = self._step_cache[key] = self._build(
-                key, plan, do_comm, args[1])
-        _STEP_PROGRAMS.append(StepProgram(self.name, key, fn, args))
-        return fn
+        """A cache miss: build the step for ``key`` and call it for the first
+        time, both inside BUILD -- the call is where JAX traces the loss,
+        lowers it and compiles (or loads from the persistent cache), which
+        ``build_context`` files into the program's :class:`BuildRecord`. Keeps
+        the program, registers it with the shapes of ``args``, and returns
+        the step's outputs."""
+        with build_context(self.name) as building:
+            fn = self._build(key, plan, do_comm, args[1])
+            program = StepProgram(self.name, key, fn, args)  # before args are donated
+            out = fn(*args)
+        build = program.build = building.record(self._counter)
+        self._step_cache[key] = fn
+        _STEP_PROGRAMS.append(program)
+        _metrics.counter("opt.step_cache_misses").inc()
+        _metrics.counter("opt.build_cache_hits").inc(int(build.cache_hit))
+        _metrics.gauge("opt.step_cache_size").set(len(self._step_cache))
+        _metrics.gauge("opt.build_trace_sec").add(build.trace_s)
+        _metrics.gauge("opt.build_lower_sec").add(build.lower_s)
+        _metrics.gauge("opt.build_compile_sec").add(build.compile_s)
+        # in a postmortem dump a recompile stands in front of the stall it caused
+        _flight.recorder().instant("opt.build", a=build.total_s, b=build.step)
+        return out
 
     def step(self, state: TrainState, batch) -> Tuple[TrainState, Dict]:
         """One training iteration over the whole mesh."""
         k = self.num_steps_per_communication
         self._counter += 1
         do_comm = (self._counter % k) == 0
-        # STEP is the host side of the whole step on the profiler's clock;
-        # dispatch is STEP - PLAN - BUILD (BUILD opens on a cache miss only)
+        # STEP is the host side of the whole step on the profiler's clock.
+        # BUILD opens on a cache miss only and holds the program's first
+        # call; dispatch is STEP - PLAN on a hit and the build record's
+        # dispatch_s on a miss
         with timeline_context(self.name, "STEP"):
             with timeline_context(self.name, "PLAN"):
                 plan, w, wkey = self._weights_and_key() if do_comm else (
@@ -466,13 +536,12 @@ class _FusedOptimizer:
             key = (do_comm,) + wkey
             args = (w, state.params, state.opt_state, state.model_state, batch)
             fn = self._step_cache.get(key)
-            if fn is None:
-                fn = self._compile(key, plan, do_comm, args)
             _perf_gate_delay()
             try:
                 with _metrics.timed("opt.step_sec"), \
                         _flight.recorder().span("opt.step", b=self._counter):
-                    params, opt_state, model_state, metrics = fn(*args)
+                    params, opt_state, model_state, metrics = fn(*args) \
+                        if fn is not None else self._compile(key, plan, do_comm, args)
             except Exception as exc:
                 # black-box dump before the stack unwinds: the ring's tail IS
                 # the postmortem evidence (rate-limited; never raises)
@@ -580,7 +649,7 @@ class DistributedHierarchicalNeighborAllreduceOptimizer(_FusedOptimizer):
                 self.neighbor_machine_weights, self.enable_topo_check)
         return CombinePlan(W)
 
-    def init(self, params, model_state=None) -> TrainState:
+    def _init_state(self, params, model_state) -> TrainState:
         st = _global_state()
         opt_state = self.base.init(params)
         mesh = st.machine_mesh
@@ -627,7 +696,7 @@ class DistributedShardedAllreduceOptimizer(_FusedOptimizer):
 
     _shard_of = staticmethod(_flat_shard)
 
-    def init(self, params, model_state=None) -> TrainState:
+    def _init_state(self, params, model_state) -> TrainState:
         st = _global_state()
         mesh = st.mesh
         n = mesh.devices.size
@@ -827,8 +896,8 @@ class _WindowOptimizer(_FusedOptimizer):
     def _active_shard(self) -> int:
         return self._comm_rounds % self._shard_factor
 
-    def init(self, params, model_state=None) -> TrainState:
-        state = super().init(params, model_state)
+    def _init_state(self, params, model_state) -> TrainState:
+        state = super()._init_state(params, model_state)
         leaves, self._treedef = jax.tree_util.tree_flatten(state.params)
         thr = _global_state().config.fusion_threshold_bytes
         # threshold > 0: ONE window over the whole tree (one put+update
@@ -1051,9 +1120,8 @@ class _WindowOptimizer(_FusedOptimizer):
         args = (np.zeros((1, 1), np.float32),
                 state.params, state.opt_state, state.model_state, batch)
         fn = self._step_cache.get(key)
-        if fn is None:
-            fn = self._compile(key, None, False, args)
-        params, opt_state, model_state, metrics = fn(*args)
+        params, opt_state, model_state, metrics = fn(*args) \
+            if fn is not None else self._compile(key, None, False, args)
         return TrainState(params, opt_state, model_state), metrics
 
     def _gossip(self, buffers):  # packed [n, total] buffers -> mixed buffers
@@ -1789,7 +1857,7 @@ class DistributedPushSumOptimizer(_WindowOptimizer):
     def _restore_flags(self) -> None:
         _global_state().win_ops_with_associated_p = self._prior_associated_p
 
-    def init(self, params, model_state=None) -> TrainState:
+    def _init_state(self, params, model_state) -> TrainState:
         # Mass-conservation accounting for the health plane: `minted` is
         # the de-bias mass this controller CREATED (p=1 per owned rank at
         # window creation, or at a checkpoint-fallback re-mint); a rejoin
@@ -1798,7 +1866,7 @@ class DistributedPushSumOptimizer(_WindowOptimizer):
         # (bf.cluster_health's drift check; docs/metrics.md).
         was_rejoining = _hb.quarantine_pending()
         self._reminted = False
-        state = super().init(params, model_state)
+        state = super()._init_state(params, model_state)
         minted = 0.0
         mass = 0.0
         for nm in self._win_names:

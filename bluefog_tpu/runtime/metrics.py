@@ -444,6 +444,31 @@ _HELP_EXACT: Dict[str, str] = {
     "opt.step_sec": "host seconds dispatching one optimizer step's program "
                     "(asynchronous: the device runs on after it; not the "
                     "step's duration)",
+    "opt.step_cache_misses": "step programs this job built: optimizer "
+                             "steps whose plan had no cached program "
+                             "(StepProgram.build has each one's cost; "
+                             "docs/timeline.md, Set-up)",
+    "opt.build_cache_hits": "of those builds, the ones whose every compile "
+                            "request the persistent compile cache answered",
+    "opt.step_cache_size": "step programs the optimizer that built last "
+                           "holds in its cache",
+    "opt.build_trace_sec": "seconds tracing losses to jaxprs inside "
+                           "<optimizer>.BUILD spans, summed over the job's "
+                           "builds (outermost traces only)",
+    "opt.build_lower_sec": "seconds lowering step jaxprs to modules inside "
+                           "BUILD spans, summed over the job's builds",
+    "opt.build_compile_sec": "seconds in the backend inside BUILD spans, "
+                             "summed over the job's builds: compilation, or "
+                             "the persistent cache's load on a hit",
+    "opt.init_sec": "wall seconds of the last <optimizer>.INIT span: "
+                    "opt.init() to its state in place on the devices",
+    "opt.init_hbm_peak_bytes": "largest peak_bytes_in_use over the mesh's "
+                               "local devices at the end of the last "
+                               "opt.init() (absent where the backend keeps "
+                               "no memory_stats)",
+    "import.total_sec": "seconds `import bluefog_tpu` took in this process "
+                        "(stamped by the package's __init__, rewritten at "
+                        "every bf.init())",
     "opt.pack_sec": "seconds packing the fusion buffer per gossip step",
     "opt.gossip_sec": "seconds in window gossip ops per step",
     "opt.unpack_sec": "seconds unpacking the fusion buffer per step",
@@ -594,13 +619,16 @@ _HELP_PREFIX = (
                    "trace analyzer (microseconds)"),
     ("slo.", "serving-plane SLO series (docs/slo.md)"),
     ("trace.", "serve request-path tracing series (docs/slo.md)"),
+    ("import.", "seconds of `import bluefog_tpu` spent in this import group "
+                "of the package's __init__ (the groups sum to "
+                "import.total_sec)"),
 )
 
 # Instrument-name prefix families the tree may create (first dotted
 # segment). The bfcheck [metrics] analyzer enforces this plus HELP
 # resolution for every creation site in the package — a new family must
 # be added here (with curated HELP coverage) before it can ship.
-_PREFIX_FAMILIES = ("alert", "cp", "flash", "hb", "loop", "loss", "membership", "moe", "opt",
+_PREFIX_FAMILIES = ("alert", "cp", "flash", "hb", "import", "loop", "loss", "membership", "moe", "opt",
                     "pushsum", "serve", "slo", "trace", "tune", "watchdog", "win")
 
 

@@ -117,6 +117,17 @@ def _maybe_init_distributed() -> None:
     )
 
 
+def _publish_import_seconds() -> None:
+    """``import bluefog_tpu`` as the package's ``__init__`` stamped it, by
+    import group, as gauges ``import.<group>_sec`` and ``import.total_sec``:
+    written at every ``init()`` because each zeroes the registry."""
+    import bluefog_tpu
+    from . import metrics as _metrics
+
+    for group, seconds in getattr(bluefog_tpu, "IMPORT_SECONDS", {}).items():
+        _metrics.gauge(f"import.{group}_sec").set(seconds)
+
+
 def init(
     topology_fn=None,
     is_weighted: bool = False,
@@ -161,6 +172,7 @@ def init(
     # counter block re-baselines, so snapshots report this job's deltas.
     from . import metrics as _metrics
     _metrics.reset_for_job()
+    _publish_import_seconds()
     # Fresh live time-series plane (ring history, per-edge estimators,
     # alert-rule state; re-reads BLUEFOG_ALERT_RULES/TS_* knobs).
     from . import timeseries as _timeseries

@@ -18,6 +18,7 @@ queue.SimpleQueue) is the fallback and the semantics are identical.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import json
 import queue
 import threading
@@ -295,6 +296,108 @@ def timeline_context(tensor_name: str, activity: str, tid: int = 0):
         finally:
             if tl is not None:
                 tl.activity_end(tensor_name, tid)
+
+
+# -- the BUILD span of a step program, with what JAX reports inside it -------
+
+@dataclasses.dataclass(frozen=True)
+class BuildRecord:
+    """What building one step program cost: ``StepProgram.build``, filled on
+    the step-cache miss. ``total_s`` is the ``<optimizer>.BUILD`` span, which
+    holds the jitted closure and the program's first call; ``trace_s``,
+    ``lower_s`` and ``compile_s`` are what JAX reported inside it
+    (``jax.monitoring``): the loss traced to a jaxpr (the outermost traces
+    only: an inner ``jit``'s is part of its caller's), the jaxpr lowered to a
+    module, and the backend's part, a compilation or with ``cache_hit`` the
+    load from the persistent cache (``cache_load_s`` of it reading the entry;
+    ``saved_s`` is what JAX says the hit saved)."""
+
+    step: int          # the optimizer's step counter at the miss
+    t_begin_ns: int    # time.perf_counter_ns(), the flight ring's clock
+    total_s: float
+    trace_s: float
+    lower_s: float
+    compile_s: float
+    cache_hit: bool    # every compile request of the build was a cache hit
+    cache_load_s: float
+    saved_s: float
+
+    @property
+    def dispatch_s(self) -> float:
+        """What is left of BUILD: the closure, the arguments' shapes, the
+        program's first dispatch."""
+        return self.total_s - self.trace_s - self.lower_s - self.compile_s
+
+
+class _OpenBuild:
+    """A BUILD span while it is open on a thread: where the two listeners
+    below file events, and after it what the record is made from."""
+
+    def __init__(self) -> None:
+        self.t_begin_ns = time.perf_counter_ns()
+        self.total_s = 0.0
+        self.traces: list = []  # (when it ended, seconds) of the outermost traces
+        self.lower_s = self.compile_s = self.cache_load_s = self.saved_s = 0.0
+        self.compiles = self.cache_hits = 0
+
+    def record(self, step: int) -> BuildRecord:
+        return BuildRecord(
+            step, self.t_begin_ns, self.total_s, sum(s for _, s in self.traces),
+            self.lower_s, self.compile_s,
+            self.compiles > 0 and self.cache_hits >= self.compiles,
+            self.cache_load_s, self.saved_s)
+
+
+_BUILDING = threading.local()  # .open: the thread's _OpenBuild, if any
+
+
+def _on_build_seconds(event: str, seconds: float, **_) -> None:
+    build = getattr(_BUILDING, "open", None)
+    if build is None:  # hlo_text(), a user's own jit, a reference's steps
+        return
+    if event == "/jax/core/compile/jaxpr_trace_duration":
+        # an inner jit's trace ends inside its caller's and is part of it
+        now = time.perf_counter()
+        while build.traces and build.traces[-1][0] > now - seconds:
+            build.traces.pop()
+        build.traces.append((now, seconds))
+    elif event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        build.lower_s += seconds
+    elif event == "/jax/core/compile/backend_compile_duration":
+        build.compile_s += seconds
+        build.compiles += 1
+    elif event == "/jax/compilation_cache/cache_retrieval_time_sec":
+        build.cache_load_s += seconds
+    elif event == "/jax/compilation_cache/compile_time_saved_sec":
+        build.saved_s += seconds
+
+
+def _on_build_event(event: str, **_) -> None:
+    build = getattr(_BUILDING, "open", None)
+    if build is not None and event == "/jax/compilation_cache/cache_hits":
+        build.cache_hits += 1
+
+
+# one pair a process, for good: bf.init() registers nothing, and nothing here
+# calls clear_event_listeners(), which would take other listeners away
+jax.monitoring.register_event_duration_secs_listener(_on_build_seconds)
+jax.monitoring.register_event_listener(_on_build_event)
+
+
+@contextlib.contextmanager
+def build_context(tensor_name: str):
+    """The span ``<tensor_name>.BUILD`` around the building of a step program
+    and its first call. While it is open on this thread, what JAX reports of
+    tracing, lowering, compiling and the persistent cache is filed into the
+    object it yields, whose ``record(step)`` is the :class:`BuildRecord`
+    afterwards. With none open the listeners do nothing."""
+    building = _BUILDING.open = _OpenBuild()
+    try:
+        with timeline_context(tensor_name, "BUILD"):
+            yield building
+    finally:
+        _BUILDING.open = None
+        building.total_s = (time.perf_counter_ns() - building.t_begin_ns) / 1e9
 
 
 def start_timeline(prefix: str) -> bool:

@@ -117,15 +117,18 @@ def test_registry_is_bounded_and_holds_no_device_array(bf4):
     state = opt.init(params())
     args = (np.zeros((1, 1), np.float32), state.params, state.opt_state,
             state.model_state, jnp.ones((N, 4), jnp.float32))
+    sharding = state.params["w"].sharding
     for i in range(20):
-        opt._compile((True, "plan", i), None, True, args)
+        # a build runs the program once, which donates the state: hand it on
+        out = opt._compile((True, "plan", i), None, True, args)
+        args = (args[0],) + tuple(out[:3]) + (args[4],)
     programs = bf.step_programs()
     assert len(programs) == 16
     assert [p.key[-1] for p in programs] == list(range(4, 20))  # oldest first, oldest dropped
     for program in programs:
         leaves = jax.tree_util.tree_leaves(program._avals)
         assert leaves and not any(isinstance(x, jax.Array) for x in leaves)
-        assert program._avals[1]["w"].sharding == state.params["w"].sharding
+        assert program._avals[1]["w"].sharding == sharding
 
 
 def test_hlo_text_outlives_the_optimizer_and_traces_nothing_again(bf4):
@@ -267,6 +270,7 @@ def test_step_span_holds_plan_and_build(bf4, tmp_path):
     names = [e["name"] for e in events if e["ph"] == "B"]
     assert names.count("STEP") == 3 and names.count("PLAN") == 3 and names.count("BUILD") == 1
     # every PLAN and BUILD opens and closes inside a STEP: depth 2 on the lane
+    # (opt.init's INIT stands before the first STEP, outside it)
     depth, inner = 0, []
     for e in events:
         if e["ph"] == "B":
@@ -275,5 +279,5 @@ def test_step_span_holds_plan_and_build(bf4, tmp_path):
         elif e["ph"] == "E":
             depth -= 1
     assert depth == 0
-    assert all(d == (1 if name == "STEP" else 2) for name, d in inner)
-    assert [name for name, _ in inner][:3] == ["STEP", "PLAN", "BUILD"]
+    assert all(d == (1 if name in ("STEP", "INIT") else 2) for name, d in inner)
+    assert [name for name, _ in inner][:4] == ["INIT", "STEP", "PLAN", "BUILD"]
