@@ -1,57 +1,54 @@
 """A decoder-only LM whose block is read from a configuration.
 
-Where :class:`~bluefog_tpu.models.TransformerLM` fixes its block in code, this
-one takes an :class:`LMConfig` -- the keys of a published ``config.json``, of
-the DeepSeek-V3 kind (latent attention, a biased sigmoid router, a shared
-expert, MTP), of the grouped-query kind (SmallThinker: k/v heads shared by
-a group of query heads, layers that differ in mask and rotary, a router that
-reads the block's input) or of the looped kind (Ouro: one stack of layers run
+Where :class:`~bluefog_tpu.models.TransformerLM` fixes its block in code, this one takes an
+:class:`LMConfig` -- the keys of a published ``config.json``, of the DeepSeek-V3 kind (latent
+attention, a biased sigmoid router, a shared expert, MTP), of the grouped-query kind
+(SmallThinker: k/v heads shared by a group of query heads, layers that differ in mask and rotary,
+a router that reads the block's input; Trinity: q/k norms, a gate on attention's output, sandwich
+norms around the expert layer) or of the looped kind (Ouro: one stack of layers run
 ``total_ut_steps`` times on the same weights) -- and builds, layer by layer:
 
-  attention  ``"latent"``: multi-head latent attention (two low-rank paths with
-             an RMSNorm on each latent, a no-rope part per head and one rope
-             part shared by all heads, a q.k width of ``qk_nope + qk_rope`` and
-             a narrower v); ``"equal"``: equal-width heads from one fused
-             projection; ``"grouped"``: separate q, k and v projections,
-             ``num_attention_heads`` query heads of ``head_dim`` over
-             ``num_key_value_heads`` k/v heads (query head h reads k/v head
-             h // group; the attention function is handed k and v at their
-             own head count). Rope turns pairs ``(2i, 2i+1)``
-             (``rope_interleave``) or halves. By layer: ``rope_layout[i]``
-             0 leaves layer i without rotary (NoPE), and
-             ``sliding_window_layout[i]`` 1 gives it a causal window of
-             ``sliding_window`` tokens (the attention function's ``window``)
-             where the others see the whole past.
-  FFN        a SwiGLU of ``intermediate_size`` in the ``first_k_dense_replace``
-             leading layers and wherever there are no experts; otherwise
-             :class:`~bluefog_tpu.parallel.expert.RoutedExperts`: top-k of
-             ``n_routed_experts`` by sigmoid or softmax score, plus (unless
-             ``routing_bias`` is off) a choice-only bias kept in the
-             ``"routing"`` collection and moved by the auxiliary-loss-free
-             balancing rule, not by the optimizer; the experts
-             ``experts_held`` computed here, gated by ``expert_act`` (SiLU or
-             ReLU); ``n_shared_experts`` shared experts or none. The router
-             reads the FFN's normed input (``router_input="ffn"``) or the
+  attention  ``"latent"``: multi-head latent attention (two low-rank paths with an RMSNorm on
+             each latent, a no-rope part per head and one rope part shared by all heads, a q.k
+             width of ``qk_nope + qk_rope`` and a narrower v); ``"equal"``: equal-width heads
+             from one fused projection; ``"grouped"``: separate q, k and v projections,
+             ``num_attention_heads`` query heads of ``head_dim`` over ``num_key_value_heads``
+             k/v heads (query head h reads k/v head h // group; the attention function is
+             handed k and v at their own head count), and with ``qk_norm`` an RMSNorm over
+             ``head_dim`` on q and on k before rotary. Rope turns pairs ``(2i, 2i+1)``
+             (``rope_interleave``) or halves. By layer: ``rope_layout[i]`` 0 leaves layer i
+             without rotary (NoPE), and ``sliding_window_layout[i]`` 1 gives it a causal window
+             of ``sliding_window`` tokens (the attention function's ``window``) where the others
+             see the whole past. ``attn_output_gate``: the attention's output times
+             ``sigmoid(h W_gate)``, elementwise, before the output projection (h the normed
+             input; the sigmoid and the product in float32, rounded once).
+  FFN        a SwiGLU of ``intermediate_size`` in the ``first_k_dense_replace`` leading layers
+             and wherever there are no experts; otherwise
+             :class:`~bluefog_tpu.parallel.expert.RoutedExperts`: top-k of ``n_routed_experts``
+             by sigmoid or softmax score, plus (unless ``routing_bias`` is off) a choice-only
+             bias kept in the ``"routing"`` collection and moved by the auxiliary-loss-free
+             balancing rule, not by the optimizer; the experts ``experts_held`` computed here,
+             gated by ``expert_act`` (SiLU or ReLU); ``n_shared_experts`` shared experts or
+             none. The router reads the FFN's normed input (``router_input="ffn"``) or the
              attention's (``"block"``: its scores are made before attention).
-  MTP        ``num_nextn_predict_layers`` multi-token-prediction modules after
-             the last layer, sharing the embedding and the head.
+  MTP        ``num_nextn_predict_layers`` multi-token-prediction modules after the last layer,
+             sharing the embedding and the head.
 
 and around the layers:
 
-  the loop   ``total_ut_steps`` R > 1 runs the stack R times over the same
-             ``layer_i`` submodules (the parameter tree has L layers, not
-             R x L: a weight's gradient is the sum over its R uses). After
-             every pass the one ``final_norm`` is applied and its output is
-             what the next pass reads; the one head reads it too.
-  sandwich   ``sandwich_norms``: a second norm on what attention returns
-             (``attn_out_norm``) and on what the FFN returns
-             (``ffn_out_norm``), each before its residual add: four norms a
-             layer.
-  exit gate  ``exit_gate``: one ``Linear(hidden -> 1)`` with a bias, shared by
-             the passes, gives a float32 logit a token and pass from the
-             normed state; :func:`exit_distribution` turns the R logits into
-             the probability of leaving after each pass and
-             :func:`looped_exit_loss` is the expected-exit objective.
+  embedding  ``embedding_scale``: a lookup (the trunk's input, an MTP module's next token) is
+             the embedding's output times it.
+  the loop   ``total_ut_steps`` R > 1 runs the stack R times over the same ``layer_i``
+             submodules (the parameter tree has L layers, not R x L: a weight's gradient is the
+             sum over its R uses). After every pass the one ``final_norm`` is applied and its
+             output is what the next pass reads; the one head reads it too.
+  sandwich   ``sandwich_norms``: a second norm on what attention returns (``attn_out_norm``)
+             and on what the FFN returns, dense or the expert layer's routed and shared sum
+             (``ffn_out_norm``), each before its residual add: four norms a layer.
+  exit gate  ``exit_gate``: one ``Linear(hidden -> 1)`` with a bias, shared by the passes,
+             gives a float32 logit a token and pass from the normed state;
+             :func:`exit_distribution` turns the R logits into the probability of leaving
+             after each pass and :func:`looped_exit_loss` is the expected-exit objective.
   recompute  ``remat_layers``: every layer application runs under ``jax.checkpoint``
              (``nn.remat`` of :class:`Layer`) and again in the backward pass, from its
              input. An attention function that takes ``name_residuals`` (the flash kernel)
@@ -59,21 +56,19 @@ and around the layers:
              policy keeps too: its forward kernel does not run again. Memory is state for L
              layers beside R x L inputs and residuals, not R x L applications' activations.
 
-Every norm is an RMSNorm with a learned scale, there are no biases but the
-gate's, and the residuals are sequential. Parameters are float32; ``dtype`` is
-the compute type; router scores, the top-k and the gate are float32 whatever it
-is.
+Every norm is an RMSNorm with a learned scale, no bias but the exit gate's, sequential residuals.
+Parameters are float32; ``dtype`` is the compute type; router scores, the top-k and the exit gate
+are float32 whatever it is.
 
-The parts run under ``jax.named_scope``s a trace reducer can find them by:
-``bf.mla.proj`` (latent and equal-width attention outside its kernels:
-projections, latent norms and rope) or ``bf.attn.proj`` (the same of the
-grouped kind), the two ``bf.flash.*`` of the attention function,
-``bf.moe.route`` / ``bf.moe.experts`` / ``bf.moe.shared``, ``bf.ffn.dense``,
-``bf.lm.head`` (the final norm, the gate, the head and its loss), ``bf.mtp``
-around a whole MTP module and, in a looped or gated model only, ``bf.loop.<t>``
-(t from 0) around everything pass t runs, outside the others. Gauges set while
-tracing (docs/metrics.md): ``loop.passes``, ``loop.layer_applications`` (R x L),
-``loop.recomputed``; by the two losses, ``loss.compare_heads``.
+The parts run under ``jax.named_scope``s a trace reducer can find them by: ``bf.mla.proj``
+(latent and equal-width attention outside its kernels: projections, latent norms and rope) or
+``bf.attn.proj`` (the same of the grouped kind, its q/k norms too), ``bf.attn.gate`` (the output
+gate's product and sigmoid, beside ``bf.attn.proj`` and not in it), the two ``bf.flash.*`` of the
+attention function, ``bf.moe.route`` / ``bf.moe.experts`` / ``bf.moe.shared``, ``bf.ffn.dense``,
+``bf.lm.head`` (the final norm, the exit gate, the head and its loss), ``bf.mtp`` around a whole
+MTP module and, in a looped or exit-gated model only, ``bf.loop.<t>`` (t from 0) around
+everything pass t runs, outside the others. Gauges set while tracing (docs/metrics.md): ``loop.*``,
+``attn.gated_layers``, ``attn.qk_normed_layers``; by the two losses, ``loss.compare_heads``.
 """
 
 from __future__ import annotations
@@ -92,6 +87,7 @@ from ..runtime import metrics
 
 SCOPE_MLA_PROJ = "bf.mla.proj"
 SCOPE_ATTN_PROJ = "bf.attn.proj"   # the grouped kind's, under a name of its own
+SCOPE_ATTN_GATE = "bf.attn.gate"   # the output gate, beside bf.attn.proj
 SCOPE_DENSE_FFN = "bf.ffn.dense"
 SCOPE_HEAD = "bf.lm.head"
 SCOPE_MTP = "bf.mtp"
@@ -139,17 +135,19 @@ class LMConfig:
     sandwich_norms: bool = False         # a norm on attention's and the FFN's output too
     exit_gate: bool = False              # Linear(hidden -> 1) + bias on every pass's state
     remat_layers: bool = False           # jax.checkpoint around every layer application
+    qk_norm: bool = False                # "grouped": an RMSNorm over head_dim on q and on k
+    attn_output_gate: bool = False       # attention's output times sigmoid(h W_gate)
+    embedding_scale: float = 1.0         # an embedding lookup is its output times it
 
     def __post_init__(self):
-        # the loop, the gate and the output norms are the dense block's: an
-        # expert layer sows one set of counters and keeps one routing bias a
-        # step, and an MTP module reads the un-normed trunk
-        looped = self.total_ut_steps > 1 or self.exit_gate
-        if ((looped or self.sandwich_norms) and self.n_routed_experts) or (
-                looped and self.num_nextn_predict_layers):
-            raise ValueError("a looped stack (total_ut_steps > 1, exit_gate) or sandwich norms "
-                             "with expert layers, or a looped stack with MTP modules, is not "
-                             "supported")
+        # the loop and the exit gate are the dense block's: an expert layer sows one set of
+        # counters and keeps one routing bias a step, and an MTP module reads the un-normed trunk
+        if (self.total_ut_steps > 1 or self.exit_gate) and (
+                self.n_routed_experts or self.num_nextn_predict_layers):
+            raise ValueError("a looped stack (total_ut_steps > 1, exit_gate) with expert layers "
+                             "or MTP modules is not supported")
+        if self.qk_norm and self.attention != "grouped":
+            raise ValueError(f"qk_norm is the grouped attention's, not {self.attention!r}")
 
     @classmethod
     def from_dict(cls, doc: dict, **overrides) -> "LMConfig":
@@ -223,6 +221,8 @@ class Attention(nn.Module):
                 q = dense(heads * cfg.head_dim, name="q")(h).reshape(lead + (heads, -1))
                 k = dense(kv_heads * cfg.head_dim, name="k")(h).reshape(lead + (kv_heads, -1))
                 v = dense(kv_heads * cfg.head_dim, name="v")(h).reshape(lead + (kv_heads, -1))
+                if cfg.qk_norm:
+                    q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
                 q, k = turn(q), turn(k)
             elif cfg.attention == "equal":
                 q, k, v = jnp.split(dense(3 * d, name="qkv")(h), 3, axis=-1)
@@ -243,15 +243,15 @@ class Attention(nn.Module):
                     [kv[..., :nope], jnp.broadcast_to(k_rot, lead + (heads, rot))], axis=-1)
                 v = kv[..., nope:]
         a = self.attn_fn(q, k, v)
+        if cfg.attn_output_gate:
+            a = _output_gate(a.reshape(lead + (-1,)), h, dense)
         with jax.named_scope(cfg.proj_scope):
             return dense(d, name="o")(a.reshape(lead + (-1,)))
 
 
 class Layer(nn.Module):
-    """One pre-norm block: attention, then a dense SwiGLU or the expert layer;
-    with ``sandwich_norms`` (dense blocks) each part's output is normed too
-    before its residual add. ``attn_fn`` is the layer's own (its window bound, if it has
-    one)."""
+    """One pre-norm block: attention, then a dense SwiGLU or the expert layer, each part's output
+    normed too before its residual add with ``sandwich_norms``; ``attn_fn`` is the layer's own."""
 
     cfg: LMConfig
     experts: bool
@@ -281,13 +281,14 @@ class Layer(nn.Module):
         x = x + a
         if self.experts:
             h = norm(name="ffn_norm")(x)
-            return x + RoutedExperts(
+            combined = RoutedExperts(
                 num_experts=cfg.n_routed_experts, experts_per_token=cfg.num_experts_per_tok,
                 d_ff=cfg.moe_intermediate_size, held=cfg.held, n_shared=cfg.n_shared_experts,
                 scoring=cfg.scoring_func, scaling=cfg.routed_scaling_factor,
                 bias_update_speed=cfg.bias_update_speed, dtype=self.dtype, interpret=self.interpret,
                 activation=cfg.expert_act, routing_bias=cfg.routing_bias, name="ffn")(
                     h, choice, router_logits)
+            return x + (norm(name="ffn_out_norm")(combined) if cfg.sandwich_norms else combined)
         with jax.named_scope(SCOPE_DENSE_FFN):
             h = norm(name="ffn_norm")(x)
             m = SwiGLU(cfg.intermediate_size, self.dtype, name="ffn")(h)
@@ -297,29 +298,25 @@ class Layer(nn.Module):
 class ConfigLM(nn.Module):
     """Causal LM built from an :class:`LMConfig`.
 
-    ``model.apply({"params": p}, tokens)`` gives the logits ``[B, S, V]`` in
-    float32; with MTP modules it gives ``(logits, mtp_logits)``, where
-    ``mtp_logits[k][:, i]`` predicts token ``i + k + 2`` from the trunk's
-    output at ``i`` and the embeddings of tokens ``i + 1 .. i + k + 1``
-    (``next_tokens [B, S]`` is token ``i + 1`` at position ``i``; by default
-    the sequence rolled by one, whose last position wraps).
+    ``model.apply({"params": p}, tokens)`` gives the logits ``[B, S, V]`` in float32; with MTP
+    modules it gives ``(logits, mtp_logits)``, where ``mtp_logits[k][:, i]`` predicts token
+    ``i + k + 2`` from the trunk's output at ``i`` and the embeddings of tokens ``i + 1 .. i + k
+    + 1`` (``next_tokens [B, S]`` is token ``i + 1`` at position ``i``; by default the sequence
+    rolled by one, whose last position wraps).
 
-    ``attn_fn(q, k, v) -> out`` defaults to dense causal attention;
-    ``partial(flash_attention, causal=True)`` is the kernel path. A layer with
-    a sliding window calls it with ``window=<size>`` bound, and the grouped
-    kind hands it k and v at ``num_key_value_heads``. ``choices``
-    (one ``[B, S, k]`` array of expert ids per expert layer, forward order)
-    forces the experts each token takes. Every expert layer sows its counters
-    and its choice (``mutable=["intermediates"]``; :func:`moe_counters`) and
-    keeps its routing bias in the ``"routing"`` collection, which ``init``
-    returns beside ``"params"`` and ``apply`` takes beside them.
+    ``attn_fn(q, k, v) -> out`` defaults to dense causal attention; ``partial(flash_attention,
+    causal=True)`` is the kernel path. A layer with a sliding window calls it with
+    ``window=<size>`` bound, and the grouped kind hands it k and v at ``num_key_value_heads``.
+    ``choices`` (one ``[B, S, k]`` array of expert ids per expert layer, forward order) forces
+    the experts each token takes. Every expert layer sows its counters and its choice
+    (``mutable=["intermediates"]``; :func:`moe_counters`) and keeps its routing bias in the
+    ``"routing"`` collection, which ``init`` returns beside ``"params"`` and ``apply`` takes too.
 
-    A looped model (``total_ut_steps`` R > 1) gives the last pass's logits;
-    with ``all_passes=True`` it gives ``(states [R, B, S, d], gate_logits
-    [R, B, S])`` instead -- every pass's normed state and, with an exit gate,
-    its float32 logit (else ``None``) -- and leaves the head to the caller
-    (:meth:`head`), so that one pass's ``[T, V]`` logits at a time need be
-    alive (:func:`looped_exit_loss`).
+    A looped model (``total_ut_steps`` R > 1) gives the last pass's logits; with
+    ``all_passes=True`` it gives ``(states [R, B, S, d], gate_logits [R, B, S])`` instead --
+    every pass's normed state and, with an exit gate, its float32 logit (else ``None``) -- and
+    leaves the head to the caller (:meth:`head`), so that one pass's ``[T, V]`` logits at a
+    time need be alive (:func:`looped_exit_loss`).
     """
 
     cfg: LMConfig
@@ -336,8 +333,11 @@ class ConfigLM(nn.Module):
         # recomputed, an application keeps its input and what ``attn`` names
         attn, layer = _recomputed(attn) if cfg.remat_layers else (attn, Layer)
         layer = partial(layer, cfg, dtype=self.dtype, attn_fn=attn, interpret=self.interpret)
-        self.embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=self.dtype,
-                              param_dtype=jnp.float32)
+        blocks = cfg.num_hidden_layers + cfg.num_nextn_predict_layers   # gauges set while tracing
+        metrics.gauge("attn.gated_layers").set(blocks * cfg.attn_output_gate)
+        metrics.gauge("attn.qk_normed_layers").set(blocks * cfg.qk_norm)
+        self.embed = _embedding(cfg)(cfg.vocab_size, cfg.hidden_size, dtype=self.dtype,
+                                     param_dtype=jnp.float32)
         for i in range(cfg.num_hidden_layers):
             own = {} if cfg.window_of(i) is None else {
                 "attn_fn": partial(attn, window=cfg.window_of(i))}
@@ -631,3 +631,30 @@ def _kept_residual_bytes(model: ConfigLM, tokens_shape) -> int:
     applications = cfg.total_ut_steps * cfg.num_hidden_layers + cfg.num_nextn_predict_layers
     return applications * tokens_shape[0] * tokens_shape[1] * (
         words * jnp.dtype(model.dtype).itemsize + 2 * 4 * heads)
+
+
+def _output_gate(a, h, dense):
+    """``a * sigmoid(h W_gate)`` of attention's output ``a [..., Hq * Dv]`` and its normed input
+    ``h`` under ``bf.attn.gate``: ``W_gate`` the parameter ``gate`` ``[d, Hq * Dv]``, no bias,
+    made by ``dense`` (the caller's ``nn.Dense`` in the compute type); the sigmoid and the
+    product in float32, rounded once to ``a``'s dtype."""
+    with jax.named_scope(SCOPE_ATTN_GATE):
+        g = dense(a.shape[-1], name="gate")(h).astype(jnp.float32)
+        return (a.astype(jnp.float32) * jax.nn.sigmoid(g)).astype(a.dtype)
+
+
+class _ScaledEmbed(nn.Embed):
+    """``nn.Embed`` whose lookups are its output times ``scale``, in the compute type."""
+
+    scale: float = 1.0
+
+    def __call__(self, inputs):
+        return super().__call__(inputs) * self.scale
+
+
+def _embedding(cfg: LMConfig):
+    """The embedding's module: ``nn.Embed`` itself at ``embedding_scale`` 1, where the traced
+    program holds no product."""
+    if cfg.embedding_scale == 1.0:
+        return nn.Embed
+    return partial(_ScaledEmbed, scale=cfg.embedding_scale)

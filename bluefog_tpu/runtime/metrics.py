@@ -596,6 +596,13 @@ _HELP_EXACT: Dict[str, str] = {
                                 "the output and the f32 row max and sum an "
                                 "application), so the forward kernel does "
                                 "not run again; 0 where nothing is kept",
+    "attn.gated_layers": "layers of the last ConfigLM traced whose attention "
+                         "output is gated, o * sigmoid(h W_gate) under "
+                         "bf.attn.gate (LMConfig.attn_output_gate; MTP "
+                         "blocks counted), else 0",
+    "attn.qk_normed_layers": "layers of that model with an RMSNorm over "
+                             "head_dim on q and on k before rotary "
+                             "(LMConfig.qk_norm), else 0",
     "loss.compare_heads": "head cross-entropies of the last ConfigLM loss "
                           "traced (next_token_loss: 1 + MTP modules; "
                           "looped_exit_loss: passes), each "
@@ -634,7 +641,7 @@ _HELP_PREFIX = (
 # segment). The bfcheck [metrics] analyzer enforces this plus HELP
 # resolution for every creation site in the package — a new family must
 # be added here (with curated HELP coverage) before it can ship.
-_PREFIX_FAMILIES = ("alert", "cp", "flash", "hb", "import", "loop", "loss", "membership", "moe", "opt",
+_PREFIX_FAMILIES = ("alert", "attn", "cp", "flash", "hb", "import", "loop", "loss", "membership", "moe", "opt",
                     "pushsum", "serve", "slo", "trace", "tune", "watchdog", "win")
 
 

@@ -632,16 +632,33 @@ def test_k_and_v_reach_the_kernels_at_their_own_head_count(gqa_toy):
 
 @pytest.mark.parametrize("keys", [
     {"total_ut_steps": 2, "n_routed_experts": 8}, {"exit_gate": True, "n_routed_experts": 8},
-    {"sandwich_norms": True, "n_routed_experts": 8},
     {"total_ut_steps": 2, "num_nextn_predict_layers": 1},
     {"exit_gate": True, "num_nextn_predict_layers": 1}], ids=lambda keys: "+".join(keys))
 def test_a_loop_over_expert_layers_or_mtp_modules_is_refused(keys):
-    """The loop, the gate and the output norms are the dense block's: the
-    counters and the balancing step are a layer's, not an application's, and
-    an MTP module reads the un-normed trunk. Said at construction."""
+    """The loop and the exit gate are the dense block's: the counters and the
+    balancing step are a layer's, not an application's, and an MTP module
+    reads the un-normed trunk. Said at construction."""
     with pytest.raises(ValueError, match="looped stack"):
         LMConfig(vocab_size=64, hidden_size=32, num_hidden_layers=2, num_attention_heads=4,
                  intermediate_size=48, **keys)
+
+
+def test_sandwich_norms_with_expert_layers_build_and_run():
+    """Once refused with the loop, now the expert branch's own: ``ffn_out_norm`` on
+    the routed and shared sum before its residual add, as on a dense SwiGLU."""
+    cfg = LMConfig(vocab_size=64, hidden_size=32, num_hidden_layers=2, num_attention_heads=4,
+                   intermediate_size=48, attention="equal", first_k_dense_replace=1,
+                   n_routed_experts=8, num_experts_per_tok=2, moe_intermediate_size=16,
+                   sandwich_norms=True)
+    net = ConfigLM(cfg, interpret=True)
+    tokens = jax.random.randint(jax.random.PRNGKey(30), (2, 16), 0, 64)
+    variables = net.init(jax.random.PRNGKey(31), tokens)
+    for layer in ("layer_0", "layer_1"):                # dense, then the expert layer
+        assert {"attn_out_norm", "ffn_out_norm"} <= set(variables["params"][layer])
+    loss, (routing, counters) = next_token_loss(net)(
+        variables["params"], variables["routing"], (tokens, jnp.roll(tokens, -1, axis=1)))
+    assert np.isfinite(float(loss)) and int(counters["rows_overflowed"]) == 0
+    assert int(counters["rows_routed"]) == 2 * 16 * 2     # every slot: all 8 experts held
 
 
 def test_the_new_keys_are_off_by_default_and_each_adds_only_its_own_parameters():
@@ -778,3 +795,234 @@ def test_no_logit_is_gathered_or_scattered_between_a_head_and_its_gradients(toy,
                         optax.softmax_cross_entropy_with_integer_labels)
     found = [name for name, _ in tokens_by_vocab_movers(step(), params, routing, batch, **size)]
     assert sorted(found) == ["gather", "gather", "scatter-add", "scatter-add"], found
+
+
+# -- the gated grouped-query block with sandwich-normed sigmoid experts (Trinity) ----------
+# ``benchmark/families/gated_gqa_moe_lm.py`` keeps the plain float32 reference of this block;
+# it shares no code with ``bluefog_tpu``.
+
+def _trinity_family():
+    path = os.path.join(ROOT, "benchmark", "families", "gated_gqa_moe_lm.py")
+    spec = importlib.util.spec_from_file_location("gated_gqa_moe_lm_family", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+TRINITY = _trinity_family()
+
+# the published block at toy widths: 8 query heads over 2 k/v heads of 8 (32 over 4 of 128),
+# a 16-token window under 64 tokens, one dense layer and four expert layers of the published
+# layer_types (S, S, S, F, S), experts [2, 4) of 16 held (share 1 of 8), top-4, a shared expert
+with open(os.path.join(ROOT, "benchmark", "tests", "toy", "toy-trinity.json")) as _f:
+    TRINITY_TOY = json.load(_f)
+TRINITY_BATCH = {"sequences": 2, "seq_len": 64}
+
+
+@pytest.fixture(scope="module")
+def trinity_toy():
+    """(cfg, params, routing biases, batch of one rank) from fixed seeds."""
+    params, routing = TRINITY.init(TRINITY_TOY, TRINITY_BATCH, jax.random.PRNGKey(0))
+    batch = jax.tree_util.tree_map(
+        lambda x: x[0], TRINITY.make_batch(TRINITY_TOY, TRINITY_BATCH, jax.random.PRNGKey(1), 1))
+    return TRINITY_TOY, params, routing, batch
+
+
+def _trinity_system_loss(cfg, params, routing, batch, lm=None):
+    """The program's loss, of ``lm`` (an ``LMConfig``) where given, else of the family's."""
+    if lm is None:
+        return TRINITY.loss(cfg)[0](params, routing, batch)[0]
+    net = ConfigLM(lm, interpret=True, attn_fn=partial(flash_attention, causal=True,
+                                                      interpret=True))
+    return next_token_loss(net)(params, routing, batch)[0]
+
+
+def _trinity_plain(cfg, params, routing, batch):
+    return jax.value_and_grad(partial(TRINITY.plain_loss, cfg))(params, routing, batch)
+
+
+def test_the_gated_block_has_its_own_parameters_and_gauges(trinity_toy):
+    cfg, params, routing, _ = trinity_toy
+    assert set(params["layer_0"]) == {"attn_norm", "attn", "attn_out_norm", "ffn_norm", "ffn",
+                                      "ffn_out_norm"}
+    assert set(params["layer_1"]) == set(params["layer_0"])     # sandwich norms, experts too
+    attn = params["layer_1"]["attn"]
+    assert set(attn) == {"q", "k", "v", "q_norm", "k_norm", "gate", "o"}
+    assert attn["gate"]["kernel"].shape == attn["q"]["kernel"].shape == (32, 64)
+    assert attn["q_norm"]["scale"].shape == attn["k_norm"]["scale"].shape == (8,)
+    assert set(params["layer_1"]["ffn"]) == {"router", "gate", "up", "down", "shared"}
+    assert params["layer_1"]["ffn"]["router"].shape == (32, 16)
+    assert set(routing) == {f"layer_{i}" for i in range(1, 5)}    # the dense layer has none
+    lm = TRINITY.lm_config(cfg)
+    assert [lm.window_of(i) for i in range(5)] == [16, 16, 16, None, 16]
+    assert [lm.rotary_in(i) for i in range(5)] == [True, True, True, False, True]
+    assert lm.embedding_scale == pytest.approx(32 ** 0.5)
+    metrics.gauge("attn.gated_layers").set(0)
+    metrics.gauge("attn.qk_normed_layers").set(0)
+    jax.eval_shape(TRINITY.model(cfg).apply, {"params": params, "routing": routing},
+                   jnp.zeros((1, 64), jnp.int32))
+    assert metrics.gauge("attn.gated_layers").value == 5
+    assert metrics.gauge("attn.qk_normed_layers").value == 5
+
+
+def test_gated_logits_loss_and_gradients_match_the_plain_reference(trinity_toy):
+    """Float32 on both sides at the highest matmul precision, differing in the order of
+    their sums only: the limits of the blocks above."""
+    cfg, params, routing, batch = trinity_toy
+    tokens = batch[0][:1]
+    logits = TRINITY.model(cfg).apply({"params": params, "routing": routing}, tokens)
+    want, _ = TRINITY.plain_forward(cfg, params, routing, tokens, positions=64)
+    assert _rel(logits, want) <= 2e-5
+    loss, grads = jax.value_and_grad(partial(_trinity_system_loss, cfg))(params, routing, batch)
+    want_loss, want_grads = _trinity_plain(cfg, params, routing, batch)
+    assert abs(float(loss) - float(want_loss)) <= LOSS_RTOL * float(want_loss)
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, got), want in zip(flat, jax.tree_util.tree_leaves(want_grads)):
+        assert _rel(got, want) <= GRAD_RTOL, (jax.tree_util.keystr(path), _rel(got, want))
+    # the gate, the q/k norms and both output norms of every layer get a gradient
+    for i in range(5):
+        layer = grads[f"layer_{i}"]
+        for leaf in (layer["attn"]["gate"]["kernel"], layer["attn"]["q_norm"]["scale"],
+                     layer["attn"]["k_norm"]["scale"], layer["ffn_out_norm"]["scale"]):
+            assert float(jnp.max(jnp.abs(leaf))) > 0
+
+
+def test_the_toy_trinity_through_opt_step_matches_the_plain_reference(trinity_toy, bf8):
+    """``DistributedNeighborAllreduceOptimizer(sgd(1)).step`` on eight ranks that hold the same
+    parameters and take the same batch: ``before - after`` is the gradient the step computed,
+    against ``jax.grad`` of the plain float32 loss (the limit of the grouped block's test);
+    the routing biases come back moved by the balancing rule alone."""
+    cfg, params, routing, batch = trinity_toy
+    loss_fn, form = TRINITY.loss(cfg)
+    opt = bf.DistributedNeighborAllreduceOptimizer(optax.sgd(1.0), loss_fn, **form)
+    state = opt.init(params, model_state=routing)
+    stacked = jax.tree_util.tree_map(lambda x: jnp.broadcast_to(x, (8,) + x.shape), batch)
+    state, step_metrics = opt.step(state, stacked)
+    want_loss, want_grads = _trinity_plain(cfg, params, routing, batch)
+    np.testing.assert_allclose(np.asarray(step_metrics["loss"]), float(want_loss),
+                               rtol=LOSS_RTOL)
+    after = jax.device_get(state.params)
+    flat = jax.tree_util.tree_flatten_with_path(params)[0]
+    for (path, before), new, want in zip(flat, jax.tree_util.tree_leaves(after),
+                                         jax.tree_util.tree_leaves(want_grads)):
+        for rank in (0, 7):
+            got = np.asarray(before) - new[rank]
+            assert _rel(got, want) <= GRAD_RTOL + 1e-4, (jax.tree_util.keystr(path), rank)
+    for leaf, before in zip(jax.tree_util.tree_leaves(jax.device_get(state.model_state)),
+                            jax.tree_util.tree_leaves(routing)):
+        moved = (leaf - np.asarray(before)[None]) / cfg["load_balance_coeff"]
+        np.testing.assert_allclose(moved, np.round(moved), atol=2e-3)
+        assert np.abs(moved).max() <= 1 + 2e-3 and np.any(moved != 0)
+    aux = jax.device_get(step_metrics["aux"])
+    assert np.all(aux["rows_overflowed"] == 0) and np.all(aux["rows_routed"] > 0)
+
+
+def test_the_eight_shares_of_a_sigmoid_layer_with_a_shared_expert_add_up_to_the_uncut_layer():
+    """The guide's share test at Trinity's shape: the routed parts of the eight shares of 2 of
+    a 16-expert layer (sigmoid scores, a choice-only bias, weights normalised and scaled by
+    2.826), with the shared expert -- which every chip computes alike -- counted once, are the
+    uncut plain layer's output."""
+    d, f, experts, top = 32, 16, 16, 4
+    layer = lambda held: expert.RoutedExperts(  # noqa: E731
+        num_experts=experts, experts_per_token=top, d_ff=f, held=held, n_shared=1,
+        scoring="sigmoid", scaling=2.826, interpret=True)
+    u = jax.random.normal(jax.random.PRNGKey(32), (1, 48, d), jnp.float32)
+    variables = layer((0, experts)).init(jax.random.PRNGKey(33), u)
+    params, bias = variables["params"], variables["routing"]["bias"]
+    uncut = {**TRINITY_TOY, "num_experts": experts, "published": {"num_experts": experts},
+             "deployment": {"share": 0}, "num_experts_per_tok": top}
+    want, _ = TRINITY._expert_layer(uncut, params, bias, u[0])
+    shared = expert.SwiGLU(f).apply({"params": params["shared"]}, u)
+    total = shared
+    for r in range(8):
+        out = layer((2 * r, 2 * r + 2)).apply(_share_of(variables, 2 * r, 2 * r + 2), u)
+        total = total + (out - shared)
+    assert _rel(total[0], want) <= 1e-5
+    assert _rel(out[0], want) > 1e-2                      # one share alone is not it
+    assert _rel((total + shared)[0], want) > 1e-2         # nor the shared expert counted twice
+
+
+def _new_key_trees(key):
+    base = LMConfig(vocab_size=64, hidden_size=32, num_hidden_layers=2, num_attention_heads=4,
+                    intermediate_size=48, attention="grouped", num_key_value_heads=2, head_dim=8)
+    tokens = jnp.zeros((1, 16), jnp.int32)
+    tree = lambda cfg: ConfigLM(cfg).init(jax.random.PRNGKey(0), tokens)["params"]  # noqa: E731
+    changed = {"qk_norm": True, "attn_output_gate": True, "embedding_scale": 4.0}[key]
+    return base, tree(base), tree(dataclasses.replace(base, **{key: changed}))
+
+
+@pytest.mark.parametrize("key", ["qk_norm", "attn_output_gate", "embedding_scale"])
+def test_each_new_key_is_off_by_default_and_adds_only_its_own_parameters(key):
+    base, before, after = _new_key_trees(key)
+    assert (base.qk_norm, base.attn_output_gate, base.embedding_scale) == (False, False, 1.0)
+    added = {"qk_norm": {"q_norm", "k_norm"}, "attn_output_gate": {"gate"},
+             "embedding_scale": set()}[key]
+    for layer in ("layer_0", "layer_1"):
+        assert set(after[layer]["attn"]) - set(before[layer]["attn"]) == added
+        assert set(before[layer]["attn"]) <= set(after[layer]["attn"])
+        assert set(after[layer]) == set(before[layer])
+    assert set(after) == set(before)
+    if key == "attn_output_gate":
+        assert after["layer_0"]["attn"]["gate"]["kernel"].shape == (32, 32)   # d -> Hq * D
+    if key == "embedding_scale":
+        assert jax.tree_util.tree_structure(after) == jax.tree_util.tree_structure(before)
+        tokens = jnp.arange(16)[None]
+        cfg = dataclasses.replace(base, embedding_scale=4.0)
+        got = ConfigLM(cfg).apply({"params": before}, tokens, method=lambda m, t: m.embed(t))
+        want = before["embed"]["embedding"][tokens] * 4.0
+        assert _rel(got, want) <= 1e-6
+
+
+@pytest.mark.parametrize("attention", ["latent", "equal"])
+def test_a_qk_norm_outside_grouped_attention_is_refused(attention):
+    with pytest.raises(ValueError, match="qk_norm"):
+        LMConfig(vocab_size=64, hidden_size=32, num_hidden_layers=2, num_attention_heads=4,
+                 intermediate_size=48, attention=attention, qk_norm=True)
+
+
+def _skip_modules(*names, experts_only=False):
+    """A flax interceptor under which the modules of ``names`` return their input."""
+    from flax import linen as nn
+
+    def rule(next_fun, args, kwargs, context):
+        module = context.module
+        if module.name in names and (not experts_only or getattr(module.parent, "experts", False)):
+            return args[0]
+        return next_fun(*args, **kwargs)
+
+    return nn.intercept_methods(rule)
+
+
+def _gate_in_bfloat16(a, h, dense):
+    with jax.named_scope(config_lm.SCOPE_ATTN_GATE):
+        g = dense(a.shape[-1], name="gate")(h).astype(jnp.bfloat16)
+        return (a.astype(jnp.bfloat16) * jax.nn.sigmoid(g)).astype(a.dtype)
+
+
+@pytest.mark.parametrize("fault", [
+    "gate dropped", "q/k norm dropped", "expert output norm dropped", "no sqrt(d) scale",
+    "rotary on the full layers", "gate in bfloat16"])
+def test_the_gated_comparison_is_tight_enough_to_see(fault, trinity_toy, monkeypatch):
+    """The wrong-model controls: the system computes with the fault, the reference without,
+    and the loss moves by more than ten tolerances."""
+    cfg, params, routing, batch = trinity_toy
+    want = float(TRINITY.plain_loss(cfg, params, routing, batch))
+    lm, context = None, None
+    if fault == "gate dropped":
+        monkeypatch.setattr(config_lm, "_output_gate", lambda a, h, dense: a)
+    elif fault == "gate in bfloat16":
+        monkeypatch.setattr(config_lm, "_output_gate", _gate_in_bfloat16)
+    elif fault == "q/k norm dropped":
+        context = _skip_modules("q_norm", "k_norm")
+    elif fault == "expert output norm dropped":
+        context = _skip_modules("ffn_out_norm", experts_only=True)
+    elif fault == "no sqrt(d) scale":
+        lm = dataclasses.replace(TRINITY.lm_config(cfg), embedding_scale=1.0)
+    else:
+        lm = dataclasses.replace(TRINITY.lm_config(cfg), rope_layout=(1,) * 5)
+    if context is None:
+        got = float(_trinity_system_loss(cfg, params, routing, batch, lm))
+    else:
+        with context:
+            got = float(_trinity_system_loss(cfg, params, routing, batch, lm))
+    assert abs(got - want) > 10 * LOSS_RTOL * want, (got, want)
