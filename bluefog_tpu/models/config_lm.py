@@ -437,15 +437,15 @@ def _sowed(intermediates, name: str) -> list:
 def moe_counters(intermediates) -> dict:
     """The expert layers' counters of one forward pass, from the collection a
     ``mutable=["intermediates"]`` apply returns: ``rows_routed``,
-    ``rows_overflowed`` and ``tiles_in_use`` summed over the layers,
-    ``load_max_over_mean`` the largest of them. Empty for a model without
-    expert layers."""
+    ``rows_overflowed``, ``tiles_in_use`` and ``gather_trips`` summed over
+    the layers, ``load_max_over_mean`` the largest of them. Empty for a model
+    without expert layers."""
     layers = _sowed(intermediates, "moe_counters")
     if not layers:
         return {}
-    return {"rows_routed": sum(c["rows_routed"] for c in layers),
-            "rows_overflowed": sum(c["rows_overflowed"] for c in layers),
-            "tiles_in_use": sum(c["tiles_in_use"] for c in layers),
+    summed = ("rows_routed", "rows_overflowed",
+              "tiles_in_use", "gather_trips")
+    return {**{key: sum(c[key] for c in layers) for key in summed},
             "load_max_over_mean": jnp.max(jnp.stack(
                 [c["load_max_over_mean"] for c in layers]))}
 

@@ -19,16 +19,19 @@ bias, a shared expert, no token dropped): it is told which experts it holds,
 scores all of them, and computes its own experts' part of the result with
 grouped matrix products over ragged per-expert row counts. Rows enter the
 held experts' buffer and leave it through a pair of movers (:func:`rows_in`,
-:func:`rows_out`) that walk the buffer in chunks of whole tiles and stop with
-the last tile in use, as the grouped products do: the buffer's size costs its
-zero fills, not passes over it. On one chip it runs without an exchange; the
-all-to-all that would bring other chips' tokens is not here.
+:func:`rows_out`): what goes into the buffer walks it in chunks of whole tiles
+and stops with the last tile in use, as the grouped products do, so the
+buffer's size costs its zero fills, not passes over it; what comes back into
+the tokens is gathered, a pass for each of a chunk of tokens' rows, over a
+map of each token's rows (:class:`TokenRows`), and never scattered. On one
+chip it runs without an exchange; the all-to-all that would bring other
+chips' tokens is not here.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional, Sequence, Tuple
+from typing import Any, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -521,16 +524,24 @@ def _grouped_matmul_bwd(interpret, res, g):
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
-# Tiles a trip of the row movers' loops walks: 1,024 rows. Small against the
-# rows a layer routes here (the rounding to whole chunks stays a few per cent
-# of them), large enough that a trip's gather or scatter moves megabytes.
+# Tiles a trip of the loops that walk the buffer takes: 1,024 rows. Small
+# against the rows a layer routes here (the rounding to whole chunks stays a
+# few per cent of them), large enough that a trip's gather moves megabytes.
 CHUNK_TILES = 8
+# Tokens a trip of the loop back into the tokens takes: a chunk's rows, likewise.
+TOKEN_CHUNK = 1024
 
 
 def chunk_tiles(rows: int) -> int:
     """Tiles of a chunk of a buffer of ``rows`` rows: ``CHUNK_TILES``, or the
     whole of a smaller buffer."""
     return min(CHUNK_TILES, rows // ROW_TILE)
+
+
+def token_chunk(tokens: int) -> int:
+    """Tokens a trip of the loop that adds rows back into tokens takes:
+    ``TOKEN_CHUNK``, or all of fewer tokens."""
+    return min(TOKEN_CHUNK, tokens)
 
 
 def _fill(shape, dtype, *like):
@@ -561,21 +572,17 @@ def _walk(tiles_used, rows: int, body, init):
     return lax.fori_loop(0, trips, trip, init)
 
 
-def rows_in(xt, token, valid, tiles_used):
+@jax.custom_vjp
+def rows_in(xt, token, valid, tiles_used, token_rows):
     """The gather into the held experts' buffer: row ``i`` of the result
     ``[rows, d]`` is ``xt[token[i]]`` where ``valid[i]`` and zero elsewhere,
     for the rows of the first ``tiles_used`` tiles (``token``, ``valid``,
-    ``tiles_used`` as :func:`dispatch_held` gives them); the rows past them
-    are zero. Walked in chunks of :func:`chunk_tiles` tiles that stop with the
-    last tile in use, here and in the gradient (the scatter-add of the rows'
-    cotangent into ``[T, d]``, summed in float32)."""
-    return _rows_in(xt.shape[0], xt, token, valid, tiles_used)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _rows_in(tokens: int, xt, token, valid, tiles_used):
-    del tokens  # the gradient's row count
-
+    ``tiles_used``, ``token_rows`` as :func:`dispatch_held` gives them); the
+    rows past them are zero. Walked in chunks of :func:`chunk_tiles` tiles
+    that stop with the last tile in use. The gradient adds the rows'
+    cotangent back into ``[T, d]`` in float32 by gathers over ``token_rows``
+    (:func:`_to_tokens`): a gather a row routed here and one of every token,
+    where a scatter-add would move the rows one at a time."""
     def chunk(start, n, fresh, out):
         del fresh  # a row walked twice is written the same twice
         rows = jnp.where(lax.dynamic_slice_in_dim(valid, start, n)[:, None],
@@ -586,55 +593,41 @@ def _rows_in(tokens: int, xt, token, valid, tiles_used):
     return _walk(tiles_used, rows, chunk, _fill((rows, xt.shape[1]), xt.dtype, xt, token))
 
 
-def _rows_in_fwd(tokens, xt, token, valid, tiles_used):
-    return _rows_in(tokens, xt, token, valid, tiles_used), (token, valid, tiles_used)
+def _rows_in_fwd(xt, token, valid, tiles_used, token_rows):
+    return rows_in(xt, token, valid, tiles_used, token_rows), token_rows
 
 
-def _rows_in_bwd(tokens, res, g):
-    token, valid, tiles_used = res
-
-    def chunk(start, n, fresh, acc):
-        keep = lax.dynamic_slice_in_dim(valid, start, n) & fresh
-        return acc.at[lax.dynamic_slice_in_dim(token, start, n)].add(
-            jnp.where(keep[:, None], lax.dynamic_slice_in_dim(g, start, n), 0).astype(jnp.float32))
-
-    acc = _walk(tiles_used, g.shape[0], chunk,
-                _fill((tokens, g.shape[1]), jnp.float32, g, token))
-    return acc.astype(g.dtype), None, None, None
+def _rows_in_bwd(token_rows, g):
+    return _to_tokens(g, token_rows), None, None, None, None
 
 
-_rows_in.defvjp(_rows_in_fwd, _rows_in_bwd)
+rows_in.defvjp(_rows_in_fwd, _rows_in_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def rows_out(y, row_weight, token, tiles_used, tokens: int):
-    """The weighted scatter back: ``[tokens, d]`` in ``y``'s type, row ``t`` the
-    float32 sum of ``row_weight[i] * y[i]`` over the buffer's rows ``i`` with
-    ``token[i] == t`` (``row_weight`` is zero on padding), taken over the first
-    ``tiles_used`` tiles in chunks of :func:`chunk_tiles` tiles.
+@jax.custom_vjp
+def rows_out(y, row_weight, token, tiles_used, token_rows):
+    """The weighted sum back: ``[T, d]`` in ``y``'s type, row ``t`` the float32
+    sum of ``row_weight[i] * y[i]`` over the buffer's rows ``i`` with
+    ``token[i] == t`` (``row_weight`` is zero on padding), added in ascending
+    ``i`` and rounded once, by gathers over ``token_rows`` (:func:`_to_tokens`,
+    the weights gathered beside the rows): a gather a row routed here and one
+    of every token, where a scatter-add would move the rows one at a time.
 
     **``y`` has to be zero past the tiles in use**, as :func:`grouped_matmul`
     leaves its result: the value does not read those rows, but the gradient
-    walks the same chunks and writes ``d_y`` over ``y``, which it needs no
-    longer (no second buffer, no fill) -- ``d_y = g[token] * row_weight`` up to
-    the last chunk in use and ``y``'s own rows past it, which are the zeros
-    ``d_y`` holds there only if ``y`` did. ``d_row_weight = sum(g[token] * y,
-    -1)``."""
-    def chunk(start, n, fresh, acc):
-        weight = jnp.where(fresh, lax.dynamic_slice_in_dim(row_weight, start, n), 0)
-        return acc.at[lax.dynamic_slice_in_dim(token, start, n)].add(
-            lax.dynamic_slice_in_dim(y, start, n).astype(jnp.float32) * weight[:, None])
-
-    return _walk(tiles_used, y.shape[0], chunk,
-                 _fill((tokens, y.shape[1]), jnp.float32, y, row_weight, token)).astype(y.dtype)
+    walks the buffer in chunks of :func:`chunk_tiles` tiles up to the last
+    tile in use and writes ``d_y`` over ``y``, which it needs no longer (no
+    second buffer, no fill) -- ``d_y = g[token] * row_weight`` up to the last
+    chunk in use and ``y``'s own rows past it, which are the zeros ``d_y``
+    holds there only if ``y`` did. ``d_row_weight = sum(g[token] * y, -1)``."""
+    return _to_tokens(y, token_rows, row_weight)
 
 
-def _rows_out_fwd(y, row_weight, token, tiles_used, tokens):
-    return rows_out(y, row_weight, token, tiles_used, tokens), (y, row_weight, token, tiles_used)
+def _rows_out_fwd(y, row_weight, token, tiles_used, token_rows):
+    return rows_out(y, row_weight, token, tiles_used, token_rows), (y, row_weight, token, tiles_used)
 
 
-def _rows_out_bwd(tokens, res, g):
-    del tokens
+def _rows_out_bwd(res, g):
     y, row_weight, token, tiles_used = res
 
     def chunk(start, n, fresh, carry):
@@ -654,7 +647,7 @@ def _rows_out_bwd(tokens, res, g):
 
     d_y, d_weight = _walk(tiles_used, y.shape[0], chunk,
                           (y, _fill(row_weight.shape, jnp.float32, y, row_weight, token, g)))
-    return d_y, d_weight.astype(row_weight.dtype), None, None
+    return d_y, d_weight.astype(row_weight.dtype), None, None, None
 
 
 rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
@@ -671,17 +664,15 @@ def routed_rows_bound(tokens: int, experts_per_token: int, held: int,
     router's choice is skewed by it, and the fullest of 32 shares of 8 experts
     was measured at up to 2.84 times the uniform share (PERF.md section 6, PR 27).
     The bound sizes the buffer, and the buffer's size costs memory and zero
-    fills only: the row movers and the grouped products stop with the tiles in
-    use (:func:`rows_in`, :func:`rows_out`, :func:`grouped_matmul`). What the
-    movers cost follows ``tiles_in_use / moe.buffer_tiles``, and not in the
-    movers' favour everywhere: XLA:TPU adds a chunk's rows into ``[T, d]`` one
-    row at a time and a whole buffer's after one sort, so the chunked walk is
-    the cheaper one while under about 45 % of the buffer's tiles are in use,
-    costs the same there, and costs more past it (with the buffer full a
-    layer's movers take 1.75 times the whole-buffer expressions they replaced;
-    PERF.md section 6, PR 33). A deployment whose held experts fill more than
-    that half of the bound, step after step, wants a wider bound or the gather
-    form of the two scatters described there."""
+    fills only: the grouped products and the two walks over the buffer (the
+    gather into it, :func:`rows_out`'s gradient) stop with the tiles in use
+    (:func:`grouped_matmul`, :func:`rows_in`, :func:`rows_out`), and the way
+    back into the tokens costs what is routed, not the buffer: a gather of a
+    row for each (chunk of tokens, pass) trip, ``gather_trips`` of them, about
+    rows routed / :func:`token_chunk` plus a chunk count, and one gather of
+    every token back into its own order. No row is scattered: XLA:TPU adds a
+    scatter's rows one at a time, 0.29--0.36 us a 10 KB row, where a gather
+    moves one in 33--60 ns (PERF.md section 6, PR 33)."""
     slots = tokens * experts_per_token
     return min(slots, -(-4 * slots * held // num_experts))
 
@@ -729,15 +720,18 @@ def dispatch_held(ids, held: Tuple[int, int], bound: int):
     slots that chose a held expert are laid out in expert order, every
     expert's rows starting on a tile and an expert without rows still given
     one tile, so that a tile is all one expert's. Returns ``(slot [rows],
-    valid [rows], tile_expert [tiles], tiles_used [1], counters)``: row ``i``
-    is slot ``slot[i]`` of the flattened ``[T * k]`` choices (token
-    ``slot // k``) where ``valid``, and padding elsewhere; ``counters`` are
-    scalars -- ``rows_routed`` (slots that chose a held expert),
-    ``load_max_over_mean`` (the fullest held expert's rows over the mean),
-    ``rows_overflowed`` (slots past ``bound``, cut from the end of the expert
-    order: their contribution is lost, and this is where it shows),
-    ``tiles_in_use`` (``tiles_used``: where the grouped products and the row
-    movers stop)."""
+    valid [rows], tile_expert [tiles], tiles_used [1], counters,
+    token_rows)``: row ``i`` is slot ``slot[i]`` of the flattened ``[T * k]``
+    choices (token ``slot // k``) where ``valid``, and padding elsewhere;
+    ``counters`` are scalars -- ``rows_routed`` (slots that chose a held
+    expert), ``load_max_over_mean`` (the fullest held expert's rows over the
+    mean), ``rows_overflowed`` (slots past ``bound``, cut from the end of the
+    expert order: their contribution is lost, and this is where it shows),
+    ``tiles_in_use`` (``tiles_used``: where the grouped products and the
+    loops that walk the buffer stop), ``gather_trips`` (the trips of the loop
+    that adds rows back into tokens, the sum of ``token_rows.passes``);
+    ``token_rows`` (:class:`TokenRows`) is the way back, from a sort of each
+    token's ``k`` rows and one sort of the tokens by their count of rows."""
     lo, hi = held
     n_held = hi - lo
     rows = buffer_rows(bound, n_held)
@@ -745,7 +739,8 @@ def dispatch_held(ids, held: Tuple[int, int], bound: int):
     mine = (local >= 0) & (local < n_held)
     key = jnp.where(mine, local, n_held)            # the others sort last
     order = jnp.argsort(key, stable=True)
-    sizes = jnp.sum(key[:, None] == jnp.arange(n_held), axis=0, dtype=jnp.int32)
+    of_expert = key[:, None] == jnp.arange(n_held)  # [T * k, held]
+    sizes = jnp.sum(of_expert, axis=0, dtype=jnp.int32)
     routed = jnp.sum(sizes)
     starts = jnp.cumsum(sizes) - sizes              # of each expert in ``order``
     kept = jnp.minimum(jnp.cumsum(sizes), bound) - jnp.minimum(starts, bound)
@@ -761,13 +756,16 @@ def dispatch_held(ids, held: Tuple[int, int], bound: int):
     within = row - (tile_ends - tiles)[expert] * ROW_TILE
     valid = (within < kept[expert]) & (row < tile_ends[-1] * ROW_TILE)
     slot = jnp.where(valid, order[jnp.minimum(starts[expert] + within, order.shape[0] - 1)], 0)
+    token_rows = _token_rows(key.reshape(ids.shape), of_expert, kept,
+                             (tile_ends - tiles) * ROW_TILE, rows)
     counters = {
         "rows_routed": routed,
         "load_max_over_mean": jnp.max(sizes) * n_held / jnp.maximum(routed, 1).astype(jnp.float32),
         "rows_overflowed": routed - jnp.sum(kept),
         "tiles_in_use": tile_ends[-1],
+        "gather_trips": jnp.sum(token_rows.passes),
     }
-    return slot, valid, tile_expert, tile_ends[-1:].astype(jnp.int32), counters
+    return slot, valid, tile_expert, tile_ends[-1:].astype(jnp.int32), counters, token_rows
 
 
 class RoutedExperts(nn.Module):
@@ -787,11 +785,13 @@ class RoutedExperts(nn.Module):
     :func:`buffer_rows`); per-expert counts are ragged inside it, so imbalance
     between experts costs nothing, and all work on the buffer is done for the
     tiles in use only: the grouped products skip the others
-    (:func:`grouped_matmul`), and the gather into the buffer, the weighted
-    scatter back and both gradients (:func:`rows_in`, :func:`rows_out`) walk
+    (:func:`grouped_matmul`), and the gather into the buffer and the
+    gradient of the experts' result (:func:`rows_in`, :func:`rows_out`) walk
     it in chunks of :func:`chunk_tiles` tiles and stop with the last tile in
-    use. Past it the buffer holds its zero fill. The gauges
-    ``moe.buffer_tiles`` and ``moe.chunk_tiles`` are set while tracing.
+    use. Past it the buffer holds its zero fill. The weighted sum back into the
+    tokens and the gradient of the tokens' rows gather each token's rows over
+    :class:`TokenRows`, which :func:`dispatch_held` makes once a layer. The
+    gauges ``moe.buffer_tiles`` and ``moe.chunk_tiles`` are set while tracing.
 
     The bias is no parameter: it gets no gradient and lives in the
     ``"routing"`` collection (``model_state`` of the ``bf`` optimizers, as
@@ -865,19 +865,19 @@ class RoutedExperts(nn.Module):
                     and not self.is_initializing()):
                 bias.value = balance_bias(bias.value, ids, self.bias_update_speed)
             bound = routed_rows_bound(t, k, n_held, self.num_experts)
-            slot, valid, tile_expert, tiles_used, counters = dispatch_held(
+            slot, valid, tile_expert, tiles_used, counters, token_rows = dispatch_held(
                 ids, self.held, bound)
             metrics.gauge("moe.buffer_tiles").set(slot.shape[0] // ROW_TILE)
             metrics.gauge("moe.chunk_tiles").set(chunk_tiles(slot.shape[0]))
             token = lax.div(slot, k)                                # of each row
-            gathered = rows_in(xt, token, valid, tiles_used)        # [rows, d]
+            gathered = rows_in(xt, token, valid, tiles_used, token_rows)  # [rows, d]
             row_weight = jnp.where(valid, weights.reshape(-1)[slot], 0.0)
         with jax.named_scope(SCOPE_EXPERTS):
             mm = lambda rows, w: grouped_matmul(  # noqa: E731
                 rows, w.astype(self.dtype), tile_expert, tiles_used, self.interpret)
             y = mm(act(mm(gathered, gate)) * mm(gathered, up), down)  # [rows, d]
         with jax.named_scope(SCOPE_ROUTE):
-            routed = rows_out(y, row_weight, token, tiles_used, t)  # [t, d]
+            routed = rows_out(y, row_weight, token, tiles_used, token_rows)  # [t, d]
         if self.n_shared:
             with jax.named_scope(SCOPE_SHARED):
                 shared = SwiGLU(self.n_shared * self.d_ff, self.dtype, name="shared")(xt)
@@ -899,3 +899,92 @@ class SwiGLU(nn.Module):
                                   use_bias=False)
         h = nn.silu(dense(self.d_ff, name="gate")(x)) * dense(self.d_ff, name="up")(x)
         return dense(x.shape[-1], name="down")(h)
+
+
+# ---------------------------------------------------------------------------
+# The way back into the tokens: gathers over each token's rows, no scatter.
+# Below the layers, which keep their lines: a compiled program holds its
+# ops' source lines, and the dense cells' programs hold SwiGLU's.
+# ---------------------------------------------------------------------------
+
+class TokenRows(NamedTuple):
+    """Each token's rows in the held experts' buffer, as :func:`dispatch_held`
+    lays it out: the map by which :func:`rows_out`'s value and
+    :func:`rows_in`'s gradient add rows back into tokens with gathers. The
+    tokens are taken in an order of their own, those with the most rows here
+    first, so that a chunk of them needs as many passes as its first token
+    has rows."""
+
+    row: jax.Array      # [k, T] int32: row j of the order's token p (ascending in j), -1 past its rows
+    inverse: jax.Array  # [T] int32: where token t stands in that order
+    passes: jax.Array   # [chunks of token_chunk(T)] int32: the rows of a chunk's first token
+
+
+def _to_tokens(src, token_rows, weight=None):
+    """``[T, d]`` in ``src``'s type: row ``t`` the float32 sum of ``src[i]``
+    (times ``weight[i]``) over token ``t``'s rows ``i`` of the buffer, added in
+    ascending ``i`` from zero and rounded once -- what a scatter-add of the
+    buffer's rows in ascending order computes, bit for bit, without a scatter.
+
+    One loop over (chunk of the ordered tokens, pass ``j``), ``passes[c]``
+    trips for chunk ``c``: a trip gathers row ``j`` of each of the chunk's
+    tokens (a token with fewer rows adds zero) into a float32 carry, which the
+    chunk's first pass starts from zero, and writes the carry out rounded;
+    one gather by ``inverse`` then puts the tokens back in their own order.
+    A chunk whose tokens have no row here takes no trip."""
+    row, inverse, passes = token_rows
+    t = inverse.shape[0]
+    n = token_chunk(t)
+    ends = jnp.cumsum(passes)
+
+    def trip(i, carry):
+        acc, out = carry
+        c = jnp.sum(ends <= i)
+        j = i - ends[c] + passes[c]
+        start = jnp.minimum(c * n, t - n)   # the last chunk may overlap the one before
+        here = lax.dynamic_slice(row, (j, start), (1, n))[0]
+        term = src[jnp.maximum(here, 0)].astype(jnp.float32)
+        if weight is not None:
+            term = term * weight[jnp.maximum(here, 0)][:, None]
+        # times 1 or 0 last: a multiply-add the compiler may fuse then still adds
+        # the rounded product, as the scatter does
+        acc = jnp.where(j == 0, 0.0, acc) + term * (here >= 0)[:, None].astype(jnp.float32)
+        return acc, lax.dynamic_update_slice_in_dim(out, acc.astype(out.dtype), start, axis=0)
+
+    d = src.shape[1]
+    init = (_fill((n, d), jnp.float32, src, row), _fill((t, d), src.dtype, src, row))
+    return lax.fori_loop(0, ends[-1], trip, init)[1][inverse]
+
+
+def _token_rows(key, of_expert, kept, first_row, rows: int) -> TokenRows:
+    """The way back from :func:`dispatch_held`'s buffer (``rows`` rows, held
+    expert ``e``'s from ``first_row[e]``, its first ``kept[e]`` slots taken)
+    to the tokens. ``key`` ``[T, k]`` is each slot's held expert (``held``
+    where it chose none), ``of_expert`` ``[T * k, held]`` the same one-hot. A
+    slot's row is its expert's first row plus its place among the expert's
+    slots, which the stable sort that laid out the buffer kept in slot order.
+    Each token's rows are sorted along ``k`` (absent slots last), then the
+    tokens by their count of rows, most first (a tie keeps the tokens'
+    order), in one variadic sort that carries the rows; the inverse is
+    counted, not sorted."""
+    t, k = key.shape
+    held = of_expert.shape[1]
+    place = _rank(of_expert)
+    e = jnp.minimum(key.reshape(-1), held - 1)
+    live = (key.reshape(-1) < held) & (place < kept[e])
+    by_token = lax.sort(jnp.where(live, first_row[e] + place, rows).reshape(t, k), dimension=1)
+    count = jnp.sum(by_token < rows, axis=1, dtype=jnp.int32)
+    negated, *columns = lax.sort((-count, *by_token.T), num_keys=1, is_stable=True)
+    row = jnp.stack(columns)
+    of_count = count[:, None] == jnp.arange(k + 1)  # [T, k + 1]
+    more = jnp.cumsum(jnp.sum(of_count, axis=0)[::-1])[::-1] - jnp.sum(of_count, axis=0)
+    n = token_chunk(t)
+    return TokenRows(row=jnp.where(row < rows, row, -1).astype(jnp.int32),
+                     inverse=(more[count] + _rank(of_count)).astype(jnp.int32),
+                     passes=-negated[jnp.minimum(jnp.arange(-(-t // n)) * n, t - n)])
+
+
+def _rank(members):
+    """For each row of the one-hot ``members`` ``[n, groups]``, how many rows
+    before it are of its group (0 for a row of none)."""
+    return jnp.sum((jnp.cumsum(members, axis=0, dtype=jnp.int32) - 1) * members, axis=1)

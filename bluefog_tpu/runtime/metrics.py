@@ -577,9 +577,10 @@ _HELP_EXACT: Dict[str, str] = {
     "moe.buffer_tiles": "128-row tiles of the held experts' buffer of the "
                         "last RoutedExperts layer traced "
                         "(expert.buffer_rows / ROW_TILE)",
-    "moe.chunk_tiles": "tiles a trip of that layer's row movers walks "
-                       "(expert.chunk_tiles): the movers stop after the "
-                       "chunk that holds tile tiles_in_use - 1",
+    "moe.chunk_tiles": "tiles a trip of that layer's walks over the buffer "
+                       "takes (expert.chunk_tiles; rows_in, rows_out's "
+                       "gradient): they stop after the chunk that holds "
+                       "tile tiles_in_use - 1",
     "loop.passes": "times the last ConfigLM traced runs its one stack of "
                    "layers on the same weights (LMConfig.total_ut_steps; 1 "
                    "for a model that is not looped)",

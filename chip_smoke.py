@@ -383,18 +383,19 @@ def phase_lm_flash():
 
 def _grouped_dispatch(key):
     """``GROUPED_LOADS`` slots on the held experts and 1,000 held elsewhere, one
-    a token, shuffled: ``(slot, valid, tile_expert, tiles_used)`` of a buffer
-    with tiles to spare."""
+    a token, shuffled: ``(slot, valid, tile_expert, tiles_used, token_rows)`` of
+    a buffer with tiles to spare."""
     held = len(GROUPED_LOADS)
     ids = np.concatenate([np.full(count, e) for e, count in enumerate(GROUPED_LOADS)]
                          + [np.full(GROUPED_SLOTS - sum(GROUPED_LOADS), held + 3)])
     ids = jax.random.permutation(key, jnp.asarray(ids, jnp.int32))[:, None]
-    slot, valid, tile_expert, used, counters = expert.dispatch_held(ids, (0, held), 4096)
+    slot, valid, tile_expert, used, counters, token_rows = expert.dispatch_held(
+        ids, (0, held), 4096)
     tiles = sum(max(-(-count // expert.ROW_TILE), 1) for count in GROUPED_LOADS)
     if (int(counters["rows_routed"]) != sum(GROUPED_LOADS) or int(counters["rows_overflowed"])
             or int(counters["tiles_in_use"]) != tiles):
         raise RuntimeError(f"dispatch_held miscounted {GROUPED_LOADS}: {counters}")
-    return slot, valid, tile_expert, used
+    return slot, valid, tile_expert, used, token_rows
 
 
 def _check_row_movers():
@@ -404,7 +405,7 @@ def _check_row_movers():
     loads: the loops stop after 3 chunks of 8 tiles of the buffer's 5."""
     d = MLA_MOE.hidden_size
     keys = jax.random.split(jax.random.PRNGKey(6), 5)
-    token, valid, _, used = _grouped_dispatch(keys[0])       # one slot a token
+    token, valid, _, used, token_rows = _grouped_dispatch(keys[0])  # one slot a token
     x = jax.random.normal(keys[1], (GROUPED_SLOTS, d), jnp.bfloat16)
     # stands for the experts' result: anything on padding rows, zero past the tiles in use
     extra = jnp.where(jnp.arange(token.shape[0])[:, None] < used[0] * expert.ROW_TILE,
@@ -413,9 +414,9 @@ def _check_row_movers():
     cot = jax.random.normal(keys[4], (GROUPED_SLOTS, d), jnp.float32)
 
     def moved(x, extra, weight):
-        y = expert.rows_in(x, token, valid, used) + extra
+        y = expert.rows_in(x, token, valid, used, token_rows) + extra
         return expert.rows_out(y, jnp.where(valid, weight[token], 0.0), token, used,
-                               GROUPED_SLOTS).astype(jnp.float32)
+                               token_rows).astype(jnp.float32)
 
     def plain(x, extra, weight):
         y = jnp.where(valid[:, None], x[token], 0) + extra
@@ -445,7 +446,7 @@ def _check_grouped_matmul():
     under its rows' mask, on ``GROUPED_LOADS`` in a buffer with tiles to spare."""
     d, f, held = MLA_MOE.hidden_size, MLA_MOE.moe_intermediate_size, len(GROUPED_LOADS)
     keys = jax.random.split(jax.random.PRNGKey(5), 4)
-    slot, valid, tile_expert, used = _grouped_dispatch(keys[0])
+    slot, valid, tile_expert, used, _ = _grouped_dispatch(keys[0])
     x = jax.random.normal(keys[1], (GROUPED_SLOTS, d), jnp.bfloat16)
     rows = jnp.where(valid[:, None], x[slot], 0)
     weights = (jax.random.normal(keys[2], (held, d, f), jnp.float32) / np.sqrt(d)).astype(

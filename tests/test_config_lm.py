@@ -258,7 +258,7 @@ def test_ragged_loads_drop_nothing_and_an_overflow_is_counted(load):
 def test_grouped_matmul_gradients_with_an_empty_expert():
     """Rows of experts 0 and 2 of three; expert 1 has none and a zero gradient."""
     ids = jnp.array([[0], [2], [2], [0], [2]])
-    slot, valid, tile_expert, used, _ = expert.dispatch_held(ids, (0, 3), bound=5)
+    slot, valid, tile_expert, used, _, _ = expert.dispatch_held(ids, (0, 3), bound=5)
     assert tile_expert.tolist()[:3] == [0, 1, 2] and int(used[0]) == 3
     x = jax.random.normal(jax.random.PRNGKey(8), (5, 16), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(9), (3, 16, 8), jnp.float32)
@@ -482,14 +482,17 @@ def test_the_toy_smallthinker_through_opt_step_matches_the_plain_reference(gqa_t
             assert _rel(got, want) <= GRAD_RTOL + 1e-4, (jax.tree_util.keystr(path), rank)
     aux = jax.device_get(metrics["aux"])
     assert np.all(aux["rows_overflowed"] == 0) and np.all(aux["rows_routed"] > 0)
-    # where the row movers and the grouped products stopped, summed over the layers
+    # where the row movers and the grouped products stopped, and the trips of the
+    # loops that add rows back into tokens, summed over the layers
     _, state = GQA.model(cfg).apply({"params": params}, batch[0], mutable=["intermediates"])
     k, held = cfg["moe_num_active_primary_experts"], cfg["moe_num_primary_experts"]
     bound = expert.routed_rows_bound(batch[0].size, k, held,
                                      cfg["published"]["moe_num_primary_experts"])
-    used = [expert.dispatch_held(ids.reshape(-1, k), GQA.held_range(cfg), bound)[3]
-            for ids in moe_choices(state["intermediates"])]
+    layers = [expert.dispatch_held(ids.reshape(-1, k), GQA.held_range(cfg), bound)
+              for ids in moe_choices(state["intermediates"])]
+    used = [layer[3] for layer in layers]
     assert len(used) == 8 and np.all(aux["tiles_in_use"] == int(sum(used)[0]))
+    assert np.all(aux["gather_trips"] == sum(int(layer[4]["gather_trips"]) for layer in layers))
     assert 8 * held <= int(sum(used)[0]) <= 8 * expert.buffer_rows(bound, held) // expert.ROW_TILE
 
 
@@ -689,16 +692,16 @@ def test_the_new_keys_are_off_by_default_and_each_adds_only_its_own_parameters()
 
 
 # sha256 of the printed jaxpr of value_and_grad of the two accepted ConfigLM
-# cells' losses at their toy sizes, taken from PR 34's parent commit's
-# ``config_lm.py`` under jax 0.9.0 and this suite's matmul precision: an
-# ``LMConfig`` without the loop, the sandwich norms, the gate and the
-# recomputation must trace to the program it traced to before they came
-# (PR 34): no new parameter, no new equation, no checkpoint. The losses of
-# that commit took their cross-entropy from optax, so it is put back here:
-# ``label_cross_entropy`` (PR 35) is then all that differs.
+# cells' losses at their toy sizes, under jax 0.9.0 and this suite's matmul
+# precision: an ``LMConfig`` without the loop, the sandwich norms, the gate and
+# the recomputation must trace to the program it traced to before they came
+# (no new parameter, no new equation, no checkpoint), with the expert layer's
+# way back to the tokens as it now is (gathers over ``TokenRows``, the counter
+# ``gather_trips``). That program took its cross-entropy from optax, so it is
+# put back here: ``label_cross_entropy`` is then all that differs.
 _PARENT_LOSS_JAXPRS = {
-    "joyai": "5d717bd5036d62d27e36265088bbfae7c808def8c843af5c256f0eadd273cbfd",
-    "smallthinker": "287d0ebbea729dcac6ed15f53d5a69bb3ce95bec22e7fbaf213f76348f9c3f6b",
+    "joyai": "20932f38a16077fcac45033b0dbd633dba5240be17aaba3bc69c95c40c0e28ae",
+    "smallthinker": "d8a70cfa9d3790fe1b10980e1c1a7daf18c059ab5fa71aef8361ae59cd976182",
 }
 
 
