@@ -234,7 +234,10 @@ def causal_schedule(sq: int, sk: int, q_off: int = 0, k_off: int = 0,
     (the larger of the two grid orders: K/V blocks with kj innermost, q-side
     blocks with qi innermost); ``chunks_computed``: [tq, chunk] score chunks
     the live steps work on; ``chunks_needed``: those that hold any allowed
-    (row, column) pair, counted over the whole block without the tiles."""
+    (row, column) pair, counted over the whole block without the tiles;
+    ``chunks_masked``: those of the edge steps that the kernels mask, when
+    the offsets reach them as these Python ints (``_edge_chunks`` where the
+    edges lie on chunk boundaries, else every chunk an edge step computes)."""
     tq, tk = _q_tile(sq), _k_tile(sk)
     nq, nk, cw = sq // tq, sk // tk, _chunk(tq, tk)
     offs = (q_off, k_off)
@@ -248,6 +251,13 @@ def causal_schedule(sq: int, sk: int, q_off: int = 0, k_off: int = 0,
         chunks = chunks - _first_chunk(offs, qi, kj, tq, tk, window, xp=np)
         needed &= column + cw - 1 > q_lo[:, None] - window
     computed = np.where(interior, tk // cw, np.where(live, chunks, 0))
+    edge = live & ~interior
+    if cw < tk and _on_chunks(q_off - k_off, cw, window):
+        bodies = _edge_chunks(tk // cw, cw, window)
+        past = (q_off + qi * tq - k_off - kj * tk) // cw
+        masked = sum(len(bodies[m][2]) for m in past[edge])
+    else:
+        masked = int(computed[edge].sum())
     kv = _kv_block(offs, qi, kj, tq, tk, np, window, nq, nk)
     qb = _q_block(offs, qi, kj, tq, tk, nq, np, window, nk)
     n_live = int(live.sum())
@@ -257,6 +267,7 @@ def causal_schedule(sq: int, sk: int, q_off: int = 0, k_off: int = 0,
                              _idle_fetches(qb.T.ravel(), live.T.ravel())),
         "chunks_computed": int(computed.sum()),
         "chunks_needed": int(needed.sum()),
+        "chunks_masked": int(masked),
     }
 
 
@@ -287,16 +298,53 @@ def _q_index_map(causal: bool, tq: int, tk: int, nq: int, window=None,
         bh, _q_block(offs, qi, kj, tq, tk, nq, jnp, window, nk), 0)
 
 
-def _when_causal(offs_ref, qi, kj, tq, tk, body, window=None):
+def _on_chunks(gap, cw: int, window) -> bool:
+    """Whether a static ``gap`` = q_off - k_off (None: traced offsets) and
+    the window put both edges of the band on column-chunk boundaries."""
+    return (gap is not None and gap % cw == 0
+            and (window is None or window % cw == 0))
+
+
+def _edge_chunks(nc: int, cw: int, window) -> dict:
+    """The edge steps of a K tile of ``nc`` chunks where the edges lie on
+    chunk boundaries (``_on_chunks``), by m, the chunks from the tile's
+    first column to the q tile's first row: ``{m: (first, n, masks)}``, the
+    live chunks [first, n) and the chunks an edge crosses, as (chunk from
+    ``first``, diagonal). The diagonal's chunk keeps row >= column, the
+    trailing edge's (W = w chunks behind it) row < column; every other
+    chunk is allowed whole. A step holds the diagonal for m < nc, the
+    trailing edge for w <= m < w + nc, and is interior in between."""
+    w = None if window is None else window // cw
+    ms = range(nc) if w is None else sorted({*range(nc), *range(w, w + nc)})
+    bodies = {}
+    for m in ms:
+        first = 0 if w is None else max(m - w, 0)
+        masks = ((0, False),) if w is not None and m >= w else ()
+        if m < nc:
+            masks += ((m - first, True),)
+        bodies[m] = (first, min(m + 1, nc), masks)
+    return bodies
+
+
+def _when_causal(offs_ref, qi, kj, tq, tk, body, window=None, gap=None):
     """Run ``body(masked, lo, hi)`` for this step's causal class: not at all
     on a dead step, unmasked over the whole K tile in the interior, and on
     an edge masked over the columns [lo, hi) that hold an allowed one: the
     chunks up to the diagonal's, and under a window from its trailing
-    edge's on — static bounds a variant, picked by ``pl.when``."""
+    edge's on — static bounds a variant, picked by ``pl.when``. Where a
+    static ``gap`` puts the edges on chunk boundaries, ``masked`` names the
+    chunks an edge crosses (``_edge_chunks``) and no other is masked; at
+    traced offsets it is True, every column of [lo, hi)."""
     live, interior = _causal_tile(offs_ref, qi, kj, tq, tk, window)
     pl.when(interior)(lambda: body(False, 0, tk))
-    edge = live & ~interior
     cw = _chunk(tq, tk)
+    if cw < tk and _on_chunks(gap, cw, window):
+        past = offs_ref[0] + qi * tq - offs_ref[1] - kj * tk
+        for m, (a, c, masks) in _edge_chunks(tk // cw, cw, window).items():
+            pl.when(past == m * cw)(
+                functools.partial(body, masks, a * cw, c * cw))
+        return
+    edge = live & ~interior
     if cw == tk:
         pl.when(edge)(lambda: body(True, 0, tk))
         return
@@ -315,6 +363,33 @@ def _when_causal(offs_ref, qi, kj, tq, tk, body, window=None):
                     functools.partial(body, True, a * cw, c * cw))
 
 
+def _mask(offs_ref, qi, kj, tq, tk, lo, hi, window, masked):
+    """``x, fill -> x`` with ``fill`` at the pairs of an edge tile's columns
+    [lo, hi) that are not allowed: every column tested (``masked`` True),
+    or the chunks ``masked`` names alone (``_edge_chunks``), each by a
+    static [tq, tq] triangle, the other columns passed on as they are. A
+    dot, row max or row sum still sees one [tq, hi - lo] array."""
+    if masked is True:
+        allowed = _allowed(offs_ref, qi, kj, tq, tk, lo, hi, window)
+        return lambda x, fill: jnp.where(allowed, x, fill)
+    row = jax.lax.broadcasted_iota(jnp.int32, (tq, tq), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (tq, tq), 1)
+    keeps = [(c * tq, row >= col if diagonal else row < col)
+             for c, diagonal in masked]
+
+    def apply(x, fill):
+        parts, at = [], 0
+        for start, keep in keeps:
+            parts += [x[:, at:start],
+                      jnp.where(keep, x[:, start:start + tq], fill)]
+            at = start + tq
+        parts.append(x[:, at:])
+        return jnp.concatenate([part for part in parts if part.shape[1]],
+                               axis=1)
+
+    return apply
+
+
 def _allowed(offs_ref, qi, kj, tq, tk, lo, hi, window):
     """[tq, hi - lo] mask of an edge tile's columns [lo, hi): the column is
     at or before the row and, under a window, less than ``window`` behind."""
@@ -331,7 +406,7 @@ def _allowed(offs_ref, qi, kj, tq, tk, lo, hi, window):
 
 
 def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
-            causal: bool, scale: float, window=None):
+            causal: bool, scale: float, window=None, gap=None):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     tq = q_ref.shape[1]
@@ -345,7 +420,7 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
         m_ref[0] = jnp.full_like(m_ref[0], _NEG)
         l_ref[0] = jnp.zeros_like(l_ref[0])
 
-    def body(masked: bool, lo: int, hi: int):
+    def body(masked, lo: int, hi: int):
         # Dots keep the inputs' NATIVE dtype (bf16) with f32 accumulation:
         # the MXU runs bf16x bf16 at 4x its f32 rate, and the operands are
         # already bf16 so the products are bit-identical; only the scale
@@ -359,15 +434,15 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
             preferred_element_type=jnp.float32,
             precision=_dot_prec(q_ref.dtype)) * scale
         if masked:
-            allowed = _allowed(offs_ref, qi, kj, tq, tk, lo, hi, window)
-            s = jnp.where(allowed, s, _NEG)
+            mask = _mask(offs_ref, qi, kj, tq, tk, lo, hi, window, masked)
+            s = mask(s, _NEG)
         m_prev = m_ref[0][:, 0]                       # [TQ]
         l_prev = l_ref[0][:, 0]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
         alpha = jnp.exp(m_prev - m_new)               # 0 on the first block
         p = jnp.exp(s - m_new[:, None])
         if masked:
-            p = jnp.where(allowed, p, 0.0)
+            p = mask(p, 0.0)
         l_new = alpha * l_prev + jnp.sum(p, axis=-1)
         o_ref[0] = alpha[:, None] * o_ref[0] + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
@@ -382,11 +457,12 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
         # Three tile classes: dead tiles (K entirely in the future, or
         # behind the window) are skipped and fetch nothing (_kv_block);
         # interior tiles (K entirely in the past and inside the window) run
-        # unmasked; edge tiles pay the mask — two [TQ, W] iotas, compares
-        # and selects — over their live column chunks only. With TK = 4 TQ
-        # the diagonal is no small part: 16 of the 40 live tiles of an
+        # unmasked; edge tiles work on their live column chunks only and
+        # pay the mask — iotas, compares and selects — on the chunk an edge
+        # crosses (on all of them at traced offsets). With TK = 4 TQ the
+        # diagonal is no small part: 16 of the 40 live tiles of an
         # 8192-token sequence, every tile at 2048.
-        _when_causal(offs_ref, qi, kj, tq, tk, body, window)
+        _when_causal(offs_ref, qi, kj, tq, tk, body, window, gap)
     else:
         body(False, 0, tk)
 
@@ -410,7 +486,26 @@ def _bhsd(x):  # [B, S, H, C] -> [B*H, S, C]
     return x.transpose(0, 2, 1, 3).reshape(B * H, S, C)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
+def _entry(name: str):
+    """``jax.jit`` of an entry point's body, static ``gap`` and all, under
+    the entry point's own name: what jaxprs and traces call it (a trace's
+    op names read ``jit(flash_block)/bf.flash.fwd``)."""
+    def jit(body):
+        body.__name__ = body.__qualname__ = name
+        return jax.jit(body, static_argnames=("causal", "window",
+                                              "interpret", "gap"))
+    return jit
+
+
+def _gap(q_off, k_off):
+    """q_off - k_off where both offsets are Python ints, else None: a
+    traced offset (a ring step's) leaves the kernels masking every live
+    column of an edge tile."""
+    if isinstance(q_off, int) and isinstance(k_off, int):
+        return q_off - k_off
+    return None
+
+
 def flash_block(q, k, v, q_off, k_off, *, causal: bool = True, window=None,
                 interpret: bool = False):
     """Attention partials of q against one K/V block.
@@ -425,7 +520,16 @@ def flash_block(q, k, v, q_off, k_off, *, causal: bool = True, window=None,
     Returns (o, m, l): [B, Sq, Hq, Dv] f32 unnormalized output and
     [B, Sq, Hq] f32 row max / row sum. Final output = o / l after merging
     blocks; a row that sees no column of this block has l = 0.
+
+    Offsets given as Python ints let an edge tile mask only the chunk an
+    edge crosses (``_edge_chunks``); the numbers are the same either way.
     """
+    return _flash_block(q, k, v, q_off, k_off, causal=causal, window=window,
+                        interpret=interpret, gap=_gap(q_off, k_off))
+
+
+@_entry("flash_block")
+def _flash_block(q, k, v, q_off, k_off, *, causal, window, interpret, gap):
     B, Sq, H, D = q.shape
     Sk, Dv = k.shape[1], v.shape[-1]
     group = _check_heads(q, k, v, causal, window)
@@ -435,7 +539,7 @@ def flash_block(q, k, v, q_off, k_off, *, causal: bool = True, window=None,
     offs = jnp.asarray([q_off, k_off], jnp.int32)
     grid = (B * H, Sq // tq, Sk // tk)
     kernel = functools.partial(_kernel, causal=causal, scale=scale,
-                               window=window)
+                               window=window, gap=gap)
     kv_map = _kv_index_map(causal, tq, tk, group, window, Sq // tq, Sk // tk)
     kw = _vma(q, k, v)
     out_shape = (
@@ -475,8 +579,13 @@ def flash_block(q, k, v, q_off, k_off, *, causal: bool = True, window=None,
     return sbhd(o), sbhd(m)[..., 0], sbhd(l)[..., 0]
 
 
+# the compiled programs are the jitted body's: a caller that changes the
+# tiles (the tests) drops them through the entry point
+flash_block.clear_cache = _flash_block.clear_cache
+
+
 def _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref,
-               d_ref, masked: bool, lo: int, hi: int, scale: float,
+               d_ref, masked, lo: int, hi: int, scale: float,
                window=None):
     """Shared backward-tile recompute -> (q, k, g*inv_l, P_unnorm, dS) over
     the columns [lo, hi) of the K/V tile (all of it but on an edge tile:
@@ -504,8 +613,8 @@ def _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref,
                             preferred_element_type=jnp.float32,
                             precision=_dot_prec(q_ref.dtype)) * scale
     if masked:
-        allowed = _allowed(offs_ref, qi, kj, tq, tk, lo, hi, window)
-        s = jnp.where(allowed, s, _NEG)
+        mask = _mask(offs_ref, qi, kj, tq, tk, lo, hi, window, masked)
+        s = mask(s, _NEG)
     # VPU saver: the softmax row normalizer inv_l is folded into the
     # per-ROW quantities instead of the [TQ, TK] tile — p stays
     # UNNORMALIZED (exp(s - m), in [0, 1] since m is the global row max)
@@ -516,7 +625,7 @@ def _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref,
     # — one fewer full-tile elementwise pass per (q, k) tile pair.
     p = jnp.exp(s - m[:, None])
     if masked:
-        p = jnp.where(allowed, p, 0.0)
+        p = mask(p, 0.0)
     g_scaled = (g.astype(jnp.float32)
                 * inv_l[:, None]).astype(g.dtype)   # [TQ, D]: cheap
     dp = jax.lax.dot_general(g_scaled, v, (((1,), (1,)), ((), ())),
@@ -528,7 +637,7 @@ def _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref,
 
 def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref,
                 dk_ref, dv_ref, dq_ref, *, causal: bool, scale: float,
-                window=None):
+                window=None, gap=None):
     """The backward (flash-attention-2): for each K/V tile, iterate query
     tiles innermost and accumulate dv += P^T @ dO and dk += dS^T @ (Q *
     scale) into the K tile's output blocks, and dq += dS @ K * scale into
@@ -549,7 +658,7 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref,
         dk_ref[0] = jnp.zeros_like(dk_ref[0])
         dv_ref[0] = jnp.zeros_like(dv_ref[0])
 
-    def body(masked: bool, lo: int, hi: int):
+    def body(masked, lo: int, hi: int):
         q, k, g, p, ds = _bwd_tiles(offs_ref, qi, kj, q_ref, k_ref, v_ref,
                                     g_ref, m_ref, l_ref, d_ref, masked, lo,
                                     hi, scale, window)
@@ -571,7 +680,7 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref,
                 preferred_element_type=jnp.float32, precision=prec) * scale)
 
     if causal:
-        _when_causal(offs_ref, qi, kj, tq, tk, body, window)
+        _when_causal(offs_ref, qi, kj, tq, tk, body, window, gap)
     else:
         body(False, 0, tk)
 
@@ -582,7 +691,6 @@ def _lane8(x):  # [B, S, H] -> [B*H, S, 8] (TPU sublane x lane tiling)
     return jnp.broadcast_to(t[:, :, None], (B * H, S, 8))
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
 def flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off, *,
                     causal: bool = True, window=None,
                     interpret: bool = False):
@@ -607,22 +715,34 @@ def flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off, *,
     ``_dq_rows`` allows (32k tokens at 128 lanes), q is walked in row
     blocks that fit, each one call of the same kernel at its own ``q_off``,
     and the blocks' dk/dv are summed: no second kernel for long shapes, and
-    no score built twice there either.
+    no score built twice there either. Offsets as in :func:`flash_block`.
     """
+    return _flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off,
+                            causal=causal, window=window, interpret=interpret,
+                            gap=_gap(q_off, k_off))
+
+
+@_entry("flash_block_bwd")
+def _flash_block_bwd(q, k, v, g, d_term, m, l, q_off, k_off, *, causal,
+                     window, interpret, gap):
     Sq = q.shape[1]
     rows = _dq_rows(Sq, q.shape[-1])
 
     def block(r):
         qb, gb, db, mb, lb = (x[:, r:r + rows] for x in (q, g, d_term, m, l))
         return _bwd_call(qb, k, v, gb, db, mb, lb, q_off + r, k_off,
-                         causal, window, interpret)
+                         causal, window, interpret,
+                         None if gap is None else gap + r)
 
     dq, dk, dv = zip(*map(block, range(0, Sq, rows)))
     return jnp.concatenate(dq, axis=1), sum(dk[1:], dk[0]), sum(dv[1:], dv[0])
 
 
+flash_block_bwd.clear_cache = _flash_block_bwd.clear_cache
+
+
 def _bwd_call(q, k, v, g, d_term, m, l, q_off, k_off, causal, window,
-              interpret):
+              interpret, gap):
     """One ``pallas_call`` of the backward kernel: (dq, dk, dv) of q's rows
     against the K/V block, dq resident (``flash_block_bwd``)."""
     B, Sq, H, D = q.shape
@@ -664,7 +784,7 @@ def _bwd_call(q, k, v, g, d_term, m, l, q_off, k_off, causal, window,
     with jax.named_scope(SCOPE_DKV):
         dk, dv, dq = pl.pallas_call(
             functools.partial(_bwd_kernel, causal=causal, scale=scale,
-                              window=window),
+                              window=window, gap=gap),
             grid_spec=grid_spec,
             out_shape=(
                 jax.ShapeDtypeStruct((B * H, Sk, D), jnp.float32, **kw),
@@ -788,6 +908,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
         metrics.gauge("flash.dead_steps_fetching").set(sched["dead_fetching"])
         metrics.gauge("flash.chunks_computed").set(sched["chunks_computed"])
         metrics.gauge("flash.chunks_needed").set(sched["chunks_needed"])
+        metrics.gauge("flash.chunks_masked").set(sched["chunks_masked"])
     if name_residuals:  # a line of its own: the default call keeps its source position
         return _flash(q, k, v, causal, window, interpret, True)
     return _flash(q, k, v, causal, window, interpret)

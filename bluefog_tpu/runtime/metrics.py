@@ -562,6 +562,9 @@ _HELP_EXACT: Dict[str, str] = {
     "flash.chunks_needed": "score chunks of that call that hold an allowed "
                            "(row, column) pair: the causal floor of "
                            "flash.chunks_computed",
+    "flash.chunks_masked": "score chunks of that call's edge steps that the "
+                           "kernels mask: those an edge of the band crosses "
+                           "(flash._edge_chunks)",
     "flash.bwd_fused": "1 when the backward of the last flash_attention "
                        "traced is one call of the kernel, a head's dq "
                        "resident in VMEM; 0 when the shape rule "

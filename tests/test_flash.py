@@ -215,7 +215,8 @@ def test_flash_attention_gradients_at_4to1_tiles(tiles_8x32):
                                    err_msg=f"grad mismatch for {name}")
     gauges = metrics.snapshot(include_native=False)["gauges"]
     assert (gauges["flash.dead_steps_fetching"], gauges["flash.chunks_computed"],
-            gauges["flash.chunks_needed"]) == (0.0, 136.0, 136.0)
+            gauges["flash.chunks_needed"], gauges["flash.chunks_masked"]) == (
+                0.0, 136.0, 136.0, 16.0)
     assert gauges["flash.bwd_fused"] == 1.0
 
 
@@ -248,7 +249,7 @@ def test_dead_steps_keep_their_neighbours_block(q_off, k_off):
                                       "BLUEFOG_FLASH_TK": "32"}],
                          ids=["env_unset", "old_tile_knobs_set"])
 @pytest.mark.parametrize("s,want", [
-    (8192, (64, 40, 24, 0, 136, 136)), (2048, (4, 4, 0, 0, 10, 10))])
+    (8192, (64, 40, 24, 0, 136, 136, 16)), (2048, (4, 4, 0, 0, 10, 10, 4))])
 def test_causal_schedule_of_the_benchmarks_sequences(monkeypatch, s, want,
                                                      env):
     """The cells' geometry is the module's, whatever the environment holds."""
@@ -258,7 +259,7 @@ def test_causal_schedule_of_the_benchmarks_sequences(monkeypatch, s, want,
     got = flash.causal_schedule(s, s, 0, 0)
     assert tuple(got[key] for key in (
         "steps", "live", "dead", "dead_fetching", "chunks_computed",
-        "chunks_needed")) == want
+        "chunks_needed", "chunks_masked")) == want
 
 
 @pytest.mark.parametrize("q_off,k_off", [(8192, 0), (1000, 300), (300, 1000)])
@@ -477,9 +478,22 @@ def _chunks_by_rows(sq, sk, q_off, k_off, window, tq, cw):
     return seen
 
 
+def _chunks_cut(sq, sk, q_off, k_off, window, tq, cw):
+    """[q tiles, column chunks]: whether the chunk holds an allowed pair of
+    the q tile and a pair that is not -- the chunks a mask has work in."""
+    t = q_off + np.arange(sq)
+    first = np.maximum(t - window + 1 if window else np.full(sq, k_off), k_off)
+    last = np.minimum(t, k_off + sk - 1)
+    lo = k_off + np.arange(sk // cw) * cw
+    whole = ((first.reshape(-1, tq).max(axis=1)[:, None] <= lo)
+             & (last.reshape(-1, tq).min(axis=1)[:, None] >= lo + cw - 1))
+    return _chunks_by_rows(sq, sk, q_off, k_off, window, tq, cw) & ~whole
+
+
 @pytest.mark.parametrize("sq,sk,q_off,k_off,window", [
     (16384, 16384, 0, 0, 4096),      # smallthinker-s16384-*: 252 chunks of 528
     (16384, 16384, 0, 0, None),
+    (8192, 8192, 0, 0, 2048),        # trinity-mini-s8192-*: 70 chunks of 136
     (8192, 8192, 0, 0, 4096), (8192, 8192, 8192, 0, 4096),
     (8192, 8192, 1000, 300, 4096), (8192, 8192, 300, 1000, 700),
     (4096, 8192, 5000, 0, 300), (2048, 2048, 0, 0, 512), (2048, 2048, 0, 0, 1),
@@ -488,14 +502,24 @@ def test_causal_schedule_under_a_window_computes_what_is_needed(
         sq, sk, q_off, k_off, window):
     """At the default 512 x 2048 tiles: the chunks the live steps work on are
     the chunks that hold an allowed pair, counted by brute force, and no copy
-    is issued for dead steps alone -- both edges."""
+    is made for dead steps alone -- both edges. Where the offsets and the
+    window lie on chunk boundaries the kernels mask just the chunks that
+    hold a pair that is not allowed; elsewhere every chunk of an edge step."""
     got = flash.causal_schedule(sq, sk, q_off, k_off, window)
     needed = int(_chunks_by_rows(sq, sk, q_off, k_off, window, 512, 512).sum())
+    cut = int(_chunks_cut(sq, sk, q_off, k_off, window, 512, 512).sum())
     assert got["chunks_computed"] == got["chunks_needed"] == needed > 0
     assert got["dead_fetching"] == 0
     assert got["live"] + got["dead"] == got["steps"]
+    if (q_off - k_off) % 512 == 0 and (window or 0) % 512 == 0:
+        assert got["chunks_masked"] == cut
+    else:
+        assert needed >= got["chunks_masked"] >= cut
     if (sq, q_off, window) == (16384, 0, 4096):
-        assert (got["live"], needed) == (84, 252)   # of 144 live and 528
+        # of 144 live, 528 and 140 masked in every chunk of an edge step
+        assert (got["live"], needed, got["chunks_masked"]) == (84, 252, 56)
+    if (sq, q_off, window) == (8192, 0, 2048):
+        assert (needed, got["chunks_masked"]) == (70, 28)   # of 70
 
 
 @pytest.mark.parametrize("window", [1, 5, 8, 9, 31, 32, 40, 64, 127, 128, 500])
@@ -540,18 +564,21 @@ def test_heads_and_window_are_checked():
 # sha256 of the printed jaxpr of value_and_grad of ``flash_attention(q, k, v,
 # causal=True)`` -- both pallas_calls with their kernel bodies, grids and
 # index maps -- at the head layouts of the cells that had the kernels before
-# grouped heads and the window came (PR 32), taken from the parent commit's
-# ``flash.py`` under jax 0.9.0. A ``window`` of None and as many k/v heads as
-# query heads must trace to the program they traced to then: the step
-# programs of the accepted cells do not move.
+# grouped heads and the window came, under jax 0.9.0. Taken again
+# when an edge step came to mask only the chunk the diagonal crosses: the
+# kernel bodies changed by design, and the bit-for-bit checks above
+# (``test_chunk_masks_equal_the_whole_tiles_bit_for_bit``) are what show that
+# they compute the numbers of the bodies before. A ``window`` of None and as
+# many k/v heads as query heads must trace to this program: the step programs
+# of the accepted cells move only with the kernels.
 _PINNED_JAX = "0.9.0"
 _PARENT_JAXPRS = {
     "pythia-s8192": ((1, 8192, 16, 128), 128,
-                     "81cd84cd6d968d87dfa726076ad081c4b0846873ee7a07ad2162ec4699aa3452"),
+                     "db09cd84baa974a61c1cfbb34c1e7f65417c1a7851b21152c9734022bca55ba1"),
     "pythia-s2048x4": ((4, 2048, 16, 128), 128,
-                       "c88260c691d57893ff051bdc9f88008b055f0881e646250563e97e3b5c6af6f2"),
+                       "f95d5d4850bf7e8e72490444572a4094a9f2dc324c0a80dd9c1991cd7fe36903"),
     "joyai-s8192": ((1, 8192, 32, 192), 128,
-                    "ceb9478d73301d3d373f0b081ed9fe1d81221462120c01ae4d14855c3021edbf"),
+                    "6e034a6a6c8cc25ecb90326f7b669955ed370ff77709d1ea300fdabf1af88ec2"),
 }
 
 
@@ -570,6 +597,189 @@ def test_plain_causal_heads_trace_to_the_parents_program(cell):
 
     text = str(jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, q, v))
     assert hashlib.sha256(text.encode()).hexdigest() == want
+
+
+# The ring's calls of the two kernels (offsets traced, a ring step's) at two
+# cells' layouts: sha256 of the kernels' printed jaxprs and grid mappings, taken
+# from the parent commit's ``flash.py`` (before an edge step masked by chunk)
+# under jax 0.9.0. At traced offsets an edge step masks every live column: the
+# ring runs the kernels it ran.
+_RING_KERNELS = {
+    "grouped-window": ((1, 8192, 32, 128), 4, 2048,
+                       "c44ef2df82611e6ba2e83983d2f69c520f79f394bef60971611542febd283be3"),
+    "causal": ((1, 8192, 16, 128), 16, None,
+               "bb962cf2c6c0f09c06d0f0948cb96ba310e8a9dc78016193987ccc1fa8980660"),
+}
+
+
+def _kernels(jaxpr):
+    """The ``pallas_call`` equations of a jaxpr, nested jaxprs included, in
+    order: each one's kernel jaxpr and grid mapping, printed."""
+    from jax import core as jax_core
+
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(str(eqn.params["jaxpr"]) + str(eqn.params["grid_mapping"]))
+        for sub in jax_core.jaxprs_in_params(eqn.params):
+            found += _kernels(sub)
+    return found
+
+
+def _block_jaxprs(shape, hkv, window, offs):
+    """Jaxprs of ``flash_block`` and ``flash_block_bwd`` at bf16 q ``shape``
+    and k, v at ``hkv`` heads of 128: the offsets traced (``offs`` None) or
+    ``offs`` as Python ints."""
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct(shape[:2] + (hkv, 128), jnp.bfloat16)
+    stat = jax.ShapeDtypeStruct(shape[:3], jnp.float32)
+    g = jax.ShapeDtypeStruct(shape, jnp.float32)
+    kw = dict(causal=True, window=window)
+    if offs is None:
+        fwd = jax.make_jaxpr(lambda q, k, v, a, b: flash.flash_block(
+            q, k, v, a, b, **kw))(q, kv, kv, 0, 0)
+        bwd = jax.make_jaxpr(lambda *x: flash.flash_block_bwd(*x, **kw))(
+            q, kv, kv, g, stat, stat, stat, 0, 0)
+    else:
+        fwd = jax.make_jaxpr(lambda q, k, v: flash.flash_block(
+            q, k, v, *offs, **kw))(q, kv, kv)
+        bwd = jax.make_jaxpr(lambda *x: flash.flash_block_bwd(*x, *offs, **kw))(
+            q, kv, kv, g, stat, stat, stat)
+    return fwd.jaxpr, bwd.jaxpr
+
+
+@pytest.mark.parametrize("layout", sorted(_RING_KERNELS))
+def test_the_ring_traces_to_the_parents_kernels(layout):
+    import hashlib
+
+    if jax.__version__ != _PINNED_JAX:
+        pytest.skip(f"the hashes are of jaxprs printed by jax {_PINNED_JAX}")
+    shape, hkv, window, want = _RING_KERNELS[layout]
+    text = "".join(sum(map(_kernels, _block_jaxprs(shape, hkv, window, None)), []))
+    assert hashlib.sha256(text.encode()).hexdigest() == want
+
+
+@pytest.mark.parametrize("s,hq,hkv,window,bodies", [
+    (16384, 28, 4, 4096, (10, 11)),   # smallthinker-s16384-*: a window layer
+    (16384, 28, 4, None, (6, 7)),     # its global layer
+    (8192, 32, 4, 2048, (10, 11)),    # trinity-mini-s8192-*: a window layer
+    (8192, 16, 16, None, (6, 7)),     # pythia-s8192-*, trinity-mini's global layer
+])
+def test_one_more_body_a_kernel_under_a_window(s, hq, hkv, window, bodies):
+    """``pl.when`` bodies of the forward and the backward kernel at the
+    cells' layouts, offsets 0 and 0: the zeroing of the running state (one in
+    the forward, two in the backward), the interior and one an edge step's
+    m (``_edge_chunks``): as many as the variants of [lo, hi) with no window,
+    one more with one -- the (0, all chunks) variant, diagonal or trailing
+    edge. At traced offsets the parent's count, one fewer under a window."""
+    got = tuple(str(_kernels(j)[0]).count("cond[")
+                for j in _block_jaxprs((1, s, hq, 128), hkv, window, (0, 0)))
+    assert got == bodies
+    ring = tuple(str(_kernels(j)[0]).count("cond[")
+                 for j in _block_jaxprs((1, s, hq, 128), hkv, window, None))
+    assert ring == tuple(n - (window is not None) for n in bodies)
+
+
+# -- an edge step masks the chunk an edge crosses ------------------------------
+# Where the offsets reach the kernels as Python ints that put both edges on
+# chunk boundaries (flash_attention's 0 and 0), an edge step masks just the
+# chunk the diagonal crosses and the one the window's trailing edge crosses
+# (``flash._edge_chunks``), the other columns of [lo, hi) as they are; at
+# traced offsets (a ring step's) it masks every live column, as before. The two
+# compute the same numbers bit for bit. XLA:CPU is kept from rewriting them: its
+# algebraic simplifier turns a row sum of concatenated chunks into a sum of
+# per-chunk sums (a Mosaic body is not rewritten so), and at 8-column chunks its
+# exp rounds otherwise in a fusion of pieces than of the tile, so the check is
+# compiled without that pass and at 128 x 512 tiles: four lane-wide chunks a K
+# tile, the default 512 x 2048's geometry.
+
+EDGE_TQ, EDGE_TK = 128, 512
+
+
+@pytest.fixture
+def tiles_128x512(monkeypatch):
+    monkeypatch.setattr(flash, "_Q_TILES", (EDGE_TQ,))
+    monkeypatch.setattr(flash, "_K_TILES", (EDGE_TK,))
+    flash.flash_block.clear_cache()
+    flash.flash_block_bwd.clear_cache()
+    yield
+    flash.flash_block.clear_cache()
+    flash.flash_block_bwd.clear_cache()
+
+
+def _op_for_op(f, *args):
+    """``f(*args)`` compiled without XLA's algebraic simplifier."""
+    return jax.jit(f).lower(*args).compile(
+        {"xla_disable_hlo_passes": "algsimp"})(*args)
+
+
+def _static_and_traced(q, k, v, w, q_off, k_off, window):
+    """(o, m, l, dq, dk, dv) of ``flash_block`` and ``flash_block_bwd`` with
+    the offsets as Python ints and as traced values; both backwards from the
+    first forward's statistics."""
+    kw = dict(causal=True, window=window, interpret=True)
+    fwd = [_op_for_op(lambda q, k, v: flash.flash_block(q, k, v, q_off, k_off, **kw),
+                      q, k, v),
+           _op_for_op(lambda q, k, v, a, b: flash.flash_block(q, k, v, a, b, **kw),
+                      q, k, v, q_off, k_off)]
+    o, m, l = fwd[0]
+    stats = (w, jnp.sum(w * o / l[..., None], axis=-1), m, l)
+    bwd = [_op_for_op(lambda *x: flash.flash_block_bwd(*x, q_off, k_off, **kw),
+                      q, k, v, *stats),
+           _op_for_op(lambda *x: flash.flash_block_bwd(*x, **kw),
+                      q, k, v, *stats, q_off, k_off)]
+    return [tuple(f) + tuple(b) for f, b in zip(fwd, bwd)]
+
+
+@pytest.mark.parametrize("window,hq,hkv", [
+    (None, 2, 2), (EDGE_TQ, 4, 2), (2 * EDGE_TQ, 4, 2), (4 * EDGE_TQ, 4, 2)],
+    ids=["causal", "window-1-chunk", "window-2-chunks", "window-4-chunks"])
+def test_chunk_masks_equal_the_whole_tiles_bit_for_bit(tiles_128x512, window,
+                                                       hq, hkv):
+    """A 1024-token block at offsets 0 and 0 (8 x 2 steps): grouped heads,
+    and windows from one chunk (a step holds both edges) to four (the
+    (0, all chunks) variant is the diagonal's in one q tile, the trailing
+    edge's in another)."""
+    q, k, v, w = _grouped(hq, hkv, seq=1024)
+    static, traced = _static_and_traced(q, k, v, w, 0, 0, window)
+    for a, b, name in zip(static, traced, ("o", "m", "l", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+    masked = flash.causal_schedule(1024, 1024, window=window)["chunks_masked"]
+    assert masked == _chunks_cut(1024, 1024, 0, 0, window, EDGE_TQ, EDGE_TQ).sum()
+
+
+@pytest.mark.parametrize("q_off,window,masks", [
+    (3 * EDGE_TQ, None, ((3, True),)), (EDGE_TK, EDGE_TK, ((0, False),))],
+    ids=["zero-all-diagonal", "zero-all-trailing"])
+def test_the_zero_all_variant_masks_the_edge_it_holds(tiles_128x512, q_off,
+                                                      window, masks):
+    """One q tile against one K tile, the q tile's first row 3 chunks past
+    the K tile's first column (it holds the diagonal in its last chunk) or 4
+    under a 4-chunk window (the trailing edge in its first): both are the
+    step of all four chunks from the first, told apart by m at run time."""
+    m = q_off // EDGE_TQ
+    assert flash._edge_chunks(4, EDGE_TQ, window)[m] == (0, 4, masks)
+    q, k, v, w = _grouped(2, 1, seq=EDGE_TK)
+    q, w = q[:, :EDGE_TQ], w[:, :EDGE_TQ]
+    static, traced = _static_and_traced(q, k, v, w, q_off, 0, window)
+    for a, b, name in zip(static, traced, ("o", "m", "l", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+    assert float(jnp.min(static[2])) > 0    # every row sees a column
+
+
+@pytest.mark.parametrize("window", [None, 2048, 4096])
+def test_edge_chunks_at_the_default_tiles(window):
+    """The edge steps of a 2048-column K tile of four 512-wide chunks: the
+    diagonal's chunk is the m-th, the trailing edge's is m - w's; both kinds
+    give the (0, all chunks) variant under the cells' windows (w = 4, 8)."""
+    bodies = flash._edge_chunks(4, 512, window)
+    w = window and window // 512
+    want = {m: (0, m + 1, ((m, True),)) for m in range(4)}
+    if w:
+        want.update({m: (m - w, 4, ((0, False),)) for m in range(w, w + 4)})
+    assert bodies == want
+    zero_all = [b[2] for b in bodies.values() if b[:2] == (0, 4)]
+    assert zero_all == ([((3, True),), ((0, False),)] if w else [((3, True),)])
 
 
 def _named(jaxpr):
